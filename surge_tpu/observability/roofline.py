@@ -1,10 +1,8 @@
 """Roofline recorder: measured device-fold figures as append-only JSONL.
 
-docs/roofline.md holds the measured walls every fold decision rests on
-(~58 µs scan-step floor, d2h ~25 MB/s, the ~8 µs/event-slot steady-fold
-dispatch with ~9× padding over-dispatch — BENCH_NOTES round 9). Those rows
-were hand-carried out of bench runs; this module makes the measurement
-continuous: a :class:`RooflineRecorder` snapshots a refresh-round ledger's
+Fold figures used to be hand-carried out of bench runs into a doc table;
+this module makes the measurement continuous: a :class:`RooflineRecorder`
+snapshots a refresh-round ledger's
 :meth:`~surge_tpu.replay.ledger.ReplayLedger.summary` (measured ev/s,
 µs/slot, µs/event, padding-waste ratio) into one JSON line per snapshot —
 append-only, so a file accumulates the machine's trajectory across runs
@@ -12,8 +10,8 @@ and regressions show as rows, not as a reverted doc table.
 
 ``tools/roofline_record.py`` is the operator CLI (pulls ``DumpReplayLedger``
 from a live engine, or reads a saved dump file); :data:`REFERENCE` carries
-the docs/roofline.md anchor figures so a row can be compared against the
-published wall in one call (:func:`against_reference`).
+the anchor figures a row can be compared against in one call
+(:func:`against_reference`).
 """
 
 from __future__ import annotations
@@ -26,17 +24,16 @@ from typing import Dict, Iterator, Optional
 __all__ = ["REFERENCE", "RooflineRecorder", "against_reference",
            "roofline_row"]
 
-#: docs/roofline.md anchor figures (the published walls new rows are read
-#: against). Keys name the measured regime; values the doc's figures.
+#: anchor figures new rows are read against. Keys name the measured regime.
 REFERENCE: Dict[str, Dict[str, float]] = {
-    # BENCH_NOTES round 9: steady ragged incremental folds on the CPU
-    # backend — ~8 µs of host-observed dispatch per padded event slot,
+    # steady ragged incremental folds on the CPU backend of the sandbox
+    # (host clock, not a device figure) — ~8 µs of host-observed dispatch per padded event slot,
     # ~9× padding over-dispatch (pow8 lane bucket × pow2 window tail)
     "steady-ragged-cpu": {"us_per_slot": 8.0, "waste_ratio": 9.0},
 }
 
 #: the summary keys a roofline row carries (the derived ratios first — the
-#: figures docs/roofline.md tabulates — then the raw totals they came from)
+#: compared figures — then the raw totals they came from)
 _ROW_KEYS = ("fold_events_per_sec", "us_per_slot", "us_per_event",
              "waste_ratio", "rounds", "events", "dispatched_slots",
              "occupied_slots", "dispatch_us", "encode_us", "feed_us",

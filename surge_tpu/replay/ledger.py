@@ -26,7 +26,7 @@ so a ledger dump interleaves with engine/broker flight dumps through
 lands on incident timelines next to the breach that paged. The
 ``DumpReplayLedger`` admin RPC pulls it; ``tools/roofline_record.py``
 snapshots :meth:`ReplayLedger.summary` into append-only JSONL rows
-comparable against docs/roofline.md.
+comparable against the recorder's anchor figures.
 """
 
 from __future__ import annotations
@@ -102,7 +102,7 @@ class ReplayLedger(FlightRecorder):
 
         ``buckets`` (bucketed refresh dispatch, ISSUE 18) carries one dict
         per fused bucket program the round issued — ``{width, lanes_b,
-        lanes, windows, dispatched, occupied, ragged}`` — and
+        lanes, windows, dispatched, occupied}`` — and
         ``bucket_table`` the size of the layout's bounded compile-signature
         table; both optional so pre-bucketing callers stay source-compatible."""
         t = self.totals

@@ -177,7 +177,6 @@ class MeshPlane:
         from jax.sharding import PartitionSpec as P
 
         from surge_tpu.replay.engine import make_batch_fold
-        from surge_tpu.replay.jax_compat import shard_map as _shard_map
 
         plane = self.plane
         wire = plane._wire
@@ -208,7 +207,7 @@ class MeshPlane:
 
         axis = self.axis
         p2 = P(axis, None)
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=({k: p2 for k in fnames}, p2, p2,
                       {k: p2 for k in fnames}, p2, p2, p2,
@@ -254,8 +253,6 @@ class MeshPlane:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from surge_tpu.replay.jax_compat import shard_map as _shard_map
-
         fnames = [f.name for f in self._fields]
         per_dev = self.per_dev
         axis = self.axis
@@ -270,7 +267,7 @@ class MeshPlane:
             return ({k: v[None] for k, v in slab0.items()}, ords0[None])
 
         p2 = P(axis, None)
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=({k: p2 for k in fnames}, p2, {k: P() for k in fnames},
                       P(), P()),
@@ -310,8 +307,6 @@ class MeshPlane:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from surge_tpu.replay.jax_compat import shard_map as _shard_map
-
         plane = self.plane
         names = [f.name for f in self._fields]
         dts = [plane._dev_dts[n] for n in names]
@@ -346,7 +341,7 @@ class MeshPlane:
             return both[:-1], both[-1].astype(jnp.int32)
 
         if not narrow:
-            mapped = _shard_map(
+            mapped = jax.shard_map(
                 local_wide, mesh=self.mesh,
                 in_specs=({k: p2 for k in names}, p2, P()),
                 out_specs=(P(), P()), check_vma=False)
@@ -380,7 +375,7 @@ class MeshPlane:
                 flags.append(fits.astype(jnp.uint16))
             return jnp.concatenate(cols16 + [jnp.stack(flags)])
 
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             local_narrow, mesh=self.mesh,
             in_specs=({k: p2 for k in names}, P()),
             out_specs=P(), check_vma=False)

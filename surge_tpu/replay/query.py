@@ -576,8 +576,6 @@ class QueryEngine:
         else:
             from jax.sharding import PartitionSpec as P
 
-            from surge_tpu.replay.jax_compat import shard_map as _shard_map
-
             axis = self.mesh_axis
             pe = P(axis)  # event axis, sharded
             pr = P()      # replicated (predicate values, type filter, output)
@@ -598,7 +596,7 @@ class QueryEngine:
                         out[name] = jax.lax.psum(v, axis)
                 return out
 
-            mapped = _shard_map(
+            mapped = jax.shard_map(
                 sharded, mesh=self.mesh,
                 in_specs=(pe, pe, pe, pr, pr, {n: pe for n in col_names}),
                 out_specs={name: pr for name in

@@ -183,9 +183,7 @@ def _sharded_program(engine, key: frozenset, width: int, bs: int, k_cap: int):
     axis = engine.mesh_axis
     p2 = P(axis, None)
     p3 = P(axis, None, None)
-    from surge_tpu.replay.jax_compat import shard_map as _shard_map
-
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local_fold, mesh=engine.mesh,
         in_specs=({k: p2 for k in
                    (f.name for f in engine.spec.registry.state.fields)},

@@ -333,9 +333,7 @@ def _program(afold: AssociativeFold, mesh, mesh_axis: str, b: int,
 
     p_ev = P(mesh_axis, None)
     ev_names = tuple(k for k, _, _ in ev_shapes)
-    from surge_tpu.replay.jax_compat import shard_map as _shard_map
-
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=({k: p_ev for k in ev_names},
                   {k: P(None, None) for k in init_names}),

@@ -176,7 +176,7 @@ def test_bench_resident_feed_paired_smoke():
 
 def test_bench_ragged_paired_ladder_smoke():
     """SURGE_BENCH_RAGGED=1 (ISSUE 18): the paired interleaved dense vs
-    bucketed vs bucketed+pallas refresh-dispatch ladder plus the donation
+    bucketed refresh-dispatch ladder plus the donation
     probe emit per-arm medians and waste ratios off the ledger, tiny-sized
     here (probe capacity shrunk from 1M to 4096 rows so the smoke stays in
     tier-1 budget; the mesh topology and donate on/off arms still run)."""
@@ -204,7 +204,7 @@ def test_bench_ragged_paired_ladder_smoke():
     ladder = payload["ragged_ladder"]
     assert set(ladder) == {"steady_ragged", "dense_32"}
     for shape, row in ladder.items():
-        for arm in ("dense", "bucketed", "bucketed_pallas"):
+        for arm in ("dense", "bucketed"):
             assert row[arm]["events_per_sec_median"] > 0, (shape, arm)
             assert row[arm]["rounds"]
             assert row[arm]["waste_ratio"] >= 1.0

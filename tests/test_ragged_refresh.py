@@ -1,9 +1,10 @@
 """Bucketed ragged refresh dispatch (ISSUE 18): length-bucketed refresh
-programs, the ragged Pallas fold tile, and donated slab scatters.
+programs and donated slab scatters.
 
 The load-bearing proofs mirror the plane's golden bar: byte-identity vs the
 full cold-start replay across evict/re-admit and a partition rebalance, on
-cpu AND the forced 8-device mesh, for the bucketed and pallas-ragged arms.
+cpu AND the forced 8-device mesh, with the plane seeded through the XLA tile
+and through the Pallas tile scan.
 On top of that: the compile-signature set stays bounded by the layout's
 bucket table under 100 adversarial rounds (dense and bucketed), a donated
 refresh round never surfaces a deleted buffer to any read path (batched
@@ -52,11 +53,10 @@ def make_plane(log, *, capacity=64, ledger=None, mesh=None, overrides=None):
 
 
 def _refresh_sigs(plane):
-    return {s for s in plane._signatures
-            if s[0] in ("refresh", "refresh-ragged")}
+    return {s for s in plane._signatures if s[0] == "refresh"}
 
 
-# -- golden byte-identity: bucketed and pallas-ragged arms ----------------------------
+# -- golden byte-identity: bucketed refresh over an xla- and a pallas-seeded plane ----
 
 
 @pytest.mark.parametrize("overrides", [
@@ -64,7 +64,7 @@ def _refresh_sigs(plane):
     {"surge.replay.resident.refresh-dispatch": "bucketed",
      "surge.replay.tile-backend": "pallas",
      "surge.replay.dispatch": "select"},
-], ids=["bucketed", "bucketed-pallas"])
+], ids=["bucketed", "bucketed-pallas-seed"])
 def test_bucketed_refresh_golden_byte_identity(overrides):
     """Incremental bucketed refresh rounds — across evictions, re-admissions
     AND a partition revoke/re-grant — byte-identical to the full cold-start
@@ -79,8 +79,6 @@ def test_bucketed_refresh_golden_byte_identity(overrides):
         append_events(log, evs)
         led = ReplayLedger(name="engine:t")
         plane = make_plane(log, capacity=8, ledger=led, overrides=overrides)
-        ragged_arm = overrides.get("surge.replay.tile-backend") == "pallas"
-        assert plane._ragged == ragged_arm
         await plane.start()
         try:
             for rnd in range(4):
@@ -114,9 +112,6 @@ def test_bucketed_refresh_golden_byte_identity(overrides):
                 for bk in e["buckets"]:
                     assert 0 < bk["lanes"] <= bk["lanes_b"]
                     assert (bk["lanes_b"], bk["width"]) in plane.bucket_table
-            if ragged_arm:
-                assert any(s[0] == "refresh-ragged"
-                           for s in plane._signatures)
             assert led.summary()["bucket_programs"] == sum(
                 len(e["buckets"]) for e in rounds)
         finally:
@@ -321,12 +316,12 @@ def test_chaos_renders_bucket_anatomy():
         batch=8, width=8, feed_us=10.0, encode_us=5.0, dispatch_us=100.0,
         bucket_table=12,
         buckets=[{"width": 4, "lanes_b": 8, "lanes": 6, "windows": 1,
-                  "dispatched": 32, "occupied": 20, "ragged": True},
+                  "dispatched": 32, "occupied": 20},
                  {"width": 8, "lanes_b": 8, "lanes": 4, "windows": 1,
-                  "dispatched": 96, "occupied": 30, "ragged": None}])
+                  "dispatched": 96, "occupied": 30}])
     text = chaos._render_bucket_anatomy(led.dump())
     assert "bucket_table=12" in text
-    assert "w4×8: lanes 6/8" in text and "ragged" in text
+    assert "w4×8: lanes 6/8" in text
     assert "w8×8: lanes 4/8" in text
     # a dense dump renders nothing
     dense = ReplayLedger(name="engine:t")

@@ -385,7 +385,8 @@ def test_chunked_upload_reassembles_exactly():
 def test_pallas_tile_backend_matches_xla():
     """surge.replay.tile-backend=pallas must fold byte-identically to the XLA
     scan (interpret mode on CPU runs the same kernel program), across models
-    with packed-only (counter) and float-side (bank_account) wires."""
+    with packed-only (counter) and float-side (bank_account) wires and bool
+    state (shopping_cart)."""
     import random
 
     from surge_tpu.codec.tensor import encode_events_columnar
@@ -426,6 +427,51 @@ def test_pallas_tile_backend_matches_xla():
     for name in bouts["xla"].states:
         np.testing.assert_array_equal(bouts["xla"].states[name],
                                       bouts["pallas"].states[name])
+
+    # bool state (shopping_cart) rides the kernel as int32; engine-default
+    # geometry, a lane count that pads up to the 128-lane tiling
+    from surge_tpu.models import shopping_cart
+    from surge_tpu.testing import random_cart_log
+
+    cspec = shopping_cart.make_replay_spec()
+    ccolev = encode_events_columnar(
+        cspec.registry, [random_cart_log(rng, f"c{i}") for i in range(150)])
+    couts = {}
+    for backend in ("xla", "pallas"):
+        eng = ReplayEngine(cspec, config=Config(overrides={
+            "surge.replay.tile-backend": backend}))
+        couts[backend] = eng.replay_resident(eng.prepare_resident(ccolev))
+    assert couts["pallas"].states["checked_out"].dtype == np.bool_
+    assert couts["pallas"].states["checked_out"].any()
+    for name in couts["xla"].states:
+        np.testing.assert_array_equal(couts["xla"].states[name],
+                                      couts["pallas"].states[name])
+
+
+def test_pallas_lane_tiling_and_backend_gate(monkeypatch):
+    """What Mosaic refused on the chip (PR 21), held on the CPU: lane blocks are
+    multiples of 128 that fit VMEM double-buffered, tiles pad to whole blocks,
+    and the kernel interprets on cpu only, compiles on tpu, raises elsewhere."""
+    import jax
+
+    from surge_tpu.replay import pallas_fold
+
+    word = 512 * 4  # one u32 word slab, width 512
+    assert pallas_fold._lane_tiling(8192, word) == (8192, 1024)
+    assert pallas_fold._lane_tiling(1024, word) == (1024, 1024)
+    assert pallas_fold._lane_tiling(3000, word) == (3072, 1024)
+    assert pallas_fold._lane_tiling(64, word) == (128, 128)
+    # bank_account: four 4-byte side columns beside the word -> narrower blocks
+    bs_p, lb = pallas_fold._lane_tiling(3000, 5 * word)
+    assert (bs_p, lb) == (3072, 512)
+    assert 2 * lb * 5 * word <= pallas_fold._VMEM_BUDGET
+
+    assert pallas_fold._interpret() is True  # the suite runs on cpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_fold._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        pallas_fold._interpret()
 
 
 def test_select_dispatch_matches_switch_dispatch():

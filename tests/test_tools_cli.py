@@ -62,15 +62,26 @@ def test_bench_trend_merges_fabricated_series(tmp_path, capsys):
         any("fold_events_per_sec" in line for line in out.splitlines()[:-1])
 
 
-def test_bench_trend_on_real_repo_series(capsys):
-    """The checked-in BENCH_*.json series parses end to end: every file
-    yields a row and at least the ladder medians form a series."""
-    rc = bench_trend.main(["--dir", REPO, "--format", "json"])
+def test_bench_trend_ladder_rounds_form_a_series(tmp_path, capsys):
+    """A multi-round ladder series in the shape of the repo's BENCH_LADDER
+    records parses end to end: every file yields a row, a record without an
+    extractable number included, and the nested ladder medians form one
+    series ordered by round."""
+    for rnd, median in ((6, 800.0), (7, 950.0), (8, 1100.0)):
+        _write(tmp_path / f"BENCH_LADDER_r{rnd:02d}.json",
+               {"protocol": "paired, interleaved arms, medians only",
+                "arms": [{"baseline": {"commands_per_sec_median": median / 2}},
+                         {"candidate": {"commands_per_sec_median": median}}]})
+    _write(tmp_path / "BENCH_HANDOFF_r01.json", {"notes": "no headline"})
+
+    rc = bench_trend.main(["--dir", str(tmp_path), "--format", "json"])
     out = capsys.readouterr().out
     assert rc == 0
     tail = json.loads(out.strip().splitlines()[-1])
-    assert tail["files"] >= 10
-    assert "commands_per_sec_median" in tail["series"]
+    assert tail["files"] == 4
+    medians = tail["series"]["commands_per_sec_median"]
+    assert medians["points"] == 3
+    assert (medians["first"], medians["last"]) == (800.0, 1100.0)
 
 
 def test_bench_trend_rejects_missing_dir(tmp_path, capsys):

@@ -241,7 +241,7 @@ def _render_bucket_anatomy(payload) -> str:
     """Per-round bucket fill + waste columns off a ledger envelope (ISSUE
     18's bucketed ragged dispatch): one line per round with buckets, then
     one line per bucket program (`w<width>×<lanes_b>` lanes dealt / lane
-    slots, slot fill, waste, ragged-tile flag). Empty string when no round
+    slots, slot fill, waste). Empty string when no round
     in the dump carried bucket anatomy (dense or pre-bucketing engines)."""
     lines = []
     for ev in payload.get("events", []):
@@ -261,8 +261,6 @@ def _render_bucket_anatomy(payload) -> str:
             if disp:
                 lines[-1] += (f" waste={disp / occ:.2f}" if occ
                               else " waste=-")
-                if bk.get("ragged"):
-                    lines[-1] += " ragged"
     return "\n".join(lines)
 
 

@@ -6,7 +6,7 @@ engine admin endpoint (or reads one saved earlier as JSON), extracts the
 roofline summary (measured fold ev/s, µs/slot, µs/event, padding-waste
 ratio) and appends ONE JSON line to the trajectory file — append-only, so
 the file accumulates the machine's measured history across runs and a
-regression shows as a row, not a reverted doc table (docs/roofline.md)::
+regression shows as a row, not a reverted doc table::
 
     python tools/roofline_record.py --engine 127.0.0.1:7001 \
         --out roofline.jsonl --note "post PR-16"
@@ -14,8 +14,8 @@ regression shows as a row, not a reverted doc table (docs/roofline.md)::
     python tools/roofline_record.py ledger_dump.json --out roofline.jsonl \
         --compare steady-ragged-cpu
 
-``--compare`` prints measured/published ratios against a docs/roofline.md
-anchor figure (1.0 = the published wall holds). Exit code 0 on success, 2 on
+``--compare`` prints measured/published ratios against an anchor figure of
+``surge_tpu.observability.roofline.REFERENCE`` (1.0 = the anchor holds). Exit code 0 on success, 2 on
 bad input or an engine without the observatory.
 """
 
@@ -58,7 +58,7 @@ def main(argv=None) -> int:
     ap.add_argument("--note", default="", help="free-form row annotation")
     ap.add_argument("--compare", metavar="ANCHOR",
                     help="print measured/published ratios against a "
-                         "docs/roofline.md anchor (e.g. steady-ragged-cpu)")
+                         "REFERENCE anchor (e.g. steady-ragged-cpu)")
     args = ap.parse_args(argv)
 
     if bool(args.dump) == bool(args.engine):
