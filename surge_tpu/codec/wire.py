@@ -174,9 +174,18 @@ class WireFormat:
         ``wire_bytes_per_event()`` per real event, no window padding at all.
         The device slices per-aggregate slabs from it (see
         :meth:`decode_words`)."""
-        word = self._pack_words(type_ids,
-                                {pf.name: cols[pf.name]
-                                 for pf in self.packed_fields})
+        return self.split_flat(self.flat_words(type_ids, cols), cols)
+
+    def flat_words(self, type_ids: np.ndarray, cols: Mapping[str, np.ndarray]
+                   ) -> np.ndarray:
+        """First half of :meth:`pack_flat`: one packed word per event ``[N]``."""
+        return self._pack_words(type_ids, {pf.name: cols[pf.name]
+                                           for pf in self.packed_fields})
+
+    def split_flat(self, word: np.ndarray, cols: Mapping[str, np.ndarray]
+                   ) -> tuple[np.ndarray, dict[str, np.ndarray]]:
+        """Second half of :meth:`pack_flat`: the words' bytes as ``[N,
+        nbytes]`` and the side columns in their wire dtypes."""
         n = word.shape[0]
         packed = np.empty((n, self.nbytes), dtype=np.uint8)
         for k in range(self.nbytes):

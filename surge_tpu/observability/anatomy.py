@@ -37,8 +37,8 @@ span's wall time into named legs along the ack critical path:
   ``decode`` — the DEVICE legs (the fold anatomy, ISSUE 16): resident-plane
   ``resident.gather`` and engine ``query.scan`` spans carry measured
   ``leg.{coalesce,dispatch,fetch,decode}-ms`` attributes, and the replay
-  profiler's ``replay.dispatch``/``replay.compile``/``replay.fetch`` stage
-  spans map by name — so a stalled refresh dispatch names
+  profiler's ``replay.dispatch``/``replay.compile``/``replay.densify``/
+  ``replay.fetch`` stage spans map by name — so a stalled refresh dispatch names
   ``device-dispatch`` dominant the same way a slow WAL names
   ``journal-fsync``;
 - ``other`` — root residue none of the above claims (reply fan-out, event
@@ -93,11 +93,14 @@ _DEVICE_ATTR_LEGS = (("leg.coalesce-ms", "gather-coalesce"),
                      ("leg.decode-ms", "decode"))
 
 #: replay-profiler stage spans carry no leg attributes — their whole
-#: duration IS the leg, mapped by name (host stages encode/h2d stay in
-#: ``other``: they are not device legs)
-_DEVICE_NAME_LEGS = (("replay.dispatch", "device-dispatch"),
-                     ("replay.compile", "device-dispatch"),
-                     ("replay.fetch", "fetch-barrier"))
+#: duration IS the leg, mapped by their exact name: a stage's children
+#: (``replay.fetch.wait``, ``replay.fetch.decode``) lie inside it and claim
+#: nothing of their own (host stages encode/h2d/plan stay in ``other``: they
+#: are not device legs)
+_DEVICE_NAME_LEGS = {"replay.dispatch": "device-dispatch",
+                     "replay.compile": "device-dispatch",
+                     "replay.densify": "device-dispatch",
+                     "replay.fetch": "fetch-barrier"}
 
 
 def _place(span: dict, offset: Optional[float]) -> dict:
@@ -233,11 +236,8 @@ def attribute_trace(spans: Sequence[dict]) -> Optional[dict]:
                     claimed = True
                 except (TypeError, ValueError):
                     pass
-        if not claimed:
-            for prefix, leg in _DEVICE_NAME_LEGS:
-                if name.startswith(prefix):
-                    legs[leg] += _dur(s)
-                    break
+        if not claimed and name in _DEVICE_NAME_LEGS:
+            legs[_DEVICE_NAME_LEGS[name]] += _dur(s)
     # client-observed broker time the broker itself does not account for:
     # wire + request encode + reply decode
     if client_calls and broker_spans:

@@ -14,7 +14,6 @@ Scale discipline (SURVEY.md §7 hard-part 2, BASELINE.md 1M-aggregate/100M-event
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass, field as dc_field
 from typing import Any, Callable, Iterable, Mapping, Optional, Sequence
 
@@ -32,6 +31,15 @@ from surge_tpu.codec.tensor import (
 from surge_tpu.codec.wire import WireFormat
 from surge_tpu.config import Config, default_config
 from surge_tpu.engine.model import ReplaySpec, StateTree
+from surge_tpu.replay.profiler import ReplayProfiler
+from surge_tpu.tracing import SpanContext, default_tracer
+
+#: the functions the cold path's ``jax.jit`` programs are made from, by name.
+#: XLA names a program ``jit_<function>``, and the benchmark's trace reduction
+#: maps programs to layers by those names (benchmarks/programs/cold-fold.json):
+#: renaming one unmaps its program. Held by tests/test_replay_spans.py.
+COLD_PATH_JIT_NAMES = ("densify", "fold", "finalize_narrow", "finalize_wide",
+                       "mk")
 
 #: the checkout's own persistent compile cache (listed in .gitignore). The
 #: path is part of every cache key, so it is fixed: never a temp, pid or
@@ -163,6 +171,9 @@ class ResidentCorpus:
     #: per-corpus device caches (tile plan, dense tile buffers, worklists) —
     #: populated lazily by the engine, keyed by plan geometry
     cache: dict = dc_field(default_factory=dict)
+    #: context of the ``replay.h2d`` span that uploaded it: a fold of this
+    #: corpus continues that trace
+    trace_ctx: Optional[SpanContext] = None
 
 
 #: minimum guard rows appended past the wire corpus, so a wire packed under a
@@ -419,6 +430,11 @@ def _unapply_perm(perm: Optional[np.ndarray],
     return out
 
 
+def _wire_nbytes(packed, side: Mapping[str, Any]) -> int:
+    """Bytes of a wire's event buffers: the packed rows and the side columns."""
+    return int(packed.nbytes + sum(v.nbytes for v in side.values()))
+
+
 def _bucket_len(n: int) -> int:
     """Next power of two ≥ n (min 64Ki) — the bucketed buffer length."""
     target = 1 << 16
@@ -460,6 +476,9 @@ class ResidentWire:
     #: wires saved before fingerprints existed (upload falls back to the
     #: structural byte/side checks)
     layout: Optional[dict] = None
+    #: context of the ``replay.encode`` span that packed it, so the upload
+    #: continues that trace; not saved (a loaded wire starts a new trace)
+    trace_ctx: Optional[SpanContext] = None
 
     def save(self, root: str) -> None:
         import json
@@ -522,6 +541,10 @@ class ResidentPlan:
         return (len(self.big_i0) * self.bs_big
                 + len(self.small_i0) * self.bs_small) * self.width
 
+    @property
+    def tiles(self) -> int:
+        return len(self.big_i0) + len(self.small_i0)
+
 
 @dataclass
 class ReplayResult:
@@ -550,6 +573,9 @@ class ReplayEngine:
     spec: the model's ReplaySpec.
     config: batch size / time chunk / bucket knobs (``surge.replay.*``).
     mesh: optional ``jax.sharding.Mesh``; batch dim B is sharded over ``mesh_axis``.
+    profiler: the :class:`~surge_tpu.replay.profiler.ReplayProfiler` every stage
+        is timed through; ``None`` builds a counter-only one over the
+        process-wide ring (:func:`surge_tpu.tracing.default_tracer`).
     """
 
     def __init__(self, spec: ReplaySpec, config: Config | None = None,
@@ -560,9 +586,8 @@ class ReplayEngine:
         self.spec = spec
         self.config = config or default_config()
         self.mesh = mesh
-        # optional surge_tpu.replay.profiler.ReplayProfiler: every hook below
-        # is behind one `is None` check so the default path pays nothing
-        self.profiler = profiler
+        self.profiler = (profiler if profiler is not None else
+                         ReplayProfiler.counters(tracer=default_tracer()))
         # batch-axis name: explicit arg > surge.replay.mesh-axes (first entry)
         if mesh_axis is None:
             mesh_axis = (self.config.get_str("surge.replay.mesh-axes", "data")
@@ -619,8 +644,10 @@ class ReplayEngine:
         # to one XLA compilation (shapes are static under jit), counted without any
         # private JAX internals
         self._signatures: set = set()
-        # host-side phase accounting (bench breakdown): seconds spent wire-packing
-        # and explicitly transferring windows, and windows dispatched
+        # host-side phase accounting (bench breakdown), fed by the profiler's
+        # stages: seconds of the encode stages (a window's pack, the whole of
+        # pack_resident), of the h2d stages (a window's transfer, the whole of
+        # upload_resident) and of the densify dispatches, and windows dispatched
         self.stats = {"pack_s": 0.0, "h2d_s": 0.0, "windows": 0,
                       "densify_s": 0.0}
         if mesh is not None:
@@ -669,15 +696,6 @@ class ReplayEngine:
         return key, wire, jitted
 
     # -- helpers ------------------------------------------------------------------------
-
-    def _fetch_stage(self):
-        """Profiler context for a device→host state pull (the fetch barrier
-        that closes the chunk's device time); no-op without a profiler."""
-        if self.profiler is None:
-            from contextlib import nullcontext
-
-            return nullcontext()
-        return self.profiler.stage("fetch")
 
     def _carry_struct(self) -> StateTree:
         return {f.name: None for f in self.spec.registry.state.fields}
@@ -770,7 +788,7 @@ class ReplayEngine:
                 {k: v[start:stop] for k, v in enc.cols.items()}, bs,
                 derived_cols=enc.derived_cols,
                 ordinal_base=None if ordinal_base is None else ordinal_base[start:stop])
-            with self._fetch_stage():
+            with self.profiler.stage("fetch"):
                 for name in out:
                     out[name][start:stop] = np.asarray(carry[name])[: stop - start]
             padded += bs * scanned
@@ -829,7 +847,7 @@ class ReplayEngine:
             carry, scanned = self._fold_window(carry, enc.type_ids, enc.cols, bs,
                                                derived_cols=enc.derived_cols,
                                                ordinal_base=ob)
-            with self._fetch_stage():
+            with self.profiler.stage("fetch"):
                 for name in out:
                     chunk_states = np.asarray(carry[name])[: stop - start]
                     if idxs is None:
@@ -913,34 +931,28 @@ class ReplayEngine:
         if ordinal_base is not None:
             base[:b] = np.asarray(ordinal_base, dtype=np.int32)[:b]
         scanned = 0
+        stage = self.profiler.stage
         for s, width in self._window_plan(t):
             e = min(s + width, t)
-            t0 = time.perf_counter()
-            packed, side = wire.pack_window(type_ids, cols, s, e, width, bs)
-            ord_base = base + np.int32(t_base + s)
-            t1 = time.perf_counter()
-            window = self._device_window(packed, side, ord_base)
-            t2 = time.perf_counter()
-            self.stats["pack_s"] += t1 - t0
-            self.stats["h2d_s"] += t2 - t1
+            with stage("encode", width=width) as enc:
+                packed, side = wire.pack_window(type_ids, cols, s, e, width, bs)
+                ord_base = base + np.int32(t_base + s)
+            with stage("h2d", width=width) as h2d:
+                window = self._device_window(packed, side, ord_base)
+            self.stats["pack_s"] += enc.seconds
+            self.stats["h2d_s"] += h2d.seconds
             self.stats["windows"] += 1
+            self.profiler.count_windows()
             scanned += width
             sig = (key, packed.shape,
                    tuple((k, v.shape) for k, v in sorted(side.items())))
             first_dispatch = sig not in self._signatures
             self._signatures.add(sig)
-            if self.profiler is None:
+            # a fresh signature means this dispatch pays the XLA compile;
+            # steady dispatches only pay the async host-side handoff
+            with stage("compile" if first_dispatch else "dispatch",
+                       width=width, batch=bs):
                 carry = fold(carry, *window)
-            else:
-                self.profiler.count_windows()
-                self.profiler.record("encode", t1 - t0, width=width)
-                self.profiler.record("h2d", t2 - t1, width=width)
-                # a fresh signature means this dispatch pays the XLA compile;
-                # steady dispatches only pay the async host-side handoff
-                with self.profiler.stage(
-                        "compile" if first_dispatch else "dispatch",
-                        width=width, batch=bs):
-                    carry = fold(carry, *window)
         return carry, scanned
 
     # -- resident-corpus path (single upload, on-device densify) ------------------------
@@ -960,63 +972,74 @@ class ReplayEngine:
         tile gathers from per-lane bases — so the 100M-event stable sort plus
         three full-column gathers the old path paid (~17 s of a ~26 s pack at
         bench scale) disappear; only the O(B) length argsort remains."""
+        stage = self.profiler.stage
         b = colev.num_aggregates
-        agg = np.asarray(colev.agg_idx)
-        lengths = np.bincount(agg, minlength=b).astype(np.int64)
-        if self.sort_by_length and b > 1:
-            # DESCENDING by length: the lanes still active after t events form a
-            # prefix, so each tile round dispatches a contiguous lane range
-            perm = np.argsort(-lengths, kind="stable").astype(np.int32)
-            if np.array_equal(perm, np.arange(b, dtype=np.int32)):
-                perm = None
-        else:
-            perm = None
+        with stage("encode", events=colev.num_events, aggregates=b) as enc:
+            with stage("encode.lanes"):
+                agg = np.asarray(colev.agg_idx)
+                lengths = np.bincount(agg, minlength=b).astype(np.int64)
+                if self.sort_by_length and b > 1:
+                    # DESCENDING by length: the lanes still active after t
+                    # events form a prefix, so each tile round dispatches a
+                    # contiguous lane range
+                    perm = np.argsort(-lengths, kind="stable").astype(np.int32)
+                    if np.array_equal(perm, np.arange(b, dtype=np.int32)):
+                        perm = None
+                else:
+                    perm = None
 
-        grouped = bool((np.diff(agg) >= 0).all()) if agg.size > 1 else True
-        if grouped:
-            to_pack = colev
-        else:
-            # ungrouped input: materialize the sorted order (rare —
-            # interleaved hand-built columns); lanes end up buffer-contiguous
-            if perm is not None:
-                inv = np.empty_like(perm)
-                inv[perm] = np.arange(b, dtype=np.int32)
-                colev = ColumnarEvents(
-                    num_aggregates=b, agg_idx=inv[colev.agg_idx],
-                    type_ids=colev.type_ids, cols=colev.cols,
-                    derived_cols=dict(colev.derived_cols))
-                lengths = lengths[perm]
-            to_pack = colev.sorted_by_aggregate()
+                grouped = (bool((np.diff(agg) >= 0).all()) if agg.size > 1
+                           else True)
+                if grouped:
+                    to_pack = colev
+                else:
+                    # ungrouped input: materialize the sorted order (rare —
+                    # interleaved hand-built columns); lanes end up
+                    # buffer-contiguous
+                    if perm is not None:
+                        inv = np.empty_like(perm)
+                        inv[perm] = np.arange(b, dtype=np.int32)
+                        colev = ColumnarEvents(
+                            num_aggregates=b, agg_idx=inv[colev.agg_idx],
+                            type_ids=colev.type_ids, cols=colev.cols,
+                            derived_cols=dict(colev.derived_cols))
+                        lengths = lengths[perm]
+                    to_pack = colev.sorted_by_aggregate()
 
-        wire = WireFormat(self.spec.registry, dict(to_pack.derived_cols))
-        t0 = time.perf_counter()
-        packed, side_flat = wire.pack_flat(to_pack.type_ids, to_pack.cols)
-        # tail padding so every [start + t_base, width) slab slice stays in
-        # bounds without clamping (clamped slices would shift lane data);
-        # content is irrelevant — slots past lens decode to the pad sentinel
-        guard = max(self.resident_tile_width(), _WIRE_GUARD_MIN)
-        packed = np.pad(packed, ((0, guard), (0, 0)))
-        side_flat = {k: np.pad(v, (0, guard)) for k, v in side_flat.items()}
-        pack_elapsed = time.perf_counter() - t0
-        self.stats["pack_s"] += pack_elapsed
-        if self.profiler is not None:
-            self.profiler.record("encode", pack_elapsed,
-                                 events=to_pack.num_events, kind="pack_resident")
-        # lengths/starts are in the PACKED stream's aggregate-id order; the
-        # grouped path then permutes the lane VIEW only (indirection), the
-        # ungrouped path already permuted the stream itself
-        starts = np.zeros(b + 1, dtype=np.int64)
-        np.cumsum(lengths, out=starts[1:])
-        starts_lane, lens_lane = starts[:-1], lengths
-        if grouped and perm is not None:
-            starts_lane = starts_lane[perm]
-            lens_lane = lengths[perm]
-        return ResidentWire(
-            derived_key=dict(to_pack.derived_cols), packed=packed,
-            side=side_flat, starts=starts_lane.astype(np.int32),
-            lengths=lens_lane.astype(np.int32), perm=perm, guard=guard,
-            num_events=to_pack.num_events,
-            layout=wire.layout_fingerprint())
+            wire = WireFormat(self.spec.registry, dict(to_pack.derived_cols))
+            with stage("encode.words"):
+                word = wire.flat_words(to_pack.type_ids, to_pack.cols)
+            with stage("encode.bytes"):
+                packed, side_flat = wire.split_flat(word, to_pack.cols)
+                del word
+            with stage("encode.guard"):
+                # tail padding so every [start + t_base, width) slab slice
+                # stays in bounds without clamping (clamped slices would shift
+                # lane data); content is irrelevant — slots past lens decode
+                # to the pad sentinel
+                guard = max(self.resident_tile_width(), _WIRE_GUARD_MIN)
+                packed = np.pad(packed, ((0, guard), (0, 0)))
+                side_flat = {k: np.pad(v, (0, guard))
+                             for k, v in side_flat.items()}
+                # lengths/starts are in the PACKED stream's aggregate-id
+                # order; the grouped path then permutes the lane VIEW only
+                # (indirection), the ungrouped path already permuted the
+                # stream itself
+                starts = np.zeros(b + 1, dtype=np.int64)
+                np.cumsum(lengths, out=starts[1:])
+                starts_lane, lens_lane = starts[:-1], lengths
+                if grouped and perm is not None:
+                    starts_lane = starts_lane[perm]
+                    lens_lane = lengths[perm]
+                out = ResidentWire(
+                    derived_key=dict(to_pack.derived_cols), packed=packed,
+                    side=side_flat, starts=starts_lane.astype(np.int32),
+                    lengths=lens_lane.astype(np.int32), perm=perm, guard=guard,
+                    num_events=to_pack.num_events,
+                    layout=wire.layout_fingerprint(), trace_ctx=enc.context)
+            enc.set_attribute("wire_bytes", _wire_nbytes(packed, side_flat))
+        self.stats["pack_s"] += enc.seconds
+        return out
 
     def check_wire(self, w: "ResidentWire") -> WireFormat:
         """Validate a (possibly disk-loaded) wire against this engine: guard
@@ -1067,48 +1090,48 @@ class ReplayEngine:
                 "this engine is mesh-backed; use prepare_resident_sharded / "
                 "replay_resident_sharded for the resident path")
         self.check_wire(w)
-        import jax
-
+        stage = self.profiler.stage
         b = w.lengths.shape[0]
-        t0 = time.perf_counter()
-        pow2 = self.config.get_str(
-            "surge.replay.resident-len-bucket", "pow2") == "pow2"
-        packed_b = _bucket_rows(w.packed, pow2)
-        side_b = {k: _bucket_rows(v, pow2) for k, v in w.side.items()}
-        # chunked H2D: on a high-latency link a single large put can fall
-        # off the fast path; pieces upload pipelined and are reassembled
-        # on-device with one concatenate
-        chunk_mb = self.config.get_int("surge.replay.upload-chunk-mb", 0)
-        flat_wire = _chunked_put(packed_b, chunk_mb)
-        flat_side = {k: _chunked_put(v, chunk_mb) for k, v in side_b.items()}
-        bs = min(self.batch_size, _round_up(max(b, 1), self._lane_multiple()))
-        b_pad = _round_up(max(b, 1), bs)
-        if pow2:
-            chunks = 1
-            while chunks * bs < b_pad:
-                chunks *= 2
-            b_pad = chunks * bs
-        starts_p = np.zeros((b_pad,), dtype=np.int32)
-        starts_p[:b] = w.starts
-        lens_p = np.zeros((b_pad,), dtype=np.int32)
-        lens_p[:b] = w.lengths
-        starts_dev = jax.device_put(starts_p)
-        lens_dev = jax.device_put(lens_p)
-        jax.block_until_ready(flat_wire)
-        upload_s = time.perf_counter() - t0
-        self.stats["h2d_s"] += upload_s
-        if self.profiler is not None:
-            self.profiler.record(
-                "h2d", upload_s, kind="upload_resident",
-                bytes=packed_b.nbytes + sum(v.nbytes for v in side_b.values()))
+        with stage("h2d", follows=w.trace_ctx,
+                   wire_bytes=_wire_nbytes(w.packed, w.side)) as h2d:
+            with stage("h2d.bucket"):
+                pow2 = self.config.get_str(
+                    "surge.replay.resident-len-bucket", "pow2") == "pow2"
+                packed_b = _bucket_rows(w.packed, pow2)
+                side_b = {k: _bucket_rows(v, pow2) for k, v in w.side.items()}
+                put_bytes = _wire_nbytes(packed_b, side_b)
+                bs = min(self.batch_size,
+                         _round_up(max(b, 1), self._lane_multiple()))
+                b_pad = _round_up(max(b, 1), bs)
+                if pow2:
+                    chunks = 1
+                    while chunks * bs < b_pad:
+                        chunks *= 2
+                    b_pad = chunks * bs
+                starts_p = np.zeros((b_pad,), dtype=np.int32)
+                starts_p[:b] = w.starts
+                lens_p = np.zeros((b_pad,), dtype=np.int32)
+                lens_p[:b] = w.lengths
+            with stage("h2d.put", put_bytes=put_bytes):
+                # chunked H2D: on a high-latency link a single large put can
+                # fall off the fast path; pieces upload pipelined and are
+                # reassembled on-device with one concatenate
+                chunk_mb = self.config.get_int("surge.replay.upload-chunk-mb", 0)
+                flat_wire = _chunked_put(packed_b, chunk_mb)
+                flat_side = {k: _chunked_put(v, chunk_mb)
+                             for k, v in side_b.items()}
+                starts_dev = jax.device_put(starts_p)
+                lens_dev = jax.device_put(lens_p)
+                jax.block_until_ready(flat_wire)
+            h2d.set_attribute("put_bytes", put_bytes)
+        self.stats["h2d_s"] += h2d.seconds
         return ResidentCorpus(
             derived_key=dict(w.derived_key), flat_wire=flat_wire,
             flat_side=flat_side, starts=w.starts,
             lengths=w.lengths, perm=w.perm,
             starts_dev=starts_dev, lens_dev=lens_dev, b_pad=b_pad,
-            num_events=w.num_events,
-            wire_bytes=packed_b.nbytes + sum(v.nbytes for v in side_b.values()),
-            upload_s=upload_s)
+            num_events=w.num_events, wire_bytes=put_bytes,
+            upload_s=h2d.seconds, trace_ctx=h2d.context)
 
     def prepare_resident_sharded(self, source):
         """Mesh form of :meth:`prepare_resident`: deal the packed corpus's
@@ -1248,29 +1271,18 @@ class ReplayEngine:
             return ReplayResult(states={f.name: np.zeros((0,), dtype=f.dtype)
                                         for f in self.spec.registry.state.fields},
                                 num_aggregates=0, num_events=0, padded_events=0)
-        perm = resident.perm
-        init_sorted, ord_sorted = _apply_perm(perm, init_carry, ordinal_base)
-        if self.profiler is None:
-            slab, padded = self._dispatch_resident(resident, init_sorted,
-                                                   ord_sorted)
-            # the single synchronization of the whole replay
-            states = self._pull_states(slab, b, resident.perm, resident.cache)
-        else:
-            with self.profiler.replay_pass("replay.resident", aggregates=b,
-                                           events=resident.num_events):
-                n0 = self.num_compiles()
-                t0 = time.perf_counter()
-                slab, padded = self._dispatch_resident(resident, init_sorted,
-                                                       ord_sorted)
-                self.profiler.record(
-                    "compile" if self.num_compiles() > n0 else "dispatch",
-                    time.perf_counter() - t0, aggregates=b)
-                # the fetch stage IS the single sync: a real device→host pull
-                # whose data dependency closes every chained tile program
-                # (fetch-barrier discipline — never block_until_ready)
-                with self.profiler.stage("fetch", aggregates=b):
-                    states = self._pull_states(slab, b, resident.perm,
-                                               resident.cache)
+        stage = self.profiler.stage
+        with stage("resident", follows=resident.trace_ctx, aggregates=b,
+                   events=resident.num_events) as umbrella:
+            slab, padded = self._dispatch_resident(resident, init_carry,
+                                                   ordinal_base, umbrella)
+            # the fetch stage IS the single sync of the whole replay: a real
+            # device→host pull whose data dependency closes every chained
+            # tile program (fetch-barrier discipline — never
+            # block_until_ready)
+            with stage("fetch", aggregates=b):
+                states = self._pull_states(slab, b, resident.perm,
+                                           resident.cache)
         return ReplayResult(
             states=states,
             num_aggregates=b, num_events=resident.num_events,
@@ -1291,9 +1303,12 @@ class ReplayEngine:
         STAYS on device — the caller gathers rows into its own slab with zero
         device→host traffic. ``init_carry``/``ordinal_base`` are in the
         original aggregate order, exactly like :meth:`replay_resident`."""
-        init_sorted, ord_sorted = _apply_perm(resident.perm, init_carry,
-                                              ordinal_base)
-        return self._dispatch_resident(resident, init_sorted, ord_sorted)
+        with self.profiler.stage(
+                "resident", follows=resident.trace_ctx,
+                aggregates=resident.lengths.shape[0],
+                events=resident.num_events) as umbrella:
+            return self._dispatch_resident(resident, init_carry, ordinal_base,
+                                           umbrella)
 
     def _pull_states(self, slab: Mapping[str, Any], b: int,
                      perm: Optional[np.ndarray],
@@ -1305,12 +1320,15 @@ class ReplayEngine:
         column. ``cache`` (a per-corpus dict) memoizes the device
         inverse-perm; omit it for throwaway corpora (streamed pieces).
         """
+        stage = self.profiler.stage
         fields = self.spec.registry.state.fields
         if any(np.dtype(f.dtype).itemsize > 4 for f in fields):
             # >32-bit columns don't fit the u32 packing — per-field pull
-            out_sorted = {name: np.asarray(col)[:b]
-                          for name, col in slab.items()}
-            return _unapply_perm(perm, out_sorted)
+            with stage("fetch.wait"):
+                out_sorted = {name: np.asarray(col)[:b]
+                              for name, col in slab.items()}
+            with stage("fetch.decode"):
+                return _unapply_perm(perm, out_sorted)
         inv = cache.get("invperm") if cache is not None else None
         if inv is None:
             if perm is not None:
@@ -1362,8 +1380,14 @@ class ReplayEngine:
                     out[f.name] = raw.view(dt).copy()
             return out
 
+        def pull_wide():
+            with stage("fetch.wait", wire="wide"):
+                mat = np.asarray(wide_prog(slab, inv))
+            with stage("fetch.decode"):
+                return decode_wide(mat)
+
         if not narrow_ok:
-            return decode_wide(np.asarray(wide_prog(slab, inv)))
+            return pull_wide()
 
         narrow_prog = self._finalize_programs.get("narrow")
         if narrow_prog is None:
@@ -1390,83 +1414,110 @@ class ReplayEngine:
             narrow_prog = jax.jit(finalize_narrow)
             self._finalize_programs["narrow"] = narrow_prog
 
-        buf16 = np.asarray(narrow_prog(slab, inv))  # the one device→host fetch
+        with stage("fetch.wait", wire="narrow"):
+            # the one device→host fetch
+            buf16 = np.asarray(narrow_prog(slab, inv))
         nf = len(fields)
         if not buf16[nf * b:].all():
             # a column overflowed 16 bits — refetch wide (extra round trip,
             # still exact)
-            return decode_wide(np.asarray(wide_prog(slab, inv)))
-        out: dict[str, np.ndarray] = {}
-        for i, f in enumerate(fields):
-            dt = np.dtype(f.dtype)
-            raw = buf16[i * b: (i + 1) * b]
-            if dt == np.bool_:
-                out[f.name] = raw.astype(dt)
-            elif np.issubdtype(dt, np.signedinteger):
-                out[f.name] = raw.view(np.int16).astype(dt)
-            else:
-                out[f.name] = raw.astype(dt)
-        return out
+            return pull_wide()
+        with stage("fetch.decode"):
+            out: dict[str, np.ndarray] = {}
+            for i, f in enumerate(fields):
+                dt = np.dtype(f.dtype)
+                raw = buf16[i * b: (i + 1) * b]
+                if dt == np.bool_:
+                    out[f.name] = raw.astype(dt)
+                elif np.issubdtype(dt, np.signedinteger):
+                    out[f.name] = raw.view(np.int16).astype(dt)
+                else:
+                    out[f.name] = raw.astype(dt)
+            return out
 
     def _dispatch_resident(self, resident: "ResidentCorpus",
-                           init_sorted: Mapping[str, np.ndarray] | None,
-                           ord_sorted: np.ndarray | None
-                           ) -> tuple[dict, int]:
+                           init_carry: Mapping[str, Any] | None,
+                           ordinal_base: np.ndarray | None,
+                           umbrella=None) -> tuple[dict, int]:
         """Dispatch the whole fold of one resident corpus WITHOUT syncing:
         returns the (device) state slab and the padded-slot count. ``init``/
-        ``ordinal`` inputs are already in the corpus's sorted lane order."""
+        ``ordinal`` inputs are in the order ``resident.perm`` maps from (the
+        original aggregate order; a corpus without a perm takes them as its
+        lanes lie). ``umbrella``, the caller's open ``replay.resident`` span,
+        is given the plan's counts."""
+        stage = self.profiler.stage
         b = resident.lengths.shape[0]
-        plan = self._plan_for(resident)
         b_pad = resident.b_pad
         key = frozenset(resident.derived_key.items())
 
-        if init_sorted is None and ord_sorted is None:
-            # fresh replay: build the init slab ON DEVICE (no host transfer
-            # on the replay's critical path)
-            slab, ord_d = self._fresh_slab(b_pad)
-        else:
-            ord_p = np.zeros((b_pad,), dtype=np.int32)
-            if ord_sorted is not None:
-                ord_p[:b] = np.asarray(ord_sorted).astype(np.int32)
-            slab_np = self.init_carry_np(b_pad)
-            if init_sorted is not None:
-                for k, full in init_sorted.items():
-                    slab_np[k][:b] = np.asarray(full)
-            slab = {k: jnp.asarray(v) for k, v in slab_np.items()}
-            ord_d = jnp.asarray(ord_p)
+        with stage("plan"):
+            init_sorted, ord_sorted = _apply_perm(resident.perm, init_carry,
+                                                  ordinal_base)
+            plan = self._plan_for(resident)
+            if umbrella is not None:
+                umbrella.set_attribute("padded_slots", plan.padded_slots)
+                umbrella.set_attribute("tiles", plan.tiles)
+            if init_sorted is None and ord_sorted is None:
+                # fresh replay: build the init slab ON DEVICE (no host
+                # transfer on the replay's critical path); its dispatch is
+                # part of the plan stage
+                slab, ord_d = self._fresh_slab(b_pad)
+            else:
+                ord_p = np.zeros((b_pad,), dtype=np.int32)
+                if ord_sorted is not None:
+                    ord_p[:b] = np.asarray(ord_sorted).astype(np.int32)
+                slab_np = self.init_carry_np(b_pad)
+                if init_sorted is not None:
+                    for k, full in init_sorted.items():
+                        slab_np[k][:b] = np.asarray(full)
+                slab = {k: jnp.asarray(v) for k, v in slab_np.items()}
+                ord_d = jnp.asarray(ord_p)
+            use_dense = self._use_dense(resident, plan)
+            # one work list per lane granularity; the dense layout keeps its
+            # own beside the tiles (_dense_tiles)
+            work = []
+            for bs, i0s, t_bases in ((plan.bs_big, plan.big_i0, plan.big_tb),
+                                     (plan.bs_small, plan.small_i0,
+                                      plan.small_tb)):
+                k_n = len(i0s)
+                if k_n == 0:
+                    continue
+                k_cap = self._plan_cap(k_n)
+                lists = None
+                if not use_dense:
+                    i0s_p = np.zeros((k_cap,), dtype=np.int32)
+                    i0s_p[:k_n] = i0s
+                    tb_p = np.zeros((k_cap,), dtype=np.int32)
+                    tb_p[:k_n] = t_bases
+                    lists = (jnp.asarray(i0s_p), jnp.asarray(tb_p))
+                work.append((bs, i0s, t_bases, k_cap, lists))
 
-        use_dense = self._use_dense(resident, plan)
         # two chained dispatches (big tiles, then small); per-lane order holds
         # because a lane only ever migrates big→small as the prefix shrinks
-        for bs, i0s, t_bases in ((plan.bs_big, plan.big_i0, plan.big_tb),
-                                 (plan.bs_small, plan.small_i0, plan.small_tb)):
+        for bs, i0s, t_bases, k_cap, lists in work:
             k_n = len(i0s)
-            if k_n == 0:
-                continue
-            k_cap = self._plan_cap(k_n)
             self.stats["windows"] += k_n
-            if self.profiler is not None:
-                self.profiler.count_windows(k_n)
+            self.profiler.count_windows(k_n)
             if use_dense:
                 dw, ds, i0s_d, tbs_d = self._dense_tiles(
                     resident, plan, bs, i0s, t_bases, k_cap)
                 fold = self._resident_program_dense(key, plan.width, bs,
                                                     k_cap)
-                self._signatures.add(("resident-dense", key, plan.width, bs,
-                                      k_cap, b_pad))
-                slab = fold(slab, dw, ds, resident.lens_dev, ord_d,
-                            i0s_d, tbs_d)
-                continue
-            fold = self._resident_program(key, plan.width, bs, k_cap)
-            i0s_p = np.zeros((k_cap,), dtype=np.int32)
-            i0s_p[:k_n] = i0s
-            tb_p = np.zeros((k_cap,), dtype=np.int32)
-            tb_p[:k_n] = t_bases
-            self._signatures.add(("resident", key, plan.width, bs, k_cap,
-                                  b_pad, int(resident.flat_wire.shape[0])))
-            slab = fold(slab, resident.flat_wire, resident.flat_side,
+                sig = ("resident-dense", key, plan.width, bs, k_cap, b_pad)
+                args = (dw, ds, resident.lens_dev, ord_d, i0s_d, tbs_d)
+            else:
+                fold = self._resident_program(key, plan.width, bs, k_cap)
+                sig = ("resident", key, plan.width, bs, k_cap, b_pad,
+                       int(resident.flat_wire.shape[0]))
+                args = (resident.flat_wire, resident.flat_side,
                         resident.starts_dev, resident.lens_dev, ord_d,
-                        jnp.asarray(i0s_p), jnp.asarray(tb_p), np.int32(k_n))
+                        *lists, np.int32(k_n))
+            # a fresh signature means this dispatch pays the XLA compile
+            first_dispatch = sig not in self._signatures
+            self._signatures.add(sig)
+            with stage("compile" if first_dispatch else "dispatch",
+                       tiles=k_n, batch=bs):
+                slab = fold(slab, *args)
         return slab, plan.padded_slots
 
     @property
@@ -1561,30 +1612,32 @@ class ReplayEngine:
                 np.asarray(i0s, np.int32).tobytes(),
                 np.asarray(t_bases, np.int32).tobytes())
         hit = resident.cache.get(ckey)
-        if hit is not None:
-            return hit
-        dkey = (key, plan.width, bs)
-        dens = self._densify_programs.get(dkey)
-        if dens is None:
-            wire = WireFormat(self.spec.registry, dict(resident.derived_key))
-            dens = jax.jit(_make_densify(wire, plan.width, bs))
-            self._densify_programs[dkey] = dens
-        i0s_p = np.zeros((k_cap,), dtype=np.int32)
-        i0s_p[: len(i0s)] = i0s
-        # entries past k_n are provable no-ops (t_base beyond every lane's
-        # length ⇒ every slot masks to padding ⇒ identity), so the dense fold
-        # can run a STATIC k_cap trip count and one compiled program still
-        # serves every plan in the bucket
-        tb_p = np.full((k_cap,), _NOOP_TILE_T, dtype=np.int32)
-        tb_p[: len(t_bases)] = t_bases
-        i0s_d = jnp.asarray(i0s_p)
-        tbs_d = jnp.asarray(tb_p)
-        t0 = time.perf_counter()
-        dw, ds = dens(resident.flat_wire, resident.flat_side,
-                      resident.starts_dev, i0s_d, tbs_d)
-        entry = (dw, ds, i0s_d, tbs_d)
-        resident.cache[ckey] = entry
-        self.stats["densify_s"] += time.perf_counter() - t0
+        with self.profiler.stage("densify", cached=hit is not None,
+                                 tiles=len(i0s), batch=bs) as densify:
+            if hit is not None:
+                return hit
+            dkey = (key, plan.width, bs)
+            dens = self._densify_programs.get(dkey)
+            if dens is None:
+                wire = WireFormat(self.spec.registry,
+                                  dict(resident.derived_key))
+                dens = jax.jit(_make_densify(wire, plan.width, bs))
+                self._densify_programs[dkey] = dens
+            i0s_p = np.zeros((k_cap,), dtype=np.int32)
+            i0s_p[: len(i0s)] = i0s
+            # entries past k_n are provable no-ops (t_base beyond every
+            # lane's length ⇒ every slot masks to padding ⇒ identity), so the
+            # dense fold can run a STATIC k_cap trip count and one compiled
+            # program still serves every plan in the bucket
+            tb_p = np.full((k_cap,), _NOOP_TILE_T, dtype=np.int32)
+            tb_p[: len(t_bases)] = t_bases
+            i0s_d = jnp.asarray(i0s_p)
+            tbs_d = jnp.asarray(tb_p)
+            dw, ds = dens(resident.flat_wire, resident.flat_side,
+                          resident.starts_dev, i0s_d, tbs_d)
+            entry = (dw, ds, i0s_d, tbs_d)
+            resident.cache[ckey] = entry
+        self.stats["densify_s"] += densify.seconds
         return entry
 
     def _resident_program_dense(self, key: frozenset, width: int, bs: int,
@@ -1695,63 +1748,67 @@ class ReplayEngine:
             bounds.append(min(max(cut, bounds[-1]), n_lanes))
         bounds.append(n_lanes)
 
-        pieces: list = []
-        padded = 0
-        first_piece = True
-        for lo, hi in zip(bounds[:-1], bounds[1:]):
-            if hi <= lo:
-                continue
-            base = int(piece_starts[lo])
-            end = int(piece_starts[hi])
-            if lane_order is None:
-                lanes = np.arange(lo, hi)
-                sub_starts = starts64[lo:hi] - base
-                sub_lens = w.lengths[lo:hi]
-            else:
-                lanes = lane_order[lo:hi]
-                if first_piece and zero_lanes.size:
-                    lanes = np.concatenate([lanes, zero_lanes])
-                # piece-local DESC length order so the tile plan keeps its
-                # shrinking-prefix schedule (zero lanes sort last, fold no-op)
-                lanes = lanes[np.argsort(-lens64[lanes], kind="stable")]
-                sub_starts = np.where(lens64[lanes] > 0,
-                                      starts64[lanes] - base, 0)
-                sub_lens = w.lengths[lanes]
-            first_piece = False
-            sub = ResidentWire(
-                derived_key=dict(w.derived_key),
-                packed=w.packed[base: end + w.guard],
-                side={k: v[base: end + w.guard] for k, v in w.side.items()},
-                starts=sub_starts.astype(np.int32),
-                lengths=sub_lens, perm=None, guard=w.guard,
-                num_events=end - base, layout=w.layout)
-            piece = self.upload_resident(sub)  # upload initiates...
-            # folded exactly once: the dense layout's one-time gather would
-            # never amortize (measured 2.5× slower streaming in the r5 sweep)
-            piece.cache["oneshot"] = True
-            slab, pad = self._dispatch_resident(
-                piece,
-                None if init_sorted is None else
-                {k: v[lanes] for k, v in init_sorted.items()},
-                None if ord_sorted is None else ord_sorted[lanes])
-            padded += pad
-            # hold ONLY what the sync pass needs — keeping the piece corpus
-            # itself would pin every piece's wire buffers in HBM at once
-            pieces.append((lanes, slab))  # ...fold dispatched, NOT synced
-        # one sync pass over every piece — a single packed fetch per piece
-        # (every materialized buffer is its own device→host round trip; the
-        # old per-piece-per-field np.asarray paid pieces × fields of them),
-        # then global unsort
-        out_sorted = {f.name: np.empty((b,), dtype=f.dtype)
-                      for f in state_fields}
-        for lanes, slab in pieces:
-            with self._fetch_stage():
-                piece_states = self._pull_states(slab, int(lanes.shape[0]), None)
-            for name, col in piece_states.items():
-                out_sorted[name][lanes] = col
-        return ReplayResult(states=_unapply_perm(perm, out_sorted),
-                            num_aggregates=b,
-                            num_events=w.num_events, padded_events=padded)
+        # every piece's upload, dispatches and pull under one umbrella
+        with self.profiler.stage("resident", follows=w.trace_ctx,
+                                 aggregates=b, events=w.num_events,
+                                 segments=segments):
+            pieces: list = []
+            padded = 0
+            first_piece = True
+            for lo, hi in zip(bounds[:-1], bounds[1:]):
+                if hi <= lo:
+                    continue
+                base = int(piece_starts[lo])
+                end = int(piece_starts[hi])
+                if lane_order is None:
+                    lanes = np.arange(lo, hi)
+                    sub_starts = starts64[lo:hi] - base
+                    sub_lens = w.lengths[lo:hi]
+                else:
+                    lanes = lane_order[lo:hi]
+                    if first_piece and zero_lanes.size:
+                        lanes = np.concatenate([lanes, zero_lanes])
+                    # piece-local DESC length order so the tile plan keeps its
+                    # shrinking-prefix schedule (zero lanes sort last, fold no-op)
+                    lanes = lanes[np.argsort(-lens64[lanes], kind="stable")]
+                    sub_starts = np.where(lens64[lanes] > 0,
+                                          starts64[lanes] - base, 0)
+                    sub_lens = w.lengths[lanes]
+                first_piece = False
+                sub = ResidentWire(
+                    derived_key=dict(w.derived_key),
+                    packed=w.packed[base: end + w.guard],
+                    side={k: v[base: end + w.guard] for k, v in w.side.items()},
+                    starts=sub_starts.astype(np.int32),
+                    lengths=sub_lens, perm=None, guard=w.guard,
+                    num_events=end - base, layout=w.layout)
+                piece = self.upload_resident(sub)  # upload initiates...
+                # folded exactly once: the dense layout's one-time gather would
+                # never amortize (measured 2.5× slower streaming in the r5 sweep)
+                piece.cache["oneshot"] = True
+                slab, pad = self._dispatch_resident(
+                    piece,
+                    None if init_sorted is None else
+                    {k: v[lanes] for k, v in init_sorted.items()},
+                    None if ord_sorted is None else ord_sorted[lanes])
+                padded += pad
+                # hold ONLY what the sync pass needs — keeping the piece corpus
+                # itself would pin every piece's wire buffers in HBM at once
+                pieces.append((lanes, slab))  # ...fold dispatched, NOT synced
+            # one sync pass over every piece — a single packed fetch per piece
+            # (every materialized buffer is its own device→host round trip; the
+            # old per-piece-per-field np.asarray paid pieces × fields of them),
+            # then global unsort
+            out_sorted = {f.name: np.empty((b,), dtype=f.dtype)
+                          for f in state_fields}
+            for lanes, slab in pieces:
+                with self.profiler.stage("fetch"):
+                    piece_states = self._pull_states(slab, int(lanes.shape[0]), None)
+                for name, col in piece_states.items():
+                    out_sorted[name][lanes] = col
+            return ReplayResult(states=_unapply_perm(perm, out_sorted),
+                                num_aggregates=b,
+                                num_events=w.num_events, padded_events=padded)
 
     def resident_cap_width(self) -> int:
         """Largest tile width the HBM budget allows (pow2 multiple of the min

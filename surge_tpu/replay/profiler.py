@@ -3,10 +3,21 @@
 The replay is the headline workload, yet the bench trajectory only carried
 one end-to-end timer: a regression in encode, H2D transfer, compile behavior,
 device fold, or the state fetch was indistinguishable. This profiler splits a
-replay pass into five host-clock stages:
+replay pass into stages, each a span that is open while the work runs:
 
-- ``encode``  — host-side wire packing / bucketing (CPU-bound);
-- ``h2d``     — host→device transfer of windows / the resident corpus;
+- ``encode``  — host-side wire packing / bucketing (CPU-bound); the cold
+  rebuild's ``pack_resident``, with children ``encode.lanes`` (length count
+  and sort, grouped check), ``encode.words`` (the word build),
+  ``encode.bytes`` (the byte split) and ``encode.guard`` (guard padding,
+  lane starts);
+- ``h2d``     — host→device transfer of windows / the resident corpus
+  (``upload_resident``), with children ``h2d.bucket`` (the host copy that
+  pads the buffers to their bucket) and ``h2d.put`` (``device_put`` through
+  ``block_until_ready``);
+- ``resident`` — the umbrella of one resident fold (``replay_resident`` /
+  ``fold_resident_slab``): ``plan`` (lane order, tile plan, work lists),
+  ``densify`` (the dense tile gather's dispatch, ``cached`` when the corpus
+  already holds the tiles), then ``compile``/``dispatch`` and ``fetch``;
 - ``compile`` — fold dispatches that triggered a fresh XLA compilation
   (detected from the engine's static-shape signature set, never a private
   JAX API);
@@ -14,7 +25,9 @@ replay pass into five host-clock stages:
   device keeps executing after dispatch returns);
 - ``fetch``   — dispatch → results on host. The stage is closed by the repo's
   **fetch-barrier discipline**: a real device→host fetch whose data dependency
-  forces the chained programs to finish (bench.py).
+  forces the chained programs to finish (bench.py). On the resident path its
+  children are ``fetch.wait`` (finalize dispatch to bytes on the host: where
+  the host waits for the chip) and ``fetch.decode``;
 - ``refresh`` — one incremental fold round of the resident state plane
   (surge_tpu.replay.resident_state): encode + h2d + dispatch of a committed
   batch into the on-device slab. The plane also reports its pack time under
@@ -22,16 +35,22 @@ replay pass into five host-clock stages:
   incremental folds break down in the per-stage profile exactly like
   cold-start passes; ``refresh`` is the per-round umbrella.
 
-Each stage occurrence feeds the DEBUG-level ``surge.replay.profile.*`` timers
-in :class:`~surge_tpu.metrics.EngineMetrics`, emits a span when a tracer is
-attached, and — when ``jax.profiler`` is importable — wraps
-device-dispatching stages in ``jax.profiler.TraceAnnotation`` so the stages
-line up with XLA ops in a captured device profile.
+:meth:`ReplayProfiler.stage` is the one way the replay path times anything:
+it opens a real span ``replay.<stage>`` (child of the span open in this
+context), enters a ``jax.profiler.TraceAnnotation`` of the same name — every
+stage, so a captured device profile shows the stages on its own clock beside
+the XLA programs — and on exit adds the span's one measured interval to the
+stage's seconds/count and, for the top-level stages, to the DEBUG-level
+``surge.replay.profile.*`` timers in :class:`~surge_tpu.metrics.EngineMetrics`.
+:meth:`ReplayProfiler.record` (an interval measured beforehand, a span dated
+back to it) remains for the resident plane's per-round callers only.
 
 Two modes, same names (docs/observability.md):
 
-- **counter-only** (:meth:`ReplayProfiler.counters`) — always on; the
-  resident plane's per-round "refresh" umbrella runs through it. Stage
+- **counter-only** (:meth:`ReplayProfiler.counters`) — always on; what a
+  ``ReplayEngine`` builds for itself (over
+  :func:`surge_tpu.tracing.default_tracer`, a bounded in-memory ring) and what
+  the resident plane's per-round "refresh" umbrella runs through. Stage
   seconds/counts accumulate as plain float/int bumps and the histogram
   ``record_ms`` calls no-op because the timers' sensors are disabled below
   DEBUG — the device observatory's per-stage accounting without histogram
@@ -54,10 +73,11 @@ Usage::
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, Optional
 
 from surge_tpu.metrics import EngineMetrics, Metrics, RecordingLevel, Timer
+from surge_tpu.tracing import NoopTracer, SpanContext, active_span
 
 __all__ = ["ReplayProfiler"]
 
@@ -71,20 +91,21 @@ _STAGE_TIMERS = {
     "refresh": "replay_refresh_timer",
 }
 
-#: stages that dispatch device work — annotated into XLA profiles
-_DEVICE_STAGES = frozenset({"compile", "dispatch", "fetch"})
+#: where a profiler without a tracer opens its stage spans: real spans
+#: (nesting and the measured interval need them), exported nowhere
+_UNEXPORTED = NoopTracer()
 
 
-def _trace_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` for device-visible stages, or None
-    when jax (or its profiler) is unavailable — profiling must never create a
-    jax dependency for host-only callers."""
+def _trace_annotation(name: str, **counts):
+    """A ``jax.profiler.TraceAnnotation`` carrying the stage's counts, or a
+    null context when jax (or its profiler) is unavailable — profiling must
+    never create a jax dependency for host-only callers."""
     try:
         import jax.profiler as jp
 
-        return jp.TraceAnnotation(name)
+        return jp.TraceAnnotation(name, **counts)
     except Exception:  # noqa: BLE001 — optional integration only
-        return None
+        return nullcontext()
 
 
 class ReplayProfiler:
@@ -96,30 +117,27 @@ class ReplayProfiler:
     """
 
     def __init__(self, metrics: Optional[EngineMetrics] = None,
-                 tracer=None, annotate: bool = True) -> None:
+                 tracer=None) -> None:
         self.metrics = metrics
         self.tracer = tracer
-        self.annotate = annotate
         self.stage_s: Dict[str, float] = {s: 0.0 for s in _STAGE_TIMERS}
         self.stage_n: Dict[str, int] = {s: 0 for s in _STAGE_TIMERS}
         self.windows = 0  # windows/tiles dispatched (engine-reported)
-        self._pass_span = None  # current pass-level span (parent of stages)
 
     @classmethod
     def if_enabled(cls, registry: Metrics,
                    metrics: Optional[EngineMetrics] = None,
-                   tracer=None, annotate: bool = True
-                   ) -> Optional["ReplayProfiler"]:
-        """A profiler iff the registry records at DEBUG or finer — the gate
-        that keeps the INFO hot path paying nothing (the engine then holds
-        ``profiler=None`` and every hook short-circuits on one ``is None``)."""
+                   tracer=None) -> Optional["ReplayProfiler"]:
+        """A profiler iff the registry records at DEBUG or finer: the gate of
+        the full-histogram mode. An engine handed ``None`` builds its own
+        counter-only profiler."""
         if registry.recording_level < RecordingLevel.DEBUG:
             return None
-        return cls(metrics=metrics, tracer=tracer, annotate=annotate)
+        return cls(metrics=metrics, tracer=tracer)
 
     @classmethod
     def counters(cls, metrics: Optional[EngineMetrics] = None,
-                 tracer=None, annotate: bool = True) -> "ReplayProfiler":
+                 tracer=None) -> "ReplayProfiler":
         """Counter-only mode: ALWAYS returns a profiler (no recording-level
         gate). The resident plane's per-round "refresh" umbrella runs through
         this — cheap always-on accounting (``stage_s``/``stage_n`` float/int
@@ -130,23 +148,27 @@ class ReplayProfiler:
         registry to DEBUG upgrades the SAME profiler to full-histogram mode
         with zero call-site changes — the names stay stable across both
         modes (docs/observability.md, "Two profiler modes")."""
-        return cls(metrics=metrics, tracer=tracer, annotate=annotate)
+        return cls(metrics=metrics, tracer=tracer)
 
     # -- recording ----------------------------------------------------------------------
 
-    def record(self, stage: str, seconds: float, **attrs) -> None:
-        """Attribute ``seconds`` of wall time to ``stage`` (already measured by
-        the caller — the engine's hot loops keep their own perf_counter reads)."""
+    def _account(self, stage: str, seconds: float) -> None:
         self.stage_s[stage] = self.stage_s.get(stage, 0.0) + seconds
         self.stage_n[stage] = self.stage_n.get(stage, 0) + 1
-        if self.metrics is not None:
-            timer: Timer = getattr(self.metrics, _STAGE_TIMERS[stage])
+        timer_attr = _STAGE_TIMERS.get(stage)  # child stages have no timer
+        if self.metrics is not None and timer_attr is not None:
+            timer: Timer = getattr(self.metrics, timer_attr)
             timer.record_ms(seconds * 1000.0)
+
+    def record(self, stage: str, seconds: float, **attrs) -> None:
+        """Attribute ``seconds`` of wall time, measured beforehand by the
+        caller, to ``stage``: the resident plane's per-round callers only
+        (the replay path itself times through :meth:`stage`)."""
+        self._account(stage, seconds)
         if self.tracer is not None:
-            span = self.tracer.start_span(f"replay.{stage}",
-                                          parent=self._pass_span)
+            span = self.tracer.start_span(f"replay.{stage}")
             # retro-dated to the measured interval so the trace timeline
-            # matches the perf_counter numbers the engine recorded — BOTH
+            # matches the perf_counter numbers the plane recorded — BOTH
             # clocks: the tail sampler's keep decision and the anatomy
             # placement read the mono pair first, so a wall-only retro-date
             # would make a 2s stage look like a 0ms span
@@ -163,55 +185,48 @@ class ReplayProfiler:
 
     def count_windows(self, n: int = 1) -> None:
         """Engine-reported window/tile dispatch count (one bump per window the
-        fold actually dispatched — record() calls must not inflate it)."""
+        fold actually dispatched — stage occurrences must not inflate it)."""
         self.windows += n
         if self.metrics is not None:
             self.metrics.replay_profile_windows.record(n)
 
     @contextmanager
-    def stage(self, name: str, **attrs):
-        """Time a stage inline (used where the engine has no existing timer),
-        wrapping device stages in a TraceAnnotation for XLA profiles. The
-        record lands even when the block raises — a failing compile/fetch is
-        exactly the pass an operator profiles."""
-        ann = (_trace_annotation(f"surge.replay.{name}")
-               if self.annotate and name in _DEVICE_STAGES else None)
-        t0 = time.perf_counter()
-        try:
-            if ann is not None:
-                with ann:
-                    yield
-            else:
-                yield
-        finally:
-            self.record(name, time.perf_counter() - t0, **attrs)
+    def stage(self, name: str, follows: Optional[SpanContext] = None,
+              **counts):
+        """Time a stage as a span that is open while the work runs.
 
-    @contextmanager
-    def replay_pass(self, name: str = "replay.pass", **attrs):
-        """Span + timing for one whole replay pass; stage spans emitted inside
-        become its children so a trace shows the breakdown under one parent."""
-        span = None
-        if self.tracer is not None:
-            span = self.tracer.start_span(name)
-            for k, v in attrs.items():
-                span.set_attribute(k, v)
-            self._pass_span = span
+        The span ``replay.<name>`` is a child of the span open in this context
+        (an enclosing stage, or the caller's own), else of ``follows`` (the
+        context a wire or corpus carries from the stage that made it), else a
+        new root. A ``jax.profiler.TraceAnnotation`` of the same name with the
+        same ``counts`` puts it on a captured device profile's clock. The
+        span is yielded, for counts known only later (``set_attribute``) and,
+        once closed, its ``seconds``. It finishes and is accounted even when
+        the block raises — a failing compile/fetch is exactly the pass an
+        operator profiles."""
+        tracer = self.tracer if self.tracer is not None else _UNEXPORTED
+        span = tracer.start_span(f"replay.{name}",
+                                 parent=active_span() or follows)
+        span.attributes.update(counts)
         try:
-            yield span
+            with span, _trace_annotation(span.name, **counts):
+                yield span
         finally:
-            self._pass_span = None
-            if span is not None:
-                span.finish()
+            self._account(name, span.seconds)
 
     # -- reporting ----------------------------------------------------------------------
 
     def summary(self) -> dict:
-        """``{stage: {"seconds": s, "count": n}}`` plus the covered total."""
+        """``{stage: {"seconds": s, "count": n}}`` for every stage seen, plus
+        the windows dispatched and the covered total."""
         out = {s: {"seconds": round(self.stage_s[s], 4),
                    "count": self.stage_n[s]}
-               for s in _STAGE_TIMERS}
+               for s in self.stage_s}
         out["windows"] = self.windows
-        out["total_accounted_s"] = round(sum(self.stage_s.values()), 4)
+        # the flat stages only: a child's or an umbrella's seconds lie inside
+        # another stage's
+        out["total_accounted_s"] = round(
+            sum(self.stage_s[s] for s in _STAGE_TIMERS), 4)
         return out
 
     def reset(self) -> None:

@@ -967,11 +967,20 @@ class ResidentStatePlane(Controllable):
         for p, wm in watermarks.items():
             recs = self.log.read(self.events_topic, p, wm,
                                  max_records=self._max_poll)
+            if not recs:
+                end = self.log.end_offset(self.events_topic, p)
+                if end > wm:
+                    # records may have been made durable between the empty
+                    # read and the end offset: only what a read made AFTER
+                    # the end was taken still cannot see is a hole the
+                    # caller may fast-forward over
+                    recs = self.log.read(self.events_topic, p, wm,
+                                         max_records=self._max_poll)
             if recs:
                 batches[p] = recs
                 ends[p] = recs[-1].offset + 1
             else:
-                ends[p] = self.log.end_offset(self.events_topic, p)
+                ends[p] = end
         return batches, ends
 
     async def _refresh_once(self) -> bool:

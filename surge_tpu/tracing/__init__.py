@@ -12,11 +12,13 @@ inject/extract mirrors ``TracePropagation.asHeaders``/``childFrom``
 No OpenTelemetry SDK dependency: :class:`Tracer` is the pluggable surface (users supply
 an exporter; the reference's noop-by-default ``openTelemetry`` override,
 SurgeGenericBusinessLogicTrait.scala:33), with :class:`InMemoryTracer` for tests and
-:class:`NoopTracer` as the default.
+:class:`NoopTracer` as the default. :func:`default_tracer` is the process-wide
+bounded ring the cold replay path records into when no tracer is handed to it.
 """
 
 from __future__ import annotations
 
+import collections
 import contextvars
 import json
 import random
@@ -36,8 +38,10 @@ __all__ = [
     "Tracer",
     "active_span",
     "active_trace_id",
+    "default_tracer",
     "extract_context",
     "inject_context",
+    "span_record",
 ]
 
 #: the span the current context is inside of (set by ``with span:``) — what
@@ -176,6 +180,12 @@ class Span:
     def duration_ms(self) -> float:
         return ((self.end_time or time.time()) - self.start_time) * 1000.0
 
+    @property
+    def seconds(self) -> float:
+        """Duration on the monotonic clock (to now while the span is open)."""
+        end = self.end_mono if self.end_mono is not None else time.monotonic()
+        return end - self.start_mono
+
     # context-manager sugar
     def __enter__(self) -> "Span":
         return self.activate()
@@ -258,16 +268,80 @@ class NoopTracer(Tracer):
 
 
 class InMemoryTracer(Tracer):
-    """Collects finished spans for assertions (test exporter)."""
+    """Collects finished spans in memory: the test exporter, and with a
+    ``capacity`` a bounded ring that keeps the newest spans (unbounded when
+    ``None``). Spans finish on any thread, so the ring is kept under a lock."""
 
     def __init__(self, service: str = "surge", sample_rate: float = 1.0,
-                 seed: Optional[int] = None) -> None:
-        self.finished: List[Span] = []
-        super().__init__(service=service, exporter=self.finished.append,
+                 seed: Optional[int] = None,
+                 capacity: Optional[int] = None) -> None:
+        self.capacity = capacity
+        self.finished = ([] if capacity is None
+                         else collections.deque(maxlen=capacity))
+        self._lock = threading.Lock()
+        super().__init__(service=service, exporter=self._keep,
                          sample_rate=sample_rate, seed=seed)
 
+    def _keep(self, span: Span) -> None:
+        with self._lock:
+            self.finished.append(span)
+
+    def spans(self, since_mono: Optional[float] = None) -> List[Span]:
+        """The finished spans still held, oldest first; with ``since_mono``
+        only those that started at or after that ``time.monotonic()`` stamp."""
+        with self._lock:
+            held = list(self.finished)
+        if since_mono is None:
+            return held
+        return [s for s in held if s.start_mono >= since_mono]
+
     def spans_named(self, name: str) -> List[Span]:
-        return [s for s in self.finished if s.name == name]
+        return [s for s in self.spans() if s.name == name]
+
+    def dump_to(self, path: str) -> int:
+        """Write the held spans to ``path``, one :func:`span_record` a line
+        (what :class:`JsonlSpanExporter` streams); returns how many."""
+        held = self.spans()
+        with open(path, "w", encoding="utf-8") as f:
+            for span in held:
+                f.write(json.dumps(span_record(span), default=str) + "\n")
+        return len(held)
+
+
+#: spans the process-wide ring keeps (a cold rebuild is about twenty)
+DEFAULT_RING_CAPACITY = 4096
+_DEFAULT_TRACER: Optional[InMemoryTracer] = None
+_DEFAULT_TRACER_LOCK = threading.Lock()
+
+
+def default_tracer() -> InMemoryTracer:
+    """The process-wide tracer of code that was handed none: every span kept
+    (sample rate 1), no exporter, a ring of the newest
+    ``DEFAULT_RING_CAPACITY`` finished spans. Read it with
+    :meth:`InMemoryTracer.spans`, write it out with
+    :meth:`InMemoryTracer.dump_to`."""
+    global _DEFAULT_TRACER
+    with _DEFAULT_TRACER_LOCK:
+        if _DEFAULT_TRACER is None:
+            _DEFAULT_TRACER = InMemoryTracer(capacity=DEFAULT_RING_CAPACITY)
+        return _DEFAULT_TRACER
+
+
+def span_record(span: Span) -> dict:
+    """One finished span as the JSON object the JSONL stream carries."""
+    return {
+        "name": span.name,
+        "trace_id": span.context.trace_id,
+        "span_id": span.context.span_id,
+        "parent_id": span.parent_id,
+        "start_time": span.start_time,
+        "end_time": span.end_time,
+        "duration_ms": span.duration_ms,
+        "status": span.status,
+        "attributes": span.attributes,
+        "events": [{"time": t, "name": n, "attributes": a}
+                   for t, n, a in span.events],
+    }
 
 
 class JsonlSpanExporter:
@@ -287,20 +361,7 @@ class JsonlSpanExporter:
         self._file = open(path, "a", encoding="utf-8")
 
     def __call__(self, span: Span) -> None:
-        record = {
-            "name": span.name,
-            "trace_id": span.context.trace_id,
-            "span_id": span.context.span_id,
-            "parent_id": span.parent_id,
-            "start_time": span.start_time,
-            "end_time": span.end_time,
-            "duration_ms": span.duration_ms,
-            "status": span.status,
-            "attributes": span.attributes,
-            "events": [{"time": t, "name": n, "attributes": a}
-                       for t, n, a in span.events],
-        }
-        line = json.dumps(record, default=str)
+        line = json.dumps(span_record(span), default=str)
         with self._lock:
             if self._file.closed:
                 return

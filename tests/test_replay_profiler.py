@@ -1,5 +1,8 @@
 """Per-stage replay profiler: stage coverage on the streaming and resident
-paths, span emission, DEBUG gating (disabled at INFO = engine holds None)."""
+paths, span emission, DEBUG gating of the histograms (an engine handed no
+profiler builds a counter-only one over the process-wide ring)."""
+
+import time
 
 import numpy as np
 
@@ -9,7 +12,7 @@ from surge_tpu.metrics import Metrics, RecordingLevel, engine_metrics
 from surge_tpu.models.counter import make_replay_spec
 from surge_tpu.replay.engine import ReplayEngine
 from surge_tpu.replay.profiler import ReplayProfiler
-from surge_tpu.tracing import InMemoryTracer
+from surge_tpu.tracing import InMemoryTracer, default_tracer
 
 CFG = default_config().with_overrides({
     "surge.replay.batch-size": 64,
@@ -93,14 +96,30 @@ def test_resident_path_emits_pass_and_stage_spans():
 
 
 def test_unprofiled_engine_holds_none_and_matches_results():
+    """An engine handed no profiler builds its own counter-only one over the
+    default ring (it held None before every stage became a real span), and
+    folds to the same states as a DEBUG-profiled one."""
     plain = ReplayEngine(make_replay_spec(), config=CFG)
-    assert plain.profiler is None
+    assert isinstance(plain.profiler, ReplayProfiler)
+    assert plain.profiler.metrics is None
+    assert plain.profiler.tracer is default_tracer()
     engine, _, _ = make_profiled_engine()
     ev = make_events()
+    since = time.monotonic()
     a = plain.replay_columnar(ev)
     b = engine.replay_columnar(ev)
     assert (a.states["count"] == b.states["count"]).all()
     assert (a.states["version"] == b.states["version"]).all()
+    # the plain engine's stages are real intervals in the ring: open while
+    # the work ran, both clocks read at its edges (never dated back)
+    ring = default_tracer().spans(since_mono=since)
+    assert {"replay.encode", "replay.h2d", "replay.fetch"} <= {
+        s.name for s in ring}
+    for s in ring:
+        assert since <= s.start_mono <= s.end_mono
+        assert abs((s.end_time - s.start_time) - s.seconds) < 0.05
+    assert plain.stats["pack_s"] == sum(
+        s.seconds for s in ring if s.name == "replay.encode")
 
 
 def test_summary_reset():

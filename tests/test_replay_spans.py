@@ -1,0 +1,331 @@
+"""The cold rebuild's spans (pack -> upload -> fold -> pull): real intervals from
+``ReplayProfiler.stage``, one trace a rebuild, on the profiler's clock as
+``TraceAnnotation``s of the same names, kept in the bounded default ring."""
+
+import ast
+import glob
+import inspect
+import json
+import os
+import time
+
+import numpy as np
+import pytest
+
+from surge_tpu.codec.tensor import ColumnarEvents
+from surge_tpu.config import default_config
+from surge_tpu.models.counter import make_replay_spec
+from surge_tpu.replay import engine as engine_module
+from surge_tpu.replay.engine import (COLD_PATH_JIT_NAMES, ReplayEngine,
+                                     ResidentWire)
+from surge_tpu.replay.profiler import ReplayProfiler
+from surge_tpu.tracing import (DEFAULT_RING_CAPACITY, InMemoryTracer,
+                               JsonlSpanExporter, active_span, default_tracer)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+ENCODE_CHILDREN = ["replay.encode.lanes", "replay.encode.words",
+                   "replay.encode.bytes", "replay.encode.guard"]
+H2D_CHILDREN = ["replay.h2d.bucket", "replay.h2d.put"]
+FETCH_CHILDREN = ["replay.fetch.wait", "replay.fetch.decode"]
+UMBRELLAS = ["replay.encode", "replay.h2d", "replay.resident"]
+
+
+def make_events(n_agg=48, n_per=20):
+    n = n_agg * n_per
+    return ColumnarEvents(
+        num_aggregates=n_agg,
+        agg_idx=np.repeat(np.arange(n_agg, dtype=np.int32), n_per),
+        type_ids=np.zeros(n, dtype=np.int32),
+        cols={"increment_by": np.ones(n, dtype=np.int64),
+              "decrement_by": np.zeros(n, dtype=np.int64)},
+        derived_cols={"sequence_number": "ordinal"})
+
+
+def make_engine(layout="auto", **kw):
+    cfg = default_config().with_overrides({
+        "surge.replay.batch-size": 64, "surge.replay.time-chunk": 16,
+        "surge.replay.resident-layout": layout})
+    return ReplayEngine(make_replay_spec(), config=cfg, **kw)
+
+
+def rebuild(engine, events):
+    wire = engine.pack_resident(events)
+    resident = engine.upload_resident(wire)
+    res = engine.replay_resident(resident)
+    assert (res.states["count"] == 20).all()
+    return wire, resident, res
+
+
+def ring_since(since):
+    return default_tracer().spans(since_mono=since)
+
+
+def one(spans, name):
+    found = [s for s in spans if s.name == name]
+    assert len(found) == 1, (name, [s.name for s in spans])
+    return found[0]
+
+
+def assert_children(spans, parent, names):
+    for name in names:
+        for child in (s for s in spans if s.name == name):
+            assert child.parent_id == parent.context.span_id, name
+            assert child.context.trace_id == parent.context.trace_id
+            # open while the parent was: inside it on the monotonic clock
+            assert parent.start_mono <= child.start_mono, name
+            assert child.end_mono <= parent.end_mono, name
+        assert any(s.name == name for s in spans), name
+
+
+@pytest.mark.parametrize("layout", ["auto", "dense"])
+def test_one_rebuild_is_one_trace_with_the_whole_tree(layout):
+    engine = make_engine(layout)  # no profiler, tracer or config key passed
+    since = time.monotonic()
+    wire, resident, res = rebuild(engine, make_events())
+    spans = ring_since(since)
+    assert len({s.context.trace_id for s in spans}) == 1
+    encode, h2d, resident_span = (one(spans, n) for n in UMBRELLAS)
+    fetch = one(spans, "replay.fetch")
+    # the trace replay.encode opened: the upload continues the pack's context
+    # and the fold the upload's, each after the one it follows
+    assert encode.parent_id is None
+    assert h2d.parent_id == encode.context.span_id
+    assert resident_span.parent_id == h2d.context.span_id
+    assert encode.end_mono <= h2d.start_mono <= h2d.end_mono
+    assert h2d.end_mono <= resident_span.start_mono
+    assert wire.trace_ctx == encode.context
+    assert resident.trace_ctx == h2d.context
+    assert_children(spans, encode, ENCODE_CHILDREN)
+    assert_children(spans, h2d, H2D_CHILDREN)
+    fold_children = ["replay.plan", "replay.compile", "replay.fetch"]
+    if layout == "dense":
+        fold_children.append("replay.densify")
+        assert [s.attributes["cached"] for s in spans
+                if s.name == "replay.densify"] == [False]
+    else:
+        assert not [s for s in spans if s.name == "replay.densify"]
+    assert_children(spans, resident_span, fold_children)
+    assert_children(spans, fetch, FETCH_CHILDREN)
+    assert all(s.parent_id is not None for s in spans if s is not encode)
+    assert all(s.status == "ok" and s.end_mono is not None for s in spans)
+    # the counts ride as attributes
+    n = make_events().num_events
+    assert encode.attributes["events"] == n
+    assert encode.attributes["aggregates"] == 48
+    guard_rows = wire.packed.shape[0]
+    assert encode.attributes["wire_bytes"] == guard_rows == n + wire.guard
+    assert h2d.attributes["wire_bytes"] == encode.attributes["wire_bytes"]
+    assert h2d.attributes["put_bytes"] == resident.wire_bytes == 1 << 16
+    assert resident_span.attributes["aggregates"] == 48
+    assert resident_span.attributes["events"] == n
+    assert resident_span.attributes["padded_slots"] == res.padded_events
+    assert resident_span.attributes["tiles"] == 2
+    # engine.stats keeps its keys, fed by the same intervals
+    assert engine.stats["pack_s"] == encode.seconds
+    assert engine.stats["h2d_s"] == h2d.seconds
+    assert engine.stats["densify_s"] == sum(
+        s.seconds for s in spans if s.name == "replay.densify")
+    # a second fold of the same corpus: steady dispatch, cached tiles, same trace
+    since = time.monotonic()
+    engine.replay_resident(resident)
+    again = ring_since(since)
+    assert {s.context.trace_id for s in again} == {encode.context.trace_id}
+    assert not [s for s in again if s.name == "replay.compile"]
+    assert [s for s in again if s.name == "replay.dispatch"]
+    assert all(s.attributes["cached"] for s in again
+               if s.name == "replay.densify")
+
+
+def test_a_callers_open_span_stays_the_parent_of_all_three():
+    engine = make_engine()
+    caller = InMemoryTracer(service="caller")  # whoever the caller is
+    since = time.monotonic()
+    with caller.start_span("engine.rebuild-from-events") as root:
+        rebuild(engine, make_events())
+        slab, padded = engine.fold_resident_slab(
+            engine.upload_resident(engine.pack_resident(make_events())))
+    assert active_span() is None
+    spans = ring_since(since)
+    assert {s.context.trace_id for s in spans} == {root.context.trace_id}
+    for name in UMBRELLAS:
+        for s in (s for s in spans if s.name == name):
+            assert s.parent_id == root.context.span_id, name
+            assert root.start_mono <= s.start_mono
+            assert s.end_mono <= root.end_mono
+    # fold_resident_slab is the same umbrella, without the pull
+    folds = [s for s in spans if s.name == "replay.resident"]
+    assert len(folds) == 2 and padded == folds[1].attributes["padded_slots"]
+    fetches = [s for s in spans if s.name == "replay.fetch"]
+    assert [f.parent_id for f in fetches] == [folds[0].context.span_id]
+
+
+def test_a_loaded_wire_starts_a_new_trace(tmp_path):
+    engine = make_engine()
+    since = time.monotonic()
+    wire = engine.pack_resident(make_events())
+    wire.save(str(tmp_path / "wire"))
+    with open(tmp_path / "wire" / "wire.json", encoding="utf-8") as f:
+        assert "trace_ctx" not in json.load(f)
+    loaded = ResidentWire.load(str(tmp_path / "wire"))
+    assert loaded.trace_ctx is None
+    engine.replay_resident(engine.upload_resident(loaded))
+    spans = ring_since(since)
+    encode, h2d = one(spans, "replay.encode"), one(spans, "replay.h2d")
+    assert h2d.parent_id is None
+    assert h2d.context.trace_id != encode.context.trace_id
+    assert one(spans, "replay.resident").parent_id == h2d.context.span_id
+
+
+@pytest.mark.filterwarnings("ignore:builtin type:DeprecationWarning")
+def test_stages_are_trace_annotations_of_the_same_names(tmp_path):
+    """A captured profile holds the stages as host events under their spans'
+    names, nested in their umbrellas, with the ring's durations."""
+    import jax
+    from jax.profiler import ProfileData
+
+    engine = make_engine()
+    events = make_events(n_agg=4096, n_per=20)
+    wire = engine.pack_resident(events)  # compiles outside the capture
+    engine.replay_resident(engine.upload_resident(wire))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    since = time.monotonic()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        wire = engine.pack_resident(events)
+        engine.replay_resident(engine.upload_resident(wire))
+    finally:
+        jax.profiler.stop_trace()
+    ring = {s.name: s for s in ring_since(since)}
+    pb, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                 "*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(pb).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("replay."):
+                        host[e.name] = (e.start_ns, e.start_ns + e.duration_ns,
+                                        dict(e.stats))
+    assert set(host) == set(ring)  # every stage, under its span's name
+    for child, umbrella in (("replay.encode.words", "replay.encode"),
+                            ("replay.h2d.put", "replay.h2d"),
+                            ("replay.fetch.wait", "replay.fetch"),
+                            ("replay.fetch", "replay.resident")):
+        c_lo, c_hi, _ = host[child]
+        u_lo, u_hi, _ = host[umbrella]
+        assert u_lo <= c_lo and c_hi <= u_hi, (child, umbrella)
+    for name in ("replay.encode.words", "replay.h2d.put", "replay.fetch.wait"):
+        lo, hi, _ = host[name]
+        traced_s, ring_s = (hi - lo) / 1e9, ring[name].seconds
+        # within a fifth; a stage of well under a millisecond, as the put of
+        # this small wire is on the CPU, within the entry and exit costs
+        assert abs(traced_s - ring_s) <= max(0.2 * ring_s, 2e-4), (
+            name, traced_s, ring_s)
+    # the counts known when a stage opens are the annotation's metadata
+    assert host["replay.encode"][2]["events"] == events.num_events
+    assert host["replay.h2d.put"][2]["put_bytes"] == 1 << 17
+
+
+def test_the_ring_is_bounded_and_keeps_the_newest():
+    ring = InMemoryTracer(capacity=8)
+    for i in range(9):
+        ring.start_span(f"s{i}").finish()
+    assert [s.name for s in ring.spans()] == [f"s{i}" for i in range(1, 9)]
+    assert len(ring.finished) == 8
+    cut = ring.spans()[4].start_mono
+    assert [s.name for s in ring.spans(since_mono=cut)][-1] == "s8"
+    assert all(s.start_mono >= cut for s in ring.spans(since_mono=cut))
+    # unbounded as before without a capacity
+    plain = InMemoryTracer()
+    for i in range(9):
+        plain.start_span(f"s{i}").finish()
+    assert len(plain.finished) == 9
+    # the process-wide default: one ring, every trace kept, no exporter beyond it
+    default = default_tracer()
+    assert default is default_tracer()
+    assert default.capacity == DEFAULT_RING_CAPACITY == 4096
+    assert default.sample_rate == 1.0
+    assert default.finished.maxlen == 4096
+
+
+def test_dump_to_writes_the_jsonl_exporters_record_shape(tmp_path):
+    streamed = tmp_path / "streamed.jsonl"
+    with JsonlSpanExporter(str(streamed)) as exporter:
+        ring = InMemoryTracer(capacity=4)
+        with ring.start_span("replay.encode") as span:
+            span.set_attribute("events", 7)
+            span.add_event("note", {"k": 1})
+        exporter(span)
+    dumped = tmp_path / "dumped.jsonl"
+    assert ring.dump_to(str(dumped)) == 1
+    assert (json.loads(dumped.read_text())
+            == json.loads(streamed.read_text()))
+    assert json.loads(dumped.read_text())["attributes"] == {"events": 7}
+
+
+def test_a_stage_whose_body_raises_still_finishes_its_span():
+    tracer = InMemoryTracer()
+    prof = ReplayProfiler.counters(tracer=tracer)
+    with pytest.raises(RuntimeError):
+        with prof.stage("fetch", aggregates=3):
+            with prof.stage("fetch.wait"):
+                raise RuntimeError("device lost")
+    wait, fetch = tracer.finished
+    assert (wait.name, fetch.name) == ("replay.fetch.wait", "replay.fetch")
+    assert wait.parent_id == fetch.context.span_id
+    for span in (wait, fetch):
+        assert span.end_mono is not None and span.status == "error"
+    assert active_span() is None  # nothing left open in this context
+    assert prof.stage_n["fetch"] == prof.stage_n["fetch.wait"] == 1
+    assert prof.stage_s["fetch"] >= prof.stage_s["fetch.wait"] > 0
+    # a profiler with no tracer times its stages all the same, exporting none
+    bare = ReplayProfiler()
+    with bare.stage("encode") as span:
+        pass
+    assert bare.stage_n["encode"] == 1 and span.end_mono is not None
+
+
+def test_cold_path_jit_names_are_pinned():
+    """Every ``jax.jit`` of replay/engine.py is made from a function whose
+    name the pinned tuple holds, and the benchmark's prefix file maps each
+    ``jit_<name>``: a rename would unmap a program from its layer."""
+    tree = ast.parse(inspect.getsource(engine_module))
+    returned = {}  # module-level factory -> the inner function it returns
+    for node in tree.body:
+        if isinstance(node, ast.FunctionDef):
+            names = [r.value.id for r in ast.walk(node)
+                     if isinstance(r, ast.Return)
+                     and isinstance(r.value, ast.Name)]
+            if names:
+                returned[node.name] = names[-1]
+    jitted = []
+    for call in ast.walk(tree):
+        if (isinstance(call, ast.Call) and isinstance(call.func, ast.Attribute)
+                and call.func.attr == "jit"
+                and isinstance(call.func.value, ast.Name)
+                and call.func.value.id == "jax"):
+            made_from = call.args[0]
+            if isinstance(made_from, ast.Name):
+                jitted.append(made_from.id)
+            else:  # jax.jit(_make_densify(...))
+                assert isinstance(made_from, ast.Call), ast.dump(made_from)
+                jitted.append(returned[made_from.func.id])
+    assert len(jitted) >= 8
+    assert set(jitted) == set(COLD_PATH_JIT_NAMES)
+    with open(os.path.join(ROOT, "benchmarks", "programs", "cold-fold.json"),
+              encoding="utf-8") as f:
+        prefixes = json.load(f)["prefixes"]
+    for name in COLD_PATH_JIT_NAMES:
+        assert any(f"jit_{name}".startswith(p) for p in prefixes), name
+    # and the programs a driven engine holds carry those names
+    engine = make_engine("dense")
+    rebuild(engine, make_events())
+    held = [*engine._densify_programs.values(),
+            *engine._resident_dense_folds.values(),
+            *engine._slab_programs.values(),
+            *engine._finalize_programs.values()]
+    assert len(held) >= 4
+    assert {p.__name__ for p in held} <= set(COLD_PATH_JIT_NAMES)

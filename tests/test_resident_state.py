@@ -378,6 +378,56 @@ def test_regrant_racing_inflight_fold_reanchors():
     asyncio.run(scenario())
 
 
+def test_commit_between_empty_read_and_end_offset_is_folded_not_skipped():
+    """A poll reads a partition's tail (empty) and then asks for its end
+    offset; records made durable between the two calls are no compaction hole.
+    The round must fold them, never move the watermark past them: a skipped
+    event is gone from the slab for good while ``lag_records()`` says 0."""
+
+    class CommitsAfterOneEmptyRead:
+        """The plane's log; one empty tail read of partition 1 is followed,
+        before the plane sees it, by a commit to that partition."""
+
+        def __init__(self, log, commit):
+            self._log, self._commit, self.armed = log, commit, False
+
+        def __getattr__(self, name):
+            return getattr(self._log, name)
+
+        def read(self, topic, partition, from_offset=0, **kw):
+            recs = self._log.read(topic, partition, from_offset, **kw)
+            if self.armed and not recs and partition == 1:
+                self.armed = False
+                self._commit()
+            return recs
+
+    async def scenario():
+        log = make_log()
+        exp = Expected()
+        aggs = [f"agg-{i}" for i in range(8)]
+        evs = []
+        for agg in aggs:
+            evs.extend(exp.events(agg, 2))
+        append_events(log, evs)
+        racing = CommitsAfterOneEmptyRead(
+            log, lambda: append_events(log, exp.events("agg-1", 1)))
+        plane = make_plane(racing)
+        plane._ensure_device_state()
+        plane.seed_from_log()
+        racing.armed = True
+        assert await plane._refresh_once() is True  # folded, not skipped
+        assert not racing.armed
+        append_events(log, exp.events("agg-1", 1))
+        assert await plane._refresh_once() is True
+        assert plane.lag_records() == 0
+        assert plane.snapshot_states() == exp.states
+        hit, st = await plane.read_state("agg-1")
+        assert hit and st == exp.states["agg-1"] and st.version == 4
+        await plane.stop()
+
+    asyncio.run(scenario())
+
+
 def test_prime_watermark_handoff_no_double_fold():
     """The StateStoreIndexer.prime analog: after an out-of-band seed covered
     a window, prime() must fast-forward the fold watermarks so the refresh
