@@ -37,6 +37,70 @@ DERIVE_ORDINAL = "ordinal"
 
 _MAX_PACKED_BITS = 32  # one uint32 word per event; wider layouts spill to side columns
 
+#: events a block of the flat pack covers (:meth:`WireFormat.pack_blocks`,
+#: :func:`grouped_lengths`). Sized from the columns' bytes: four int32 columns
+#: of 2^18 events are 4 MiB and the block's word and masks under 1 MiB more, so
+#: what one pass over a block writes the next pass still finds in cache.
+FLAT_PACK_BLOCK = 1 << 18
+
+
+def _as_unsigned(a: np.ndarray) -> np.ndarray:
+    """A signed integer array reinterpreted as unsigned (no copy): a negative
+    reads as a value past every declared width, so ONE compare against the
+    upper end checks both ends of a range."""
+    if a.dtype.kind == "i":
+        return a.view(a.dtype.str.replace("i", "u"))
+    return a
+
+
+def _outside(a: np.ndarray, top: int) -> bool:
+    """Whether any element of ``a`` lies outside ``[0, top]``: reductions only,
+    no mask the size of ``a``."""
+    if not a.size:
+        return False
+    if a.dtype.kind in "iu":
+        return int(_as_unsigned(a).max()) > top
+    return bool(a.min() < 0 or a.max() > top)
+
+
+def grouped_lengths(agg_idx: np.ndarray, num_aggregates: int
+                    ) -> np.ndarray | None:
+    """Events per aggregate ``[B]`` int64 of a flat stream whose events are
+    GROUPED per aggregate (``agg_idx`` non-decreasing), or ``None`` where they
+    are not.
+
+    One read of the ids, block by block with one element of overlap, and no
+    temporary their size: a block's neighbours are compared once, for where
+    the id changes. The stream is grouped where every change is a rise (the
+    first block with a fall ends the walk), and the lengths are then the
+    distances between the changes: an aggregate with no event, or an id the
+    stream skips, gets length 0 as ``np.bincount(minlength=B)`` gives it. Ids
+    outside ``[0, B)`` raise as they do there."""
+    agg = np.asarray(agg_idx)
+    n = agg.shape[0]
+    lengths = np.zeros(num_aggregates, dtype=np.int64)
+    if n == 0:
+        return lengths
+    bounds, ids = [np.zeros(1, dtype=np.int64)], [agg[:1]]
+    for lo in range(0, n - 1, FLAT_PACK_BLOCK):
+        hi = min(lo + FLAT_PACK_BLOCK, n - 1)
+        cur, nxt = agg[lo:hi], agg[lo + 1:hi + 1]
+        change = np.flatnonzero(nxt != cur)
+        rose_to = nxt[change]
+        if (rose_to < cur[change]).any():
+            return None
+        ids.append(rose_to)
+        change += lo + 1
+        bounds.append(change)
+    bounds.append(np.full(1, n, dtype=np.int64))
+    ids = np.concatenate(ids)
+    if ids[0] < 0 or ids[-1] >= num_aggregates:
+        raise ValueError(
+            f"agg_idx spans [{int(ids[0])}, {int(ids[-1])}], outside the "
+            f"{num_aggregates} aggregates declared")
+    lengths[ids] = np.diff(np.concatenate(bounds))
+    return lengths
+
 
 @dataclass(frozen=True)
 class _PackedField:
@@ -82,6 +146,11 @@ class WireFormat:
         self.side_fields = tuple(side)
         self.total_bits = shift
         self.nbytes = (shift + 7) // 8
+        # narrowest little-endian word that holds every packed bit: at bench
+        # scale the build streams one intermediate per field, so a 1-byte wire
+        # (counter) building in uint8 moves a quarter of the memory
+        self.word_dtype = np.dtype("u1" if self.nbytes == 1 else
+                                   "<u2" if self.nbytes == 2 else "<u4")
         # the byte pattern a padding slot must decode to: pad_code in the type bits,
         # zeros elsewhere
         self.pad_bytes = tuple((self.pad_code >> (8 * k)) & 0xFF
@@ -142,30 +211,93 @@ class WireFormat:
         return packed, side
 
     def _pack_words(self, type_ids: np.ndarray,
-                    cols: Mapping[str, np.ndarray]) -> np.ndarray:
-        """The shared word-build: out-of-range ids — padding (-1) or corrupt
-        positive values — pack as the pad sentinel so they carry state through
-        (the same contract make_step_fn keeps for the unpacked path); a corrupt
-        id must never spill into field bits. Dtype-preserving range checks
-        catch negatives and any value past each declared width."""
-        # narrowest word dtype that holds every packed bit: at bench scale the
-        # build streams N×4-byte intermediates per field, so a 1-byte wire
-        # (counter) building in uint8 moves a quarter of the memory
-        wdtype = (np.uint8 if self.nbytes == 1
-                  else np.uint16 if self.nbytes == 2 else np.uint32)
+                    cols: Mapping[str, np.ndarray],
+                    out: np.ndarray | None = None,
+                    report: Mapping[str, np.ndarray] | None = None
+                    ) -> np.ndarray:
+        """The one word build, for a window (:meth:`pack_window`) and for a
+        block of the flat stream (:meth:`pack_blocks`) alike: out-of-range
+        ids — padding (-1) or corrupt positive values — pack as the pad
+        sentinel so they carry state through (the same contract make_step_fn
+        keeps for the unpacked path); a corrupt id must never spill into field
+        bits. A packed column below 0 or past its declared width raises.
+
+        Built in the narrowest word dtype (:attr:`word_dtype`), into ``out``
+        where the caller has the words' final place. The range checks are
+        reductions over an unsigned view (:func:`_outside`), so nothing the
+        size of the input is made beyond the word and one cast per field; the
+        error's max and min are computed on the failing path only, over
+        ``report`` (the whole columns, where ``cols`` is one block of them)."""
         tid = np.asarray(type_ids)
-        word = np.where((tid < 0) | (tid >= self.num_types),
-                        self.pad_code, tid).astype(wdtype)
+        if tid.dtype.kind not in "iu":
+            tid = tid.astype(np.int64)
+        word = (out if out is not None
+                else np.empty(tid.shape, dtype=self.word_dtype))
+        # pad_code is num_types: every id past the last type, a negative read
+        # as unsigned among them, clamps to it
+        np.copyto(word, np.minimum(_as_unsigned(tid), self.pad_code),
+                  casting="unsafe")
         for pf in self.packed_fields:
             col = np.asarray(cols[pf.name])
-            if col.size and ((col < 0) | (col > pf.mask)).any():
+            if _outside(col, pf.mask):
+                whole = np.asarray((report or cols)[pf.name])
                 raise ValueError(
                     f"column {pf.name!r} overflows its declared {pf.bits}-bit "
-                    f"wire width (max value {int(col.max())}, "
-                    f"min {int(col.min())})")
-            word |= (col.astype(wdtype)
-                     << np.asarray(pf.shift, dtype=wdtype))
+                    f"wire width (max value {int(whole.max())}, "
+                    f"min {int(whole.min())})")
+            bits = col.astype(self.word_dtype)
+            bits <<= np.asarray(pf.shift, dtype=self.word_dtype)
+            word |= bits
         return word
+
+    def pack_blocks(self, type_ids: np.ndarray, cols: Mapping[str, np.ndarray],
+                    guard: int = 0) -> tuple[np.ndarray, int]:
+        """The flat pack as :meth:`ReplayEngine.pack_resident` runs it: the
+        packed half of :meth:`pack_flat` plus ``guard`` zero rows, ``(packed
+        uint8 [N + guard, nbytes], blocks)``, byte for byte what ``pack_flat``
+        and an ``np.pad`` give.
+
+        The buffer is allocated once and only its guard rows are zeroed. The
+        stream is then walked in blocks of :data:`FLAT_PACK_BLOCK` events
+        (``blocks`` of them; an input shorter than one is one block): per block
+        the word build of :meth:`_pack_words` reads each column once, while
+        its temporaries are still in cache, and stores the words where they
+        finally live: through a word view of the buffer for a 1-, 2- or 4-byte
+        wire, byte by byte within the block for a 3-byte one."""
+        tid = np.asarray(type_ids)
+        n = tid.shape[0]
+        fields = {pf.name: np.asarray(cols[pf.name])
+                  for pf in self.packed_fields}
+        packed = np.empty((n + guard, self.nbytes), dtype=np.uint8)
+        packed[n:] = 0
+        whole_words = self.word_dtype.itemsize == self.nbytes
+        words = packed[:n].view(self.word_dtype)[:, 0] if whole_words else None
+        blocks = range(0, n, FLAT_PACK_BLOCK)
+        for lo in blocks:
+            hi = min(lo + FLAT_PACK_BLOCK, n)
+            word = self._pack_words(
+                tid[lo:hi], {k: v[lo:hi] for k, v in fields.items()},
+                out=None if words is None else words[lo:hi], report=fields)
+            if words is None:
+                packed[lo:hi] = (word.view(np.uint8)
+                                 .reshape(hi - lo, -1)[:, :self.nbytes])
+        return packed, len(blocks)
+
+    def side_columns(self, cols: Mapping[str, np.ndarray], guard: int = 0
+                     ) -> dict[str, np.ndarray]:
+        """The side half of the flat pack with ``guard`` zero rows appended:
+        ``{name: [N + guard]}`` in each column's wire dtype, cast straight into
+        its preallocated buffer (numpy casts in strides of its own: no
+        temporary, so no loop over blocks here)."""
+        side: dict[str, np.ndarray] = {}
+        for f in self.side_fields:
+            col = np.asarray(cols[f.name])
+            n = col.shape[0]
+            buf = np.empty(n + guard, dtype=f.dtype)
+            buf[:n] = col
+            buf[n:] = 0
+            side[f.name] = buf
+        return side
 
     def pack_flat(self, type_ids: np.ndarray, cols: Mapping[str, np.ndarray]
                   ) -> tuple[np.ndarray, dict[str, np.ndarray]]:
@@ -173,19 +305,25 @@ class WireFormat:
         side {name: [N]})`` — the resident-corpus wire form: exactly
         ``wire_bytes_per_event()`` per real event, no window padding at all.
         The device slices per-aggregate slabs from it (see
-        :meth:`decode_words`)."""
+        :meth:`decode_words`).
+
+        Whole-column and plain: the reference the blocked pack
+        (:meth:`pack_blocks`, :meth:`side_columns`) is held to, byte for byte,
+        by ``tests/test_pack_blocked.py``. Nothing on the rebuild path calls
+        it."""
         return self.split_flat(self.flat_words(type_ids, cols), cols)
 
     def flat_words(self, type_ids: np.ndarray, cols: Mapping[str, np.ndarray]
                    ) -> np.ndarray:
-        """First half of :meth:`pack_flat`: one packed word per event ``[N]``."""
+        """First half of :meth:`pack_flat`: one packed word per event ``[N]``,
+        the whole stream in one build."""
         return self._pack_words(type_ids, {pf.name: cols[pf.name]
                                            for pf in self.packed_fields})
 
     def split_flat(self, word: np.ndarray, cols: Mapping[str, np.ndarray]
                    ) -> tuple[np.ndarray, dict[str, np.ndarray]]:
         """Second half of :meth:`pack_flat`: the words' bytes as ``[N,
-        nbytes]`` and the side columns in their wire dtypes."""
+        nbytes]`` and the side columns in their wire dtypes, whole-column."""
         n = word.shape[0]
         packed = np.empty((n, self.nbytes), dtype=np.uint8)
         for k in range(self.nbytes):

@@ -28,7 +28,7 @@ from surge_tpu.codec.tensor import (
     columnar_to_batch,
     encode_states,
 )
-from surge_tpu.codec.wire import WireFormat
+from surge_tpu.codec.wire import WireFormat, grouped_lengths
 from surge_tpu.config import Config, default_config
 from surge_tpu.engine.model import ReplaySpec, StateTree
 from surge_tpu.replay.profiler import ReplayProfiler
@@ -969,27 +969,47 @@ class ReplayEngine:
         is packed in ITS OWN event order and lanes point at their segments by
         indirection (``starts[k] = start of aggregate perm[k]``). Nothing in
         the device fold requires lane slabs to be buffer-contiguous — each
-        tile gathers from per-lane bases — so the 100M-event stable sort plus
-        three full-column gathers the old path paid (~17 s of a ~26 s pack at
-        bench scale) disappear; only the O(B) length argsort remains."""
+        tile gathers from per-lane bases — so no event is moved; only the O(B)
+        length argsort remains, and it is skipped where every log is as long
+        as the next.
+
+        The pack reads each input column once and writes the wire once, in
+        cache-sized blocks (``codec/wire.py:FLAT_PACK_BLOCK`` events; nothing
+        is kept from one call to the next). Its four stages:
+
+        - ``encode.lanes``: :func:`grouped_lengths`, the blocked neighbour
+          compare and the lengths from the segment boundaries. Ungrouped input
+          (rare: interleaved hand-built columns) falls back to ``bincount``
+          and the stable re-sort, whole-column.
+        - ``encode.words``: :meth:`WireFormat.pack_blocks`, the word build per
+          block, stored straight into the ``[N + guard, nbytes]`` buffer.
+        - ``encode.bytes``: :meth:`WireFormat.side_columns`, the side columns
+          cast into their ``[N + guard]`` buffers (the counter has none).
+        - ``encode.guard``: ``starts``, the lane view under ``perm``, the
+          :class:`ResidentWire`. The guard rows are part of the buffers the
+          two stages before allocate; nothing is copied to append them.
+
+        The ``replay.encode`` span says which way it went: ``grouped``,
+        ``lanes_from`` (``boundaries`` or ``bincount``) and ``blocks`` (how
+        many blocks the word pass ran)."""
         stage = self.profiler.stage
         b = colev.num_aggregates
         with stage("encode", events=colev.num_events, aggregates=b) as enc:
             with stage("encode.lanes"):
-                agg = np.asarray(colev.agg_idx)
-                lengths = np.bincount(agg, minlength=b).astype(np.int64)
-                if self.sort_by_length and b > 1:
+                lengths = grouped_lengths(colev.agg_idx, b)
+                grouped = lengths is not None
+                if not grouped:
+                    lengths = np.bincount(colev.agg_idx,
+                                          minlength=b).astype(np.int64)
+                perm = None
+                if (self.sort_by_length and b > 1
+                        and lengths.min() != lengths.max()):
                     # DESCENDING by length: the lanes still active after t
                     # events form a prefix, so each tile round dispatches a
                     # contiguous lane range
                     perm = np.argsort(-lengths, kind="stable").astype(np.int32)
                     if np.array_equal(perm, np.arange(b, dtype=np.int32)):
                         perm = None
-                else:
-                    perm = None
-
-                grouped = (bool((np.diff(agg) >= 0).all()) if agg.size > 1
-                           else True)
                 if grouped:
                     to_pack = colev
                 else:
@@ -1007,20 +1027,17 @@ class ReplayEngine:
                     to_pack = colev.sorted_by_aggregate()
 
             wire = WireFormat(self.spec.registry, dict(to_pack.derived_cols))
+            # tail padding so every [start + t_base, width) slab slice stays
+            # in bounds without clamping (clamped slices would shift lane
+            # data); content is irrelevant — slots past lens decode to the pad
+            # sentinel
+            guard = max(self.resident_tile_width(), _WIRE_GUARD_MIN)
             with stage("encode.words"):
-                word = wire.flat_words(to_pack.type_ids, to_pack.cols)
+                packed, blocks = wire.pack_blocks(to_pack.type_ids,
+                                                  to_pack.cols, guard)
             with stage("encode.bytes"):
-                packed, side_flat = wire.split_flat(word, to_pack.cols)
-                del word
+                side_flat = wire.side_columns(to_pack.cols, guard)
             with stage("encode.guard"):
-                # tail padding so every [start + t_base, width) slab slice
-                # stays in bounds without clamping (clamped slices would shift
-                # lane data); content is irrelevant — slots past lens decode
-                # to the pad sentinel
-                guard = max(self.resident_tile_width(), _WIRE_GUARD_MIN)
-                packed = np.pad(packed, ((0, guard), (0, 0)))
-                side_flat = {k: np.pad(v, (0, guard))
-                             for k, v in side_flat.items()}
                 # lengths/starts are in the PACKED stream's aggregate-id
                 # order; the grouped path then permutes the lane VIEW only
                 # (indirection), the ungrouped path already permuted the
@@ -1038,6 +1055,10 @@ class ReplayEngine:
                     num_events=to_pack.num_events,
                     layout=wire.layout_fingerprint(), trace_ctx=enc.context)
             enc.set_attribute("wire_bytes", _wire_nbytes(packed, side_flat))
+            enc.set_attribute("blocks", blocks)
+            enc.set_attribute("grouped", grouped)
+            enc.set_attribute("lanes_from",
+                              "boundaries" if grouped else "bincount")
         self.stats["pack_s"] += enc.seconds
         return out
 

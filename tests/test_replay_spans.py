@@ -12,6 +12,7 @@ import time
 import numpy as np
 import pytest
 
+from surge_tpu.codec import wire as wire_module
 from surge_tpu.codec.tensor import ColumnarEvents
 from surge_tpu.config import default_config
 from surge_tpu.models.counter import make_replay_spec
@@ -115,6 +116,10 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(layout):
     assert encode.attributes["aggregates"] == 48
     guard_rows = wire.packed.shape[0]
     assert encode.attributes["wire_bytes"] == guard_rows == n + wire.guard
+    # how the pack went: 960 grouped events are one block of the word pass
+    assert encode.attributes["blocks"] == 1
+    assert encode.attributes["grouped"] is True
+    assert encode.attributes["lanes_from"] == "boundaries"
     assert h2d.attributes["wire_bytes"] == encode.attributes["wire_bytes"]
     assert h2d.attributes["put_bytes"] == resident.wire_bytes == 1 << 16
     assert resident_span.attributes["aggregates"] == 48
@@ -135,6 +140,38 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(layout):
     assert [s for s in again if s.name == "replay.dispatch"]
     assert all(s.attributes["cached"] for s in again
                if s.name == "replay.densify")
+
+
+@pytest.mark.parametrize("grouped, block, blocks, lanes_from", [
+    (True, 1 << 18, 1, "boundaries"), (True, 100, 10, "boundaries"),
+    (True, 7, 138, "boundaries"), (False, 100, 10, "bincount")])
+def test_the_encode_span_says_how_the_pack_went(monkeypatch, grouped, block,
+                                                blocks, lanes_from):
+    """``blocks``, ``grouped`` and ``lanes_from`` ride on ``replay.encode``;
+    its four children keep their names and their parent whichever way the
+    pack went, and ``stats["pack_s"]`` is the umbrella's seconds."""
+    monkeypatch.setattr(wire_module, "FLAT_PACK_BLOCK", block)
+    events = make_events()
+    if not grouped:
+        order = np.random.default_rng(0).permutation(events.num_events)
+        events.agg_idx = events.agg_idx[order]
+    engine = make_engine()
+    since = time.monotonic()
+    wire = engine.pack_resident(events)
+    spans = ring_since(since)
+    encode = one(spans, "replay.encode")
+    assert sorted(s.name for s in spans if s is not encode) == sorted(
+        ENCODE_CHILDREN)
+    assert_children(spans, encode, ENCODE_CHILDREN)
+    assert encode.attributes["blocks"] == blocks
+    assert encode.attributes["grouped"] is grouped
+    assert encode.attributes["lanes_from"] == lanes_from
+    assert encode.attributes["events"] == 960
+    assert encode.attributes["wire_bytes"] == 960 + wire.guard
+    assert engine.stats["pack_s"] == encode.seconds
+    assert sum(s.seconds for s in spans if s is not encode) <= encode.seconds
+    res = engine.replay_resident(engine.upload_resident(wire))
+    assert (res.states["count"] == 20).all()
 
 
 def test_a_callers_open_span_stays_the_parent_of_all_three():
