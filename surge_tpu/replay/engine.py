@@ -38,8 +38,7 @@ from surge_tpu.tracing import SpanContext, default_tracer
 #: XLA names a program ``jit_<function>``, and the benchmark's trace reduction
 #: maps programs to layers by those names (benchmarks/programs/cold-fold.json):
 #: renaming one unmaps its program. Held by tests/test_replay_spans.py.
-COLD_PATH_JIT_NAMES = ("densify", "fold", "finalize_narrow", "finalize_wide",
-                       "mk")
+COLD_PATH_JIT_NAMES = ("densify", "fold", "finalize", "mk")
 
 #: the checkout's own persistent compile cache (listed in .gitignore). The
 #: path is part of every cache key, so it is fixed: never a temp, pid or
@@ -430,9 +429,14 @@ def _unapply_perm(perm: Optional[np.ndarray],
     return out
 
 
+def _side_nbytes(side: Mapping[str, Any]) -> int:
+    """Bytes of a wire's side columns."""
+    return int(sum(v.nbytes for v in side.values()))
+
+
 def _wire_nbytes(packed, side: Mapping[str, Any]) -> int:
     """Bytes of a wire's event buffers: the packed rows and the side columns."""
-    return int(packed.nbytes + sum(v.nbytes for v in side.values()))
+    return int(packed.nbytes) + _side_nbytes(side)
 
 
 def _bucket_len(n: int) -> int:
@@ -545,6 +549,16 @@ class ResidentPlan:
     def tiles(self) -> int:
         return len(self.big_i0) + len(self.small_i0)
 
+    @property
+    def slots_small(self) -> int:
+        """The padded slots the narrow granularity folds."""
+        return len(self.small_i0) * self.bs_small * self.width
+
+    @property
+    def rounds(self) -> int:
+        """Passes over time: the distinct tile offsets the plan visits."""
+        return len(np.union1d(self.big_tb, self.small_tb))
+
 
 @dataclass
 class ReplayResult:
@@ -635,11 +649,14 @@ class ReplayEngine:
         self._resident_dense_folds: dict = {}
         # on-device fresh init-slab builders per b_pad (zero host transfers)
         self._slab_programs: dict = {}
-        # the two state-pull finalize programs (wide/narrow), built once per
-        # engine — jax.jit's own shape cache handles differing batch sizes
-        # (streamed pieces are rebuilt per call; a per-corpus cache would
-        # re-jit them inside timed passes)
+        # the state-pull finalize programs, one per set of full-width columns,
+        # built once per engine — jax.jit's own shape cache handles differing
+        # batch sizes (streamed pieces are rebuilt per call; a per-corpus
+        # cache would re-jit them inside timed passes)
         self._finalize_programs: dict = {}
+        # the integer columns the last state pull found too wide for 16 bits:
+        # the next pull packs them full width from the start (_pull_states)
+        self._pull_wide: frozenset = frozenset()
         # distinct (fold-variant, window-shape) signatures — every entry corresponds
         # to one XLA compilation (shapes are static under jit), counted without any
         # private JAX internals
@@ -1055,6 +1072,7 @@ class ReplayEngine:
                     num_events=to_pack.num_events,
                     layout=wire.layout_fingerprint(), trace_ctx=enc.context)
             enc.set_attribute("wire_bytes", _wire_nbytes(packed, side_flat))
+            enc.set_attribute("side_bytes", _side_nbytes(side_flat))
             enc.set_attribute("blocks", blocks)
             enc.set_attribute("grouped", grouped)
             enc.set_attribute("lanes_from",
@@ -1114,7 +1132,8 @@ class ReplayEngine:
         stage = self.profiler.stage
         b = w.lengths.shape[0]
         with stage("h2d", follows=w.trace_ctx,
-                   wire_bytes=_wire_nbytes(w.packed, w.side)) as h2d:
+                   wire_bytes=_wire_nbytes(w.packed, w.side),
+                   side_bytes=_side_nbytes(w.side)) as h2d:
             with stage("h2d.bucket"):
                 pow2 = self.config.get_str(
                     "surge.replay.resident-len-bucket", "pow2") == "pow2"
@@ -1334,20 +1353,32 @@ class ReplayEngine:
     def _pull_states(self, slab: Mapping[str, Any], b: int,
                      perm: Optional[np.ndarray],
                      cache: Optional[dict] = None) -> dict[str, np.ndarray]:
-        """One-round-trip state pull: un-perm + truncate + bitcast-pack every
-        column into a single u32 matrix ON DEVICE, fetch once, un-bitcast on
-        the host. Each materialization of a computed device buffer is a
-        device→host round trip; per-field ``np.asarray`` paid it once per
-        column. ``cache`` (a per-corpus dict) memoizes the device
-        inverse-perm; omit it for throwaway corpora (streamed pieces).
-        """
+        """One-round-trip state pull: un-perm + truncate + pack every column
+        into a single u16 buffer ON DEVICE, fetch once, unpack on the host.
+        Each materialization of a computed device buffer is a device→host
+        round trip; per-field ``np.asarray`` paid it once per column.
+        ``cache`` (a per-corpus dict) memoizes the device inverse-perm; omit
+        it for throwaway corpora (streamed pieces).
+
+        The result transfer grows with the aggregate count, so integer and
+        bool columns ride a half-width wire: two bytes a value, with a fit
+        flag per column computed on the device. Correctness never depends on
+        a guess: a column packed narrow whose flag says it overflowed 16 bits
+        is fetched again, full width. The engine remembers from the flags of
+        its last pull which columns did not fit (:attr:`_pull_wide`) and packs
+        those full width at once, so a schema with a column that never fits
+        (a cart's ``total_cents``) pays the second round trip on its first
+        pull only; a column that fits again goes back to two bytes after the
+        pull that showed it. Float columns are always full width."""
         stage = self.profiler.stage
         fields = self.spec.registry.state.fields
         if any(np.dtype(f.dtype).itemsize > 4 for f in fields):
             # >32-bit columns don't fit the u32 packing — per-field pull
-            with stage("fetch.wait"):
+            with stage("fetch.wait") as wait:
                 out_sorted = {name: np.asarray(col)[:b]
                               for name, col in slab.items()}
+                wait.set_attribute("bytes", sum(
+                    int(col.nbytes) for col in out_sorted.values()))
             with stage("fetch.decode"):
                 return _unapply_perm(perm, out_sorted)
         inv = cache.get("invperm") if cache is not None else None
@@ -1361,100 +1392,107 @@ class ReplayEngine:
             if cache is not None:
                 cache["invperm"] = inv
         names = [f.name for f in fields]
-        dts = [np.dtype(f.dtype) for f in fields]
-        # all-integer/bool states ride the half-width wire: the result
-        # transfer grows with the aggregate count, and a u16 matrix with
-        # device-computed fit flags halves it; any overflowing column
-        # triggers one wide refetch (correctness never depends on the guess)
-        narrow_ok = not any(np.issubdtype(dt, np.floating) for dt in dts)
-        wide_prog = self._finalize_programs.get("wide")
-        if wide_prog is None:
+        floats = frozenset(f.name for f in fields
+                           if np.issubdtype(np.dtype(f.dtype), np.floating))
 
-            def finalize_wide(sl, ip):
-                cols = []
-                for name, dt in zip(names, dts):
-                    v = sl[name][ip]  # gather = un-perm + [:b] in one op
-                    if np.issubdtype(dt, np.floating) and dt.itemsize < 4:
-                        # f16/bf16 ride exactly as widened f32 bit patterns
-                        v = jax.lax.bitcast_convert_type(
-                            v.astype(jnp.float32), jnp.uint32)
-                    elif dt == np.bool_ or dt.itemsize < 4:
-                        v = v.astype(jnp.uint32)
-                    elif dt != np.dtype(np.uint32):
-                        v = jax.lax.bitcast_convert_type(v, jnp.uint32)
-                    cols.append(v)
-                return jnp.stack(cols)
+        def fetch(wide: frozenset):
+            kind = ("narrow" if not wide else
+                    "wide" if len(wide) == len(names) else "mixed")
+            with stage("fetch.wait", wire=kind) as wait:
+                # the one device→host fetch
+                buf = np.asarray(self._finalize_program(wide)(slab, inv))
+                wait.set_attribute("bytes", int(buf.nbytes))
+            return buf, dict(zip(names, buf[-len(names):]))
 
-            wide_prog = jax.jit(finalize_wide)
-            self._finalize_programs["wide"] = wide_prog
-
-        def decode_wide(mat):
-            out: dict[str, np.ndarray] = {}
-            for i, f in enumerate(fields):
-                dt = np.dtype(f.dtype)
-                raw = mat[i]
-                if np.issubdtype(dt, np.floating) and dt.itemsize < 4:
-                    out[f.name] = raw.view(np.float32).astype(dt)
-                elif dt == np.bool_ or dt.itemsize < 4:
-                    out[f.name] = raw.astype(dt)
-                else:
-                    out[f.name] = raw.view(dt).copy()
-            return out
-
-        def pull_wide():
-            with stage("fetch.wait", wire="wide"):
-                mat = np.asarray(wide_prog(slab, inv))
-            with stage("fetch.decode"):
-                return decode_wide(mat)
-
-        if not narrow_ok:
-            return pull_wide()
-
-        narrow_prog = self._finalize_programs.get("narrow")
-        if narrow_prog is None:
-
-            def finalize_narrow(sl, ip):
-                cols, flags = [], []
-                for name, dt in zip(names, dts):
-                    v = sl[name][ip]
-                    if dt == np.bool_:
-                        fits = jnp.bool_(True)
-                        v16 = v.astype(jnp.uint16)
-                    elif np.issubdtype(dt, np.signedinteger):
-                        fits = jnp.all((v >= -32768) & (v <= 32767))
-                        v16 = v.astype(jnp.uint16)  # wrap; host sign-extends
-                    else:
-                        fits = jnp.all(v <= 65535)
-                        v16 = v.astype(jnp.uint16)
-                    cols.append(v16.ravel())
-                    flags.append(fits.astype(jnp.uint16))
-                # one flat buffer, flags at the tail — a second buffer (or a
-                # full flag ROW) costs its own round trip / megabytes
-                return jnp.concatenate(cols + [jnp.stack(flags)])
-
-            narrow_prog = jax.jit(finalize_narrow)
-            self._finalize_programs["narrow"] = narrow_prog
-
-        with stage("fetch.wait", wire="narrow"):
-            # the one device→host fetch
-            buf16 = np.asarray(narrow_prog(slab, inv))
-        nf = len(fields)
-        if not buf16[nf * b:].all():
-            # a column overflowed 16 bits — refetch wide (extra round trip,
-            # still exact)
-            return pull_wide()
+        wide = floats | self._pull_wide
+        buf, fits = fetch(wide)
+        overflowed = frozenset(n for n in names
+                               if n not in wide and not fits[n])
+        if overflowed:
+            # the memory was wrong (or empty): refetch with the columns that
+            # overflowed full width — an extra round trip, still exact
+            wide |= overflowed
+            buf, fits = fetch(wide)
+        self._pull_wide = frozenset(n for n in names if not fits[n]) - floats
         with stage("fetch.decode"):
             out: dict[str, np.ndarray] = {}
-            for i, f in enumerate(fields):
+            at = 0
+            for f in self._finalize_order(wide):
                 dt = np.dtype(f.dtype)
-                raw = buf16[i * b: (i + 1) * b]
-                if dt == np.bool_:
-                    out[f.name] = raw.astype(dt)
-                elif np.issubdtype(dt, np.signedinteger):
-                    out[f.name] = raw.view(np.int16).astype(dt)
+                if f.name in wide:
+                    bits = (buf[at: at + b].astype(np.uint32)
+                            | (buf[at + b: at + 2 * b].astype(np.uint32)
+                               << np.uint32(16)))
+                    at += 2 * b
+                    if np.issubdtype(dt, np.floating):
+                        col = bits.view(np.float32).astype(dt)
+                    elif np.issubdtype(dt, np.signedinteger):
+                        col = bits.view(np.int32).astype(dt)
+                    else:
+                        col = bits.astype(dt)
                 else:
-                    out[f.name] = raw.astype(dt)
-            return out
+                    raw = buf[at: at + b]
+                    at += b
+                    col = (raw.view(np.int16).astype(dt)
+                           if np.issubdtype(dt, np.signedinteger)
+                           else raw.astype(dt))
+                out[f.name] = col
+            return {name: out[name] for name in names}
+
+    def _finalize_order(self, wide: frozenset) -> list:
+        """The state fields in the order the pull's buffer holds them: the
+        full-width columns first, so that each starts on a four-byte
+        boundary."""
+        fields = self.spec.registry.state.fields
+        return ([f for f in fields if f.name in wide]
+                + [f for f in fields if f.name not in wide])
+
+    def _finalize_program(self, wide: frozenset):
+        """The jitted state-pull program for one set of full-width columns:
+        ``(slab {f: [b_pad]}, inv [b]) -> u16 [(2 * len(wide) + narrow) * b +
+        fields]``. A column in ``wide`` is the low halves of its 32-bit
+        patterns, then the high halves (f16/bf16 ride exactly as widened f32
+        patterns, small signed integers sign-extended); any other is its
+        values wrapped to 16 bits (the host sign-extends). The tail holds one
+        flag per field, in field order: whether every value of the column
+        fits 16 bits (a float's never does) — one flat buffer, since a second
+        buffer (or a full flag ROW) costs its own round trip / megabytes."""
+        prog = self._finalize_programs.get(wide)
+        if prog is not None:
+            return prog
+        order = [(f.name, np.dtype(f.dtype))
+                 for f in self._finalize_order(wide)]
+        names = [f.name for f in self.spec.registry.state.fields]
+
+        def finalize(sl, ip):
+            parts, fits = [], {}
+            for name, dt in order:
+                v = sl[name][ip]  # gather = un-perm + [:b] in one op
+                if np.issubdtype(dt, np.floating):
+                    fits[name] = jnp.bool_(False)
+                    bits = jax.lax.bitcast_convert_type(
+                        v.astype(jnp.float32), jnp.uint32)
+                elif dt == np.bool_:
+                    fits[name] = jnp.bool_(True)
+                    bits = v.astype(jnp.uint32)
+                elif np.issubdtype(dt, np.signedinteger):
+                    fits[name] = jnp.all((v >= -32768) & (v <= 32767))
+                    bits = jax.lax.bitcast_convert_type(
+                        v.astype(jnp.int32), jnp.uint32)
+                else:
+                    fits[name] = jnp.all(v <= 65535)
+                    bits = v.astype(jnp.uint32)
+                if name in wide:
+                    parts.append((bits & np.uint32(0xFFFF)).astype(jnp.uint16))
+                    parts.append((bits >> np.uint32(16)).astype(jnp.uint16))
+                else:
+                    parts.append(bits.astype(jnp.uint16))  # wraps
+            flags = jnp.stack([fits[n] for n in names]).astype(jnp.uint16)
+            return jnp.concatenate(parts + [flags])
+
+        prog = jax.jit(finalize)
+        self._finalize_programs[wide] = prog
+        return prog
 
     def _dispatch_resident(self, resident: "ResidentCorpus",
                            init_carry: Mapping[str, Any] | None,
@@ -1475,9 +1513,15 @@ class ReplayEngine:
             init_sorted, ord_sorted = _apply_perm(resident.perm, init_carry,
                                                   ordinal_base)
             plan = self._plan_for(resident)
+            use_dense = self._use_dense(resident, plan)
             if umbrella is not None:
                 umbrella.set_attribute("padded_slots", plan.padded_slots)
                 umbrella.set_attribute("tiles", plan.tiles)
+                umbrella.set_attribute("layout",
+                                       "dense" if use_dense else "flat")
+                umbrella.set_attribute("rounds", plan.rounds)
+                umbrella.set_attribute("tiles_small", len(plan.small_i0))
+                umbrella.set_attribute("slots_small", plan.slots_small)
             if init_sorted is None and ord_sorted is None:
                 # fresh replay: build the init slab ON DEVICE (no host
                 # transfer on the replay's critical path); its dispatch is
@@ -1493,7 +1537,6 @@ class ReplayEngine:
                         slab_np[k][:b] = np.asarray(full)
                 slab = {k: jnp.asarray(v) for k, v in slab_np.items()}
                 ord_d = jnp.asarray(ord_p)
-            use_dense = self._use_dense(resident, plan)
             # one work list per lane granularity; the dense layout keeps its
             # own beside the tiles (_dense_tiles)
             work = []
