@@ -279,50 +279,160 @@ def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
     return fold_body
 
 
+#: events in one aligned row of the device's view of a flat buffer. A 1-D
+#: array of 32-bit values lies in HBM in (8, 128) tiles of 1024 consecutive
+#: elements, so ``[N] -> [N / 128, 128]`` is the same bytes (no copy), and a
+#: row of it is what the chip's native gather moves with many in flight.
+#: ``[N / 512, 512]`` is another layout: XLA copies the whole buffer to make it.
+_LANE_ROW = 128
+
+
+def _lane_gather() -> str:
+    """How a tile's lane rows leave the flat wire (:func:`_make_lane_fetch`):
+    ``rows`` on an accelerator, ``slices`` on a CPU host, where one slice a
+    lane is a memcpy and the row fetch with its shift passes measured slower
+    (1.25 times on an int32 column, 12 times on the one-byte word: PERF.md,
+    PR 28). Decided per backend like ``tile_backend`` and ``_use_dense``: no
+    config key selects it."""
+    return "slices" if jax.default_backend() == "cpu" else "rows"
+
+
+def _rows_per_lane(width: int, gather: str) -> int:
+    """What one lane asks of one array for one tile: the aligned rows that
+    cover any ``width`` events starting anywhere in a row, or the one slice."""
+    if gather == "slices":
+        return 1
+    return (width + _LANE_ROW - 2) // _LANE_ROW + 1
+
+
+def _round_rows(arr):
+    """A device buffer zero-padded to whole rows of :data:`_LANE_ROW` events
+    (``resident-len-bucket = exact``; a power-of-two bucket already is)."""
+    extra = -arr.shape[0] % _LANE_ROW
+    if not extra:
+        return arr
+    return jnp.pad(arr, [(0, extra)] + [(0, 0)] * (arr.ndim - 1))
+
+
+def _make_lane_fetch(wire: WireFormat, width: int, gather: str):
+    """How a tile's lane rows get from the flat wire into ``[width, bs]``,
+    for the flat tile and the densify gather alike. Returns ``(view, fetch)``:
+
+    - ``view(flat_wire u8 [N, nbytes], side_flat {n: [N]}) -> buffers``, once
+      a program, outside the tile loop;
+    - ``fetch(buffers, p i32 [bs]) -> (words u32 [width, bs], sides {n:
+      [width, bs]})``: column ``l`` holds events ``[p[l], p[l] + width)``,
+      with ``p`` clamped into the buffer as ``dynamic_slice`` clamps it
+      (finished and padding lanes, ``_NOOP_TILE_T`` work-list entries: their
+      garbage decodes under a False mask).
+
+    Both lowerings give the same tile, element for element.
+
+    ``slices``: one ``dynamic_slice`` a lane and array. The v5e runs that as
+    a ``while`` loop of ``bs`` trips bound by trips, not bytes (1.2 us a lane
+    for 512 B and 2 KB alike).
+
+    ``rows``: every buffer is widened to 32-bit values once a program (the
+    packed word by :meth:`WireFormat.expand_flat`) and viewed as ``[N / A,
+    A]`` (``A`` = :data:`_LANE_ROW`). A lane whose window starts at ``p =
+    q * A + o`` fetches the aligned rows ``q .. q + R - 1`` in one native
+    gather over all lanes (each row index clamped on its own), and is brought
+    back to offset 0 in time-major vector code: bit ``k`` of ``o`` selects
+    between the tile and the tile ``2^k`` events on, seven passes for any
+    ``o``, each over fewer rows than the last. 0.065 us a lane and array on
+    the v5e (PERF.md, PR 28)."""
+    nbytes = wire.nbytes
+
+    if gather == "slices":
+        def view(flat_wire, side_flat):
+            return flat_wire, side_flat
+
+        def fetch(buffers, p):
+            flat_wire, side_flat = buffers
+            bs = p.shape[0]
+            word = jax.vmap(lambda s0: jax.lax.dynamic_slice(
+                flat_wire, (s0, 0), (width, nbytes)))(p)
+            word = wire.expand_flat(word.reshape(bs * width, nbytes))
+            cut = jax.vmap(lambda arr, s0: jax.lax.dynamic_slice(
+                arr, (s0,), (width,)), in_axes=(None, 0))
+            return (word.reshape(bs, width).T,  # [width, bs]
+                    {n: cut(arr, p).T for n, arr in side_flat.items()})
+
+        return view, fetch
+
+    a = _LANE_ROW
+    r = _rows_per_lane(width, gather)
+
+    def carrier(dtype):
+        # narrower values ride as 32-bit ones: native (8, 128) rows
+        dt = np.dtype(dtype)
+        if dt.itemsize >= 4:
+            return dt
+        return np.dtype(np.float32 if dt.kind == "f" else np.int32)
+
+    def view(flat_wire, side_flat):
+        # whole rows: a power-of-two bucket, or upload_resident's rounding
+        m = flat_wire.shape[0] // a
+        return (wire.expand_flat(flat_wire).reshape(m, a),
+                {name: arr.astype(carrier(arr.dtype)).reshape(m, a)
+                 for name, arr in side_flat.items()})
+
+    def fetch(buffers, p):
+        word_rows, side_rows = buffers
+        m = word_rows.shape[0]
+        bs = p.shape[0]
+        p = jnp.clip(p, 0, m * a - width)  # dynamic_slice's clamp
+        q, o = p // a, p % a
+        idx = q[:, None] + jnp.arange(r, dtype=jnp.int32)[None, :]
+
+        def lanes(arr):
+            # mode="clip": a row index past the buffer reads its last row
+            x = jnp.take(arr, idx, axis=0, mode="clip")  # [bs, r, a]
+            x = x.reshape(bs, r * a).T  # time-major [r * a, bs]
+            s = a // 2
+            while s:
+                keep = x.shape[0] - s
+                x = jnp.where((o & s) != 0, x[s:], x[:keep])
+                s //= 2
+            return x[:width]
+
+        return (lanes(word_rows),
+                {f.name: lanes(side_rows[f.name]).astype(f.dtype)
+                 for f in wire.side_fields})
+
+    return view, fetch
+
+
 def _make_tile(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
-               unroll: int, dispatch: str, tile_backend: str):
+               unroll: int, dispatch: str, tile_backend: str, gather: str):
     """The flat-gather tile of the resident programs (single-device AND
-    mesh-sharded): ``(state_slab {f: [b_pad]}, flat_wire u8 [N, nbytes],
-    side_flat, starts [b_pad], lens [b_pad], ord_base [b_pad], i0, t_base)
-    -> state_slab``.
+    mesh-sharded), as ``(view, tile)``: ``view(flat_wire u8 [N, nbytes],
+    side_flat) -> buffers`` once a program, and ``tile(state_slab {f:
+    [b_pad]}, buffers, starts [b_pad], lens [b_pad], ord_base [b_pad], i0,
+    t_base) -> state_slab`` in its loop.
 
     One tile folds events ``[t_base, t_base+width)`` of lanes
-    ``[i0, i0+bs)``: per-lane contiguous ``dynamic_slice`` slabs out of the
-    flat packed corpus (events of one aggregate are adjacent), byte→word
-    expansion in-register, one transpose to time-major, the shared fold body
+    ``[i0, i0+bs)``: every lane's contiguous window out of the flat packed
+    corpus (events of one aggregate are adjacent) as a time-major tile
+    (:func:`_make_lane_fetch`), the shared fold body
     (:func:`_make_fold_body`), and a contiguous write-back into the state
     slab. ``i0``/``t_base`` are traced scalars."""
-    nbytes = wire.nbytes
     fold_body = _make_fold_body(spec, wire, width, bs, unroll, dispatch,
                                 tile_backend)
+    view, fetch = _make_lane_fetch(wire, width, gather)
 
-    def tile(slab_state, flat_wire, side_flat, starts_all, lens_all,
-             ord_all, i0, t_base):
+    def tile(slab_state, buffers, starts_all, lens_all, ord_all, i0, t_base):
         starts = jax.lax.dynamic_slice(starts_all, (i0,), (bs,))
         lens = jax.lax.dynamic_slice(lens_all, (i0,), (bs,))
         ord_base = jax.lax.dynamic_slice(ord_all, (i0,), (bs,))
         carry = {k: jax.lax.dynamic_slice(v, (i0,), (bs,))
                  for k, v in slab_state.items()}
-
-        def slab(arr):
-            # dynamic_slice clamps out-of-range starts (finished/padding
-            # lanes); clamped garbage decodes under a False mask
-            cut = jax.vmap(
-                lambda s0: jax.lax.dynamic_slice(arr, (s0,), (width,)))
-            return cut(starts + t_base).T  # [width, bs], rows contiguous
-
-        word = jax.vmap(
-            lambda s0: jax.lax.dynamic_slice(
-                flat_wire, (s0, 0), (width, nbytes)))(starts + t_base)
-        word = wire.expand_flat(word.reshape(bs * width, nbytes))
-        words = word.reshape(bs, width).T  # [width, bs]
-        sides = {name: slab(arr) for name, arr in side_flat.items()}
-
+        words, sides = fetch(buffers, starts + t_base)
         out = fold_body(carry, words, sides, lens, ord_base, t_base)
         return {k: jax.lax.dynamic_update_slice(slab_state[k], out[k], (i0,))
                 for k in slab_state}
 
-    return tile
+    return view, tile
 
 
 def _make_tile_dense(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
@@ -358,27 +468,25 @@ def _make_tile_dense(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
     return tile
 
 
-def _make_densify(wire: WireFormat, width: int, bs: int):
+def _make_densify(wire: WireFormat, width: int, bs: int, gather: str):
     """One-time device-side tile gather: ``(flat_wire u8 [N, nbytes],
     side_flat {n: [N]}, starts_all, i0s [k_cap], t_bases [k_cap]) ->
     (dense_words u8 [k_cap, width, bs, nbytes], dense_sides
-    {n: [k_cap, width, bs]})``.
+    {n: [k_cap, width, bs]})``, every tile through
+    :func:`_make_lane_fetch` with the word narrowed back to its bytes.
 
-    Work-list entries past ``k_n`` gather lane 0's window — garbage the fold
-    never reads (its trip count is ``k_n``)."""
-    nbytes = wire.nbytes
+    Work-list entries past ``k_n`` carry ``_NOOP_TILE_T`` and gather the
+    buffer's last window — garbage every slot of which masks to padding."""
+    view, fetch = _make_lane_fetch(wire, width, gather)
 
     def densify(flat_wire, side_flat, starts_all, i0s, t_bases):
+        buffers = view(flat_wire, side_flat)
+
         def one(args):
             i0, tb = args
             starts = jax.lax.dynamic_slice(starts_all, (i0,), (bs,))
-            rows = jax.vmap(lambda s0: jax.lax.dynamic_slice(
-                flat_wire, (s0, 0), (width, nbytes)))(starts + tb)
-            w = jnp.transpose(rows, (1, 0, 2))  # [width, bs, nbytes]
-            sides = {n: jax.vmap(lambda s0: jax.lax.dynamic_slice(
-                arr, (s0,), (width,)))(starts + tb).T
-                for n, arr in side_flat.items()}
-            return w, sides
+            words, sides = fetch(buffers, starts + tb)
+            return wire.narrow_words(words), sides  # [width, bs, nbytes]
 
         return jax.lax.map(one, (i0s, t_bases))
 
@@ -664,9 +772,10 @@ class ReplayEngine:
         # host-side phase accounting (bench breakdown), fed by the profiler's
         # stages: seconds of the encode stages (a window's pack, the whole of
         # pack_resident), of the h2d stages (a window's transfer, the whole of
-        # upload_resident) and of the densify dispatches, and windows dispatched
+        # upload_resident) and of the densify dispatches, windows dispatched,
+        # and the lane-row fetches the resident tiles asked for (_rows_fetched)
         self.stats = {"pack_s": 0.0, "h2d_s": 0.0, "windows": 0,
-                      "densify_s": 0.0}
+                      "densify_s": 0.0, "rows_fetched": 0}
         if mesh is not None:
             pspec = jax.sharding.PartitionSpec(mesh_axis)
             self._sharding = jax.sharding.NamedSharding(mesh, pspec)
@@ -1160,6 +1269,10 @@ class ReplayEngine:
                 flat_wire = _chunked_put(packed_b, chunk_mb)
                 flat_side = {k: _chunked_put(v, chunk_mb)
                              for k, v in side_b.items()}
+                if self.lane_gather == "rows":
+                    flat_wire = _round_rows(flat_wire)
+                    flat_side = {k: _round_rows(v)
+                                 for k, v in flat_side.items()}
                 starts_dev = jax.device_put(starts_p)
                 lens_dev = jax.device_put(lens_p)
                 jax.block_until_ready(flat_wire)
@@ -1558,6 +1671,7 @@ class ReplayEngine:
 
         # two chained dispatches (big tiles, then small); per-lane order holds
         # because a lane only ever migrates big→small as the prefix shrinks
+        rows_before = self.stats["rows_fetched"]
         for bs, i0s, t_bases, k_cap, lists in work:
             k_n = len(i0s)
             self.stats["windows"] += k_n
@@ -1571,6 +1685,8 @@ class ReplayEngine:
                 args = (dw, ds, resident.lens_dev, ord_d, i0s_d, tbs_d)
             else:
                 fold = self._resident_program(key, plan.width, bs, k_cap)
+                self.stats["rows_fetched"] += self._rows_fetched(
+                    resident, plan.width, k_n * bs)
                 sig = ("resident", key, plan.width, bs, k_cap, b_pad,
                        int(resident.flat_wire.shape[0]))
                 args = (resident.flat_wire, resident.flat_side,
@@ -1582,7 +1698,26 @@ class ReplayEngine:
             with stage("compile" if first_dispatch else "dispatch",
                        tiles=k_n, batch=bs):
                 slab = fold(slab, *args)
+        if umbrella is not None:
+            # a dense corpus fetches in its first fold only (replay.densify)
+            umbrella.set_attribute("gather", self.lane_gather)
+            umbrella.set_attribute(
+                "rows_fetched", self.stats["rows_fetched"] - rows_before)
         return slab, plan.padded_slots
+
+    @property
+    def lane_gather(self) -> str:
+        """How this engine's tiles fetch their lane rows: ``rows`` or
+        ``slices`` (:func:`_lane_gather`)."""
+        return _lane_gather()
+
+    def _rows_fetched(self, resident: "ResidentCorpus", width: int,
+                      lanes: int) -> int:
+        """The fetches ``lanes`` lane windows ask of a corpus's buffers:
+        aligned rows under ``rows``, slices under ``slices``, of the word and
+        of every side column (before XLA drops a column no handler reads)."""
+        return (lanes * _rows_per_lane(width, self.lane_gather)
+                * (1 + len(resident.flat_side)))
 
     @property
     def tile_backend(self) -> str:
@@ -1676,8 +1811,12 @@ class ReplayEngine:
                 np.asarray(i0s, np.int32).tobytes(),
                 np.asarray(t_bases, np.int32).tobytes())
         hit = resident.cache.get(ckey)
+        rows = (0 if hit is not None else
+                self._rows_fetched(resident, plan.width, k_cap * bs))
         with self.profiler.stage("densify", cached=hit is not None,
-                                 tiles=len(i0s), batch=bs) as densify:
+                                 tiles=len(i0s), batch=bs,
+                                 gather=self.lane_gather,
+                                 rows_fetched=rows) as densify:
             if hit is not None:
                 return hit
             dkey = (key, plan.width, bs)
@@ -1685,7 +1824,8 @@ class ReplayEngine:
             if dens is None:
                 wire = WireFormat(self.spec.registry,
                                   dict(resident.derived_key))
-                dens = jax.jit(_make_densify(wire, plan.width, bs))
+                dens = jax.jit(_make_densify(wire, plan.width, bs,
+                                             self.lane_gather))
                 self._densify_programs[dkey] = dens
             i0s_p = np.zeros((k_cap,), dtype=np.int32)
             i0s_p[: len(i0s)] = i0s
@@ -1702,6 +1842,7 @@ class ReplayEngine:
             entry = (dw, ds, i0s_d, tbs_d)
             resident.cache[ckey] = entry
         self.stats["densify_s"] += densify.seconds
+        self.stats["rows_fetched"] += rows
         return entry
 
     def _resident_program_dense(self, key: frozenset, width: int, bs: int,
@@ -1813,9 +1954,11 @@ class ReplayEngine:
         bounds.append(n_lanes)
 
         # every piece's upload, dispatches and pull under one umbrella
+        rows_before = self.stats["rows_fetched"]
         with self.profiler.stage("resident", follows=w.trace_ctx,
                                  aggregates=b, events=w.num_events,
-                                 segments=segments):
+                                 segments=segments,
+                                 gather=self.lane_gather) as umbrella:
             pieces: list = []
             padded = 0
             first_piece = True
@@ -1859,6 +2002,8 @@ class ReplayEngine:
                 # hold ONLY what the sync pass needs — keeping the piece corpus
                 # itself would pin every piece's wire buffers in HBM at once
                 pieces.append((lanes, slab))  # ...fold dispatched, NOT synced
+            umbrella.set_attribute(
+                "rows_fetched", self.stats["rows_fetched"] - rows_before)
             # one sync pass over every piece — a single packed fetch per piece
             # (every materialized buffer is its own device→host round trip; the
             # old per-piece-per-field np.asarray paid pieces × fields of them),
@@ -1944,13 +2089,13 @@ class ReplayEngine:
 
         A ``fori_loop`` over the tile work list; tile k folds events
         ``[t_bases[k], t_bases[k]+width)`` of lanes ``[i0s[k], i0s[k]+bs)``:
-        per-lane contiguous ``dynamic_slice`` slabs out of the flat packed
-        corpus (events of one aggregate are adjacent), byte→word expansion
-        in-register, one transpose to time-major, a dense scan, and a
-        contiguous write-back into the state slab. The trip count is traced,
-        so one compiled program serves every corpus in the k_cap bucket and
-        the whole replay crosses the host⇄device boundary exactly twice
-        (dispatch in, states out)."""
+        every lane's contiguous window out of the flat packed corpus (events
+        of one aggregate are adjacent) as a time-major tile
+        (:func:`_make_lane_fetch`; the buffers viewed once, before the loop),
+        the fold body, and a contiguous write-back into the state slab. The
+        trip count is traced, so one compiled program serves every corpus in
+        the k_cap bucket and the whole replay crosses the host⇄device boundary
+        exactly twice (dispatch in, states out)."""
         cache_key = (key, width, bs, k_cap)
         hit = self._resident_folds.get(cache_key)
         if hit is not None:
@@ -1958,14 +2103,17 @@ class ReplayEngine:
         import jax
 
         wire = WireFormat(self.spec.registry, dict(key))
-        tile = _make_tile(self.spec, wire, width, bs, self._unroll,
-                          self._dispatch, self.tile_backend)
+        view, tile = _make_tile(self.spec, wire, width, bs, self._unroll,
+                                self._dispatch, self.tile_backend,
+                                self.lane_gather)
 
         def fold(slab_state, flat_wire, side_flat, starts_all, lens_all,
                  ord_all, i0s, t_bases, k_n):
+            buffers = view(flat_wire, side_flat)
+
             def body(k, st):
-                return tile(st, flat_wire, side_flat, starts_all, lens_all,
-                            ord_all, i0s[k], t_bases[k])
+                return tile(st, buffers, starts_all, lens_all, ord_all,
+                            i0s[k], t_bases[k])
 
             return jax.lax.fori_loop(0, k_n, body, slab_state)
 

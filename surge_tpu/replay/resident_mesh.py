@@ -163,18 +163,18 @@ def _sharded_program(engine, key: frozenset, width: int, bs: int, k_cap: int):
     from jax.sharding import PartitionSpec as P
 
     wire = WireFormat(engine.spec.registry, dict(key))
-    tile = _make_tile(engine.spec, wire, width, bs, engine._unroll,
-                      engine._dispatch, engine.tile_backend)
+    view, tile = _make_tile(engine.spec, wire, width, bs, engine._unroll,
+                            engine._dispatch, engine.tile_backend,
+                            engine.lane_gather)
 
     def local_fold(slab_state, flat_wire, side_flat, starts_all, lens_all,
                    ord_all, i0s, t_bases, k_n):
         # local blocks arrive with the device axis (size 1) still on; drop it
         slab0 = {k: v[0] for k, v in slab_state.items()}
-        fw0 = flat_wire[0]
-        sf0 = {k: v[0] for k, v in side_flat.items()}
+        buffers = view(flat_wire[0], {k: v[0] for k, v in side_flat.items()})
 
         def body(k, st):
-            return tile(st, fw0, sf0, starts_all[0], lens_all[0], ord_all[0],
+            return tile(st, buffers, starts_all[0], lens_all[0], ord_all[0],
                         i0s[0, k], t_bases[0, k])
 
         out = jax.lax.fori_loop(0, k_n[0], body, slab0)

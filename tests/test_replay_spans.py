@@ -142,6 +142,45 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(layout):
                if s.name == "replay.densify")
 
 
+@pytest.mark.parametrize("layout", ["flat", "dense"])
+@pytest.mark.parametrize("gather, per_lane", [("slices", 1), ("rows", 2)])
+def test_the_fold_spans_say_how_the_lane_rows_were_fetched(monkeypatch, gather,
+                                                           per_lane, layout):
+    """``gather`` and ``rows_fetched`` on ``replay.resident`` (both layouts)
+    and ``replay.densify`` (dense): a window of 16 events starting anywhere
+    needs two aligned rows of 128, or one slice; the counter's wire is one
+    array. A dense corpus fetches once, in the fold that builds its tiles."""
+    monkeypatch.setattr(engine_module, "_lane_gather", lambda: gather)
+    engine = make_engine(layout)
+    since = time.monotonic()
+    _, resident, _ = rebuild(engine, make_events())
+    spans = ring_since(since)
+    plan = engine._plan_for(resident)
+    work = [(len(i0), bs) for i0, bs in ((plan.big_i0, plan.bs_big),
+                                         (plan.small_i0, plan.bs_small))
+            if len(i0)]
+    fold = one(spans, "replay.resident")
+    assert fold.attributes["gather"] == gather
+    densify = [s for s in spans if s.name == "replay.densify"]
+    if layout == "dense":
+        # densify fetches its whole padded work list (_plan_cap entries)
+        want = [engine._plan_cap(k) * bs * per_lane for k, bs in work]
+        assert [s.attributes["rows_fetched"] for s in densify] == want
+        assert {s.attributes["gather"] for s in densify} == {gather}
+    else:
+        want = [k * bs * per_lane for k, bs in work]
+        assert not densify
+    assert fold.attributes["rows_fetched"] == sum(want) > 0
+    assert engine.stats["rows_fetched"] == sum(want)
+    since = time.monotonic()
+    engine.replay_resident(resident)
+    again = ring_since(since)
+    assert one(again, "replay.resident").attributes["rows_fetched"] == (
+        0 if layout == "dense" else sum(want))
+    assert all(s.attributes["rows_fetched"] == 0 for s in again
+               if s.name == "replay.densify")
+
+
 @pytest.mark.parametrize("grouped, block, blocks, lanes_from", [
     (True, 1 << 18, 1, "boundaries"), (True, 100, 10, "boundaries"),
     (True, 7, 138, "boundaries"), (False, 100, 10, "bincount")])
