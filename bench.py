@@ -119,11 +119,9 @@ def make_engine():
         # sweep overrides with whatever measures best on chip
         "surge.replay.time-chunk": int(os.environ.get("SURGE_BENCH_TIME_CHUNK", 64)),
         "surge.replay.dispatch": os.environ.get("SURGE_BENCH_DISPATCH", "switch"),
-        # auto: assoc tree fold for models with an AssociativeFold, dense
-        # pre-gathered tiles on accelerators (the r5 on-chip redesign)
+        # auto: assoc tree fold for models with an AssociativeFold on
+        # accelerators (the r5 on-chip redesign)
         "surge.replay.tile-backend": os.environ.get("SURGE_BENCH_TILE", "auto"),
-        "surge.replay.resident-layout": os.environ.get("SURGE_BENCH_LAYOUT",
-                                                       "auto"),
         "surge.replay.upload-chunk-mb": int(
             os.environ.get("SURGE_BENCH_UPLOAD_CHUNK_MB", 0)),
         # single corpus, explicit warm: exact buffer length, no bucket padding
@@ -230,9 +228,6 @@ def replay_child(corpus_dir: str) -> None:
             # not replay — the timed pass still re-uploads its per-replay
             # inputs and re-folds every event
             engine.warm_resident(resident)
-            # under the dense layout the warm pass runs the one-time tile
-            # gather — a COLD cost, charged to replay_s below
-            densify_s = engine.stats["densify_s"]
             engine.replay_resident(resident)
             engine.stats["windows"] = 0  # count only the timed pass's windows
             warm_compiles = engine.num_compiles()
@@ -256,9 +251,8 @@ def replay_child(corpus_dir: str) -> None:
                 result = engine.replay_resident(resident)
                 steady_s = min(steady_s, time.perf_counter() - t0)
             engine.stats["windows"] = timed_windows
-            replay_s = prepare_s + densify_s + fold_s
+            replay_s = prepare_s + fold_s
             extra_timing = {"upload_s": round(resident.upload_s, 2),
-                            "densify_s": round(densify_s, 2),
                             "fold_s": round(fold_s, 2),
                             "steady_replay_s": round(steady_s, 3),
                             "steady_events_per_sec": round(
@@ -303,8 +297,6 @@ def replay_child(corpus_dir: str) -> None:
         "knobs": {"dispatch": engine._dispatch, "unroll": engine._unroll,
                   "time_chunk": engine.time_chunk, "batch": engine.batch_size,
                   "tile": engine.tile_backend,
-                  "layout": engine._resident_layout,
-                  "densify_s": round(engine.stats["densify_s"], 2),
                   "upload_chunk_mb": engine.config.get_int(
                       "surge.replay.upload-chunk-mb", 0)},
         **extra_timing,
@@ -329,7 +321,7 @@ def _device_resident_fold_rate(engine, corpus) -> float:
     chunk = max(engine.time_chunk, 1)
     key, wire, fold = engine._wire_fold({"sequence_number": "ordinal"})
     ev = corpus.events
-    # one full window of real corpus data (batch-major [b, T] densify)
+    # one full window of real corpus data (padded batch-major [b, T])
     from surge_tpu.codec.tensor import columnar_to_batch
 
     sub = ev.sorted_by_aggregate().slice_aggregates(0, min(bs, ev.num_aggregates))
@@ -1566,7 +1558,7 @@ def _merge_replay(payload: dict, child: dict, cpu_eps: float) -> None:
     payload["vs_baseline"] = round(child["events_per_sec"] / cpu_eps, 2) if cpu_eps else 0
     for k in ("platform", "aggregates_per_sec", "replay_s", "pad_ratio", "pack_s",
               "h2d_s", "windows", "compiles", "device_fold_events_per_sec",
-              "upload_s", "densify_s", "fold_s", "steady_replay_s",
+              "upload_s", "fold_s", "steady_replay_s",
               "steady_events_per_sec", "wire_mb", "stream_segments", "knobs"):
         if k in child:
             payload[k] = child[k]
@@ -2651,7 +2643,7 @@ def main() -> None:
 
     # SURGE_BENCH_RESIDENT=1: device-resident read-plane fast path — read
     # ladder + refresh-loop folds + command guard, no corpus build. The full
-    # corpus run below still replays through the resident layout by default;
+    # corpus run below still replays through the resident path by default;
     # SURGE_BENCH_STREAMING=1 (or the legacy SURGE_BENCH_RESIDENT=0) selects
     # the streaming window path there instead.
     if os.environ.get("SURGE_BENCH_RESIDENT", "0") == "1":

@@ -2,7 +2,7 @@
 """Chip smoke: the cold fold and the served resident path, once, on the attached TPU.
 
 One process, two legs through the public entry points with engine defaults
-(``tile-backend=auto``, ``resident-layout=auto``, ``refresh-dispatch=bucketed``,
+(``tile-backend=auto``, ``refresh-dispatch=bucketed``,
 donation on), every answer checked against a plain reference outside any timing:
 
 - **cold**: counter, 1,000,000 aggregates / 100,000,000 events (BASELINE.json)
@@ -185,7 +185,6 @@ def leg_cold(sizes: dict, seed: int) -> dict:
              "states_equal_closed_form": corpus.num_aggregates,
              "scalar_sample_aggregates": int(len(idx)),
              "tile_backend": engine.tile_backend,
-             "dense_layout": engine.stats["densify_s"] > 0,
              "pad_ratio": round(result.padded_events / corpus.num_events, 3),
              "corpus_build_s": round(build_s, 2), "pack_s": round(pack_s, 2),
              "upload_s": round(upload_s, 2),

@@ -6,7 +6,7 @@ once then never again, a wall-clock read bakes a constant timestamp into the
 compiled program, and mutation of closed-over host state (``stats.append``,
 ``cache[k] = …``) happens at trace time only — silently wrong on every
 subsequent cached-compilation call. The replay engine's fold builders
-(``fold_resident_slab``, ``_make_densify``, the ``replay_*`` programs) are
+(``fold_resident_slab``, ``_make_tile``, the ``replay_*`` programs) are
 all built this way, so the ROADMAP item-3 push of the hot path off the GIL
 multiplies the blast radius of one impure fold.
 """

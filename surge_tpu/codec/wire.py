@@ -345,16 +345,6 @@ class WireFormat:
             word = word | (packed[:, k].astype(jnp.uint32) << np.uint32(8 * k))
         return word
 
-    def narrow_words(self, word: Any) -> Any:
-        """The inverse of :meth:`expand_flat`: u32 words ``[...]`` back to the
-        packed bytes ``[..., nbytes]`` (the dense tile buffers hold the wire's
-        own bytes, not the widened word)."""
-        import jax.numpy as jnp
-
-        return jnp.stack(
-            [((word >> np.uint32(8 * k)) & np.uint32(0xFF)).astype(jnp.uint8)
-             for k in range(self.nbytes)], axis=-1)
-
     def decode_words(self, word: Any, side_row: Mapping[str, Any], valid: Any,
                      ord_base: Any, t: Any) -> dict[str, Any]:
         """JAX-traceable decode of one scan step's word row ``[B]`` (extracted

@@ -148,10 +148,6 @@ DEFAULTS: dict[str, Any] = {
     # picks the scanless assoc tree fold for models shipping AssociativeFold)
     "surge.replay.dispatch": "switch",  # switch | select
     "surge.replay.tile-backend": "auto",  # auto | xla | pallas | assoc
-    # resident tile layout: "dense" pre-gathers every tile once per corpus
-    # when the buffers fit dense-cap-mb of HBM; "flat" gathers per pass
-    "surge.replay.resident-layout": "auto",  # auto | flat | dense
-    "surge.replay.dense-cap-mb": 2048,
     # bucket resident-corpus row lengths to powers of two ("pow2") so the
     # jit cache sees few shapes, or keep exact lengths ("exact")
     "surge.replay.resident-len-bucket": "pow2",  # pow2 | exact

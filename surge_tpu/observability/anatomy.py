@@ -37,8 +37,8 @@ span's wall time into named legs along the ack critical path:
   ``decode`` — the DEVICE legs (the fold anatomy, ISSUE 16): resident-plane
   ``resident.gather`` and engine ``query.scan`` spans carry measured
   ``leg.{coalesce,dispatch,fetch,decode}-ms`` attributes, and the replay
-  profiler's ``replay.dispatch``/``replay.compile``/``replay.densify``/
-  ``replay.fetch`` stage spans map by name — so a stalled refresh dispatch names
+  profiler's ``replay.dispatch``/``replay.compile``/``replay.fetch`` stage
+  spans map by name — so a stalled refresh dispatch names
   ``device-dispatch`` dominant the same way a slow WAL names
   ``journal-fsync``;
 - ``other`` — root residue none of the above claims (reply fan-out, event
@@ -99,7 +99,6 @@ _DEVICE_ATTR_LEGS = (("leg.coalesce-ms", "gather-coalesce"),
 #: are not device legs)
 _DEVICE_NAME_LEGS = {"replay.dispatch": "device-dispatch",
                      "replay.compile": "device-dispatch",
-                     "replay.densify": "device-dispatch",
                      "replay.fetch": "fetch-barrier"}
 
 

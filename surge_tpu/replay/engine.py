@@ -38,7 +38,7 @@ from surge_tpu.tracing import SpanContext, default_tracer
 #: XLA names a program ``jit_<function>``, and the benchmark's trace reduction
 #: maps programs to layers by those names (benchmarks/programs/cold-fold.json):
 #: renaming one unmaps its program. Held by tests/test_replay_spans.py.
-COLD_PATH_JIT_NAMES = ("densify", "fold", "finalize", "mk")
+COLD_PATH_JIT_NAMES = ("fold", "finalize", "mk")
 
 #: the checkout's own persistent compile cache (listed in .gitignore). The
 #: path is part of every cache key, so it is fixed: never a temp, pid or
@@ -167,8 +167,8 @@ class ResidentCorpus:
     num_events: int
     wire_bytes: int  # bytes actually shipped to the device
     upload_s: float
-    #: per-corpus device caches (tile plan, dense tile buffers, worklists) —
-    #: populated lazily by the engine, keyed by plan geometry
+    #: per-corpus caches, filled lazily by the engine: the tile plan (keyed by
+    #: its geometry) and the state pull's device inverse-perm
     cache: dict = dc_field(default_factory=dict)
     #: context of the ``replay.h2d`` span that uploaded it: a fold of this
     #: corpus continues that trace
@@ -179,25 +179,10 @@ class ResidentCorpus:
 #: small tile-width cap still satisfies engines configured with a larger one
 _WIRE_GUARD_MIN = 8192
 
-#: t_base sentinel marking a dense work-list padding entry: past every real
-#: lane length (lengths are int32 event counts ≪ 2^29) yet small enough that
-#: start+t arithmetic stays far from int32 overflow. Ordinal arithmetic has
-#: the same shape: the fold body computes ``ord_base + t_base`` (pallas) or
-#: ``ord_base + t + 1`` per step (xla/assoc), and ``ord_base`` is itself an
-#: int32 already-folded event count < 2^29, so a sentinel tile's derived
-#: ordinals reach at most 2^30 + width — still far from int32 overflow. A
-#: resumed ``ordinal_base`` ABOVE 2^30 would wrap in a sentinel tile, but
-#: every sentinel slot decodes under a False mask (t ≥ lens for all lanes),
-#: so the wrapped value is provably never folded; the pallas branch clamps
-#: the sentinel before the add anyway so its ord_rel input stays in-range
-#: (see _make_fold_body).
-_NOOP_TILE_T = np.int32(1 << 29)
-
-
 def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
                     unroll: int, dispatch: str, tile_backend: str):
-    """The tile-interior fold shared by the flat-gather and dense-layout
-    resident tiles: ``(carry {f: [bs]}, words u32 [width, bs],
+    """The tile-interior fold of the resident tiles (single-device and
+    mesh-sharded): ``(carry {f: [bs]}, words u32 [width, bs],
     sides {n: [width, bs]}, lens [bs], ord_base [bs], t_base) -> carry``.
 
     Three lowerings per ``tile_backend``: the sequential XLA time scan, the
@@ -229,20 +214,9 @@ def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
 
     def fold_body(carry, words, sides, lens, ord_base, t_base):
         if pallas_scan is not None:
-            # the dense scan as a VMEM-resident kernel (relative time).
-            # t_base is clamped before the ordinal add: a _NOOP_TILE_T
-            # sentinel tile (dense-layout work-list padding) would otherwise
-            # push ord_base + t_base past 2^30, wrapping int32 for resumed
-            # ordinal bases above ~2^30 — harmless (every sentinel slot masks
-            # to padding via the hugely-negative lens - t_base) but the clamp
-            # keeps the kernel's ord_rel input in-range by construction:
-            # ord_base (< 2^29) + the clamped sentinel (2^29 - 1) < 2^30.
-            # Real tiles always have t_base < max lane length ≪ 2^29, so the
-            # clamp is the identity for every tile that folds anything.
-            t_ord = jnp.minimum(jnp.asarray(t_base, jnp.int32),
-                                jnp.int32((1 << 29) - 1))
+            # the time scan as a VMEM-resident kernel (relative time)
             return pallas_scan(carry, words, sides, lens - t_base,
-                               ord_base + t_ord)
+                               ord_base + t_base)
 
         if afold is not None:
             # no scan at all: lift every slot of the [width, bs] tile at once,
@@ -292,8 +266,8 @@ def _lane_gather() -> str:
     ``rows`` on an accelerator, ``slices`` on a CPU host, where one slice a
     lane is a memcpy and the row fetch with its shift passes measured slower
     (1.25 times on an int32 column, 12 times on the one-byte word: PERF.md,
-    PR 28). Decided per backend like ``tile_backend`` and ``_use_dense``: no
-    config key selects it."""
+    PR 28). Decided per backend like ``tile_backend``: no config key selects
+    it."""
     return "slices" if jax.default_backend() == "cpu" else "rows"
 
 
@@ -315,16 +289,16 @@ def _round_rows(arr):
 
 
 def _make_lane_fetch(wire: WireFormat, width: int, gather: str):
-    """How a tile's lane rows get from the flat wire into ``[width, bs]``,
-    for the flat tile and the densify gather alike. Returns ``(view, fetch)``:
+    """How a tile's lane rows get from the flat wire into ``[width, bs]``.
+    Returns ``(view, fetch)``:
 
     - ``view(flat_wire u8 [N, nbytes], side_flat {n: [N]}) -> buffers``, once
       a program, outside the tile loop;
     - ``fetch(buffers, p i32 [bs]) -> (words u32 [width, bs], sides {n:
       [width, bs]})``: column ``l`` holds events ``[p[l], p[l] + width)``,
       with ``p`` clamped into the buffer as ``dynamic_slice`` clamps it
-      (finished and padding lanes, ``_NOOP_TILE_T`` work-list entries: their
-      garbage decodes under a False mask).
+      (finished and padding lanes: their garbage decodes under a False
+      mask).
 
     Both lowerings give the same tile, element for element.
 
@@ -405,7 +379,7 @@ def _make_lane_fetch(wire: WireFormat, width: int, gather: str):
 
 def _make_tile(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
                unroll: int, dispatch: str, tile_backend: str, gather: str):
-    """The flat-gather tile of the resident programs (single-device AND
+    """The tile of the resident programs (single-device AND
     mesh-sharded), as ``(view, tile)``: ``view(flat_wire u8 [N, nbytes],
     side_flat) -> buffers`` once a program, and ``tile(state_slab {f:
     [b_pad]}, buffers, starts [b_pad], lens [b_pad], ord_base [b_pad], i0,
@@ -433,64 +407,6 @@ def _make_tile(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
                 for k in slab_state}
 
     return view, tile
-
-
-def _make_tile_dense(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
-                     unroll: int, dispatch: str, tile_backend: str):
-    """The dense-layout tile: ``(state_slab, dense_words u8
-    [k_cap, width, bs, nbytes], dense_sides {n: [k_cap, width, bs]},
-    lens_all, ord_all, i0, t_base, k) -> state_slab``.
-
-    Reads tile ``k`` from buffers pre-gathered by :func:`_make_densify` —
-    the per-lane gather (measured at HALF the whole fold's on-chip time,
-    BENCH_ONCHIP.json r5) is paid once per corpus upload instead of once per
-    replay pass."""
-    nbytes = wire.nbytes
-    fold_body = _make_fold_body(spec, wire, width, bs, unroll, dispatch,
-                                tile_backend)
-
-    def tile(slab_state, dense_words, dense_sides, lens_all, ord_all,
-             i0, t_base, k):
-        lens = jax.lax.dynamic_slice(lens_all, (i0,), (bs,))
-        ord_base = jax.lax.dynamic_slice(ord_all, (i0,), (bs,))
-        carry = {f: jax.lax.dynamic_slice(v, (i0,), (bs,))
-                 for f, v in slab_state.items()}
-        wslab = jax.lax.dynamic_index_in_dim(dense_words, k, 0,
-                                             keepdims=False)
-        words = wire.expand_flat(
-            wslab.reshape(width * bs, nbytes)).reshape(width, bs)
-        sides = {n: jax.lax.dynamic_index_in_dim(arr, k, 0, keepdims=False)
-                 for n, arr in dense_sides.items()}
-        out = fold_body(carry, words, sides, lens, ord_base, t_base)
-        return {f: jax.lax.dynamic_update_slice(slab_state[f], out[f], (i0,))
-                for f in slab_state}
-
-    return tile
-
-
-def _make_densify(wire: WireFormat, width: int, bs: int, gather: str):
-    """One-time device-side tile gather: ``(flat_wire u8 [N, nbytes],
-    side_flat {n: [N]}, starts_all, i0s [k_cap], t_bases [k_cap]) ->
-    (dense_words u8 [k_cap, width, bs, nbytes], dense_sides
-    {n: [k_cap, width, bs]})``, every tile through
-    :func:`_make_lane_fetch` with the word narrowed back to its bytes.
-
-    Work-list entries past ``k_n`` carry ``_NOOP_TILE_T`` and gather the
-    buffer's last window — garbage every slot of which masks to padding."""
-    view, fetch = _make_lane_fetch(wire, width, gather)
-
-    def densify(flat_wire, side_flat, starts_all, i0s, t_bases):
-        buffers = view(flat_wire, side_flat)
-
-        def one(args):
-            i0, tb = args
-            starts = jax.lax.dynamic_slice(starts_all, (i0,), (bs,))
-            words, sides = fetch(buffers, starts + tb)
-            return wire.narrow_words(words), sides  # [width, bs, nbytes]
-
-        return jax.lax.map(one, (i0s, t_bases))
-
-    return densify
 
 
 def _chunked_put(arr: np.ndarray, chunk_mb: int):
@@ -736,25 +652,11 @@ class ReplayEngine:
         # the backend here would initialize it in engine-constructing
         # processes that never dispatch)
         self._tile_backend_resolved: str | None = None
-        # resident tile layout: "dense" pre-gathers every tile once per corpus
-        # (the per-lane gather is half the on-chip fold cost), "flat" gathers
-        # per pass, "auto" picks dense when the buffers fit the HBM budget
-        self._resident_layout = self.config.get_str(
-            "surge.replay.resident-layout", "auto")
-        if self._resident_layout not in ("auto", "flat", "dense"):
-            raise ValueError(
-                f"unknown surge.replay.resident-layout "
-                f"{self._resident_layout!r} (auto|flat|dense)")
-        self._dense_cap_mb = self.config.get_int(
-            "surge.replay.dense-cap-mb", 2048)
         # one (wire, jitted fold) per derived-column declaration the inputs carry —
         # in practice at most two: framework logs (ordinal seq) and object-test logs
         self._wire_folds: dict[frozenset, tuple[WireFormat, Any]] = {}
         # resident-corpus gather-folds, same keying
         self._resident_folds: dict[frozenset, Any] = {}
-        # dense-layout programs: jitted densify gathers and dense folds
-        self._densify_programs: dict = {}
-        self._resident_dense_folds: dict = {}
         # on-device fresh init-slab builders per b_pad (zero host transfers)
         self._slab_programs: dict = {}
         # the state-pull finalize programs, one per set of full-width columns,
@@ -772,10 +674,10 @@ class ReplayEngine:
         # host-side phase accounting (bench breakdown), fed by the profiler's
         # stages: seconds of the encode stages (a window's pack, the whole of
         # pack_resident), of the h2d stages (a window's transfer, the whole of
-        # upload_resident) and of the densify dispatches, windows dispatched,
-        # and the lane-row fetches the resident tiles asked for (_rows_fetched)
+        # upload_resident), windows dispatched, and the lane-row fetches the
+        # resident tiles asked for (_rows_fetched)
         self.stats = {"pack_s": 0.0, "h2d_s": 0.0, "windows": 0,
-                      "densify_s": 0.0, "rows_fetched": 0}
+                      "rows_fetched": 0}
         if mesh is not None:
             pspec = jax.sharding.PartitionSpec(mesh_axis)
             self._sharding = jax.sharding.NamedSharding(mesh, pspec)
@@ -1081,7 +983,7 @@ class ReplayEngine:
                 carry = fold(carry, *window)
         return carry, scanned
 
-    # -- resident-corpus path (single upload, on-device densify) ------------------------
+    # -- resident-corpus path (single upload, on-device gather) -------------------------
 
     def pack_resident(self, colev: ColumnarEvents) -> "ResidentWire":
         """Host-side half of :meth:`prepare_resident`: length-sort, flat-pack
@@ -1626,12 +1528,9 @@ class ReplayEngine:
             init_sorted, ord_sorted = _apply_perm(resident.perm, init_carry,
                                                   ordinal_base)
             plan = self._plan_for(resident)
-            use_dense = self._use_dense(resident, plan)
             if umbrella is not None:
                 umbrella.set_attribute("padded_slots", plan.padded_slots)
                 umbrella.set_attribute("tiles", plan.tiles)
-                umbrella.set_attribute("layout",
-                                       "dense" if use_dense else "flat")
                 umbrella.set_attribute("rounds", plan.rounds)
                 umbrella.set_attribute("tiles_small", len(plan.small_i0))
                 umbrella.set_attribute("slots_small", plan.slots_small)
@@ -1650,8 +1549,7 @@ class ReplayEngine:
                         slab_np[k][:b] = np.asarray(full)
                 slab = {k: jnp.asarray(v) for k, v in slab_np.items()}
                 ord_d = jnp.asarray(ord_p)
-            # one work list per lane granularity; the dense layout keeps its
-            # own beside the tiles (_dense_tiles)
+            # one work list per lane granularity
             work = []
             for bs, i0s, t_bases in ((plan.bs_big, plan.big_i0, plan.big_tb),
                                      (plan.bs_small, plan.small_i0,
@@ -1660,46 +1558,33 @@ class ReplayEngine:
                 if k_n == 0:
                     continue
                 k_cap = self._plan_cap(k_n)
-                lists = None
-                if not use_dense:
-                    i0s_p = np.zeros((k_cap,), dtype=np.int32)
-                    i0s_p[:k_n] = i0s
-                    tb_p = np.zeros((k_cap,), dtype=np.int32)
-                    tb_p[:k_n] = t_bases
-                    lists = (jnp.asarray(i0s_p), jnp.asarray(tb_p))
-                work.append((bs, i0s, t_bases, k_cap, lists))
+                i0s_p = np.zeros((k_cap,), dtype=np.int32)
+                i0s_p[:k_n] = i0s
+                tb_p = np.zeros((k_cap,), dtype=np.int32)
+                tb_p[:k_n] = t_bases
+                work.append((bs, k_n, k_cap,
+                             jnp.asarray(i0s_p), jnp.asarray(tb_p)))
 
         # two chained dispatches (big tiles, then small); per-lane order holds
         # because a lane only ever migrates big→small as the prefix shrinks
         rows_before = self.stats["rows_fetched"]
-        for bs, i0s, t_bases, k_cap, lists in work:
-            k_n = len(i0s)
+        for bs, k_n, k_cap, i0s_d, tbs_d in work:
             self.stats["windows"] += k_n
             self.profiler.count_windows(k_n)
-            if use_dense:
-                dw, ds, i0s_d, tbs_d = self._dense_tiles(
-                    resident, plan, bs, i0s, t_bases, k_cap)
-                fold = self._resident_program_dense(key, plan.width, bs,
-                                                    k_cap)
-                sig = ("resident-dense", key, plan.width, bs, k_cap, b_pad)
-                args = (dw, ds, resident.lens_dev, ord_d, i0s_d, tbs_d)
-            else:
-                fold = self._resident_program(key, plan.width, bs, k_cap)
-                self.stats["rows_fetched"] += self._rows_fetched(
-                    resident, plan.width, k_n * bs)
-                sig = ("resident", key, plan.width, bs, k_cap, b_pad,
-                       int(resident.flat_wire.shape[0]))
-                args = (resident.flat_wire, resident.flat_side,
-                        resident.starts_dev, resident.lens_dev, ord_d,
-                        *lists, np.int32(k_n))
+            fold = self._resident_program(key, plan.width, bs, k_cap)
+            self.stats["rows_fetched"] += self._rows_fetched(
+                resident, plan.width, k_n * bs)
+            sig = self._resident_signature(resident, key, plan.width, bs,
+                                           k_cap)
             # a fresh signature means this dispatch pays the XLA compile
             first_dispatch = sig not in self._signatures
             self._signatures.add(sig)
             with stage("compile" if first_dispatch else "dispatch",
                        tiles=k_n, batch=bs):
-                slab = fold(slab, *args)
+                slab = fold(slab, resident.flat_wire, resident.flat_side,
+                            resident.starts_dev, resident.lens_dev, ord_d,
+                            i0s_d, tbs_d, np.int32(k_n))
         if umbrella is not None:
-            # a dense corpus fetches in its first fold only (replay.densify)
             umbrella.set_attribute("gather", self.lane_gather)
             umbrella.set_attribute(
                 "rows_fetched", self.stats["rows_fetched"] - rows_before)
@@ -1766,115 +1651,6 @@ class ReplayEngine:
             prog = jax.jit(mk)
             self._slab_programs[b_pad] = prog
         return prog()
-
-    def _use_dense(self, resident: "ResidentCorpus", plan: "ResidentPlan"
-                   ) -> bool:
-        if self._resident_layout == "flat":
-            return False
-        if resident.cache.get("oneshot"):
-            # a corpus folded once pays the densify gather without ever
-            # amortizing it — always gather per-pass
-            return False
-        if self._resident_layout == "dense":
-            return True
-        if jax.default_backend() == "cpu":
-            # dense trades memory (pad_ratio × corpus, k_cap-padded) for the
-            # accelerator's slow per-lane gather; the host gathers fine and
-            # the extra RSS breaks bounded-memory restores
-            return False
-        if plan.padded_slots < 16_000_000:
-            # the densify dispatch+compile carries ~1 s of fixed cost — below
-            # this scale the per-pass gather it saves never adds up to that
-            return False
-        return self._dense_bytes(resident, plan) <= self._dense_cap_mb * 1024 * 1024
-
-    def _dense_bytes(self, resident: "ResidentCorpus", plan: "ResidentPlan"
-                     ) -> int:
-        """HBM the dense tile buffers would occupy (k_cap-padded)."""
-        nbytes = int(resident.flat_wire.shape[1])
-        per_slot = nbytes + sum(np.dtype(arr.dtype).itemsize
-                                for arr in resident.flat_side.values())
-        total = 0
-        for bs, i0s in ((plan.bs_big, plan.big_i0),
-                        (plan.bs_small, plan.small_i0)):
-            if len(i0s):
-                total += self._plan_cap(len(i0s)) * bs * plan.width * per_slot
-        return total
-
-    def _dense_tiles(self, resident: "ResidentCorpus", plan: "ResidentPlan",
-                     bs: int, i0s: np.ndarray, t_bases: np.ndarray,
-                     k_cap: int):
-        """Build-or-fetch the dense tile buffers for one work list (cached on
-        the corpus; the gather runs once per corpus, not once per pass)."""
-        key = frozenset(resident.derived_key.items())
-        ckey = ("dense", plan.width, bs, k_cap,
-                np.asarray(i0s, np.int32).tobytes(),
-                np.asarray(t_bases, np.int32).tobytes())
-        hit = resident.cache.get(ckey)
-        rows = (0 if hit is not None else
-                self._rows_fetched(resident, plan.width, k_cap * bs))
-        with self.profiler.stage("densify", cached=hit is not None,
-                                 tiles=len(i0s), batch=bs,
-                                 gather=self.lane_gather,
-                                 rows_fetched=rows) as densify:
-            if hit is not None:
-                return hit
-            dkey = (key, plan.width, bs)
-            dens = self._densify_programs.get(dkey)
-            if dens is None:
-                wire = WireFormat(self.spec.registry,
-                                  dict(resident.derived_key))
-                dens = jax.jit(_make_densify(wire, plan.width, bs,
-                                             self.lane_gather))
-                self._densify_programs[dkey] = dens
-            i0s_p = np.zeros((k_cap,), dtype=np.int32)
-            i0s_p[: len(i0s)] = i0s
-            # entries past k_n are provable no-ops (t_base beyond every
-            # lane's length ⇒ every slot masks to padding ⇒ identity), so the
-            # dense fold can run a STATIC k_cap trip count and one compiled
-            # program still serves every plan in the bucket
-            tb_p = np.full((k_cap,), _NOOP_TILE_T, dtype=np.int32)
-            tb_p[: len(t_bases)] = t_bases
-            i0s_d = jnp.asarray(i0s_p)
-            tbs_d = jnp.asarray(tb_p)
-            dw, ds = dens(resident.flat_wire, resident.flat_side,
-                          resident.starts_dev, i0s_d, tbs_d)
-            entry = (dw, ds, i0s_d, tbs_d)
-            resident.cache[ckey] = entry
-        self.stats["densify_s"] += densify.seconds
-        self.stats["rows_fetched"] += rows
-        return entry
-
-    def _resident_program_dense(self, key: frozenset, width: int, bs: int,
-                                k_cap: int):
-        """Dense-layout twin of :meth:`_resident_program`: the fori_loop reads
-        pre-gathered ``[k_cap, width, bs, nbytes]`` tiles by index instead of
-        gathering per-lane rows from the flat corpus each pass. The trip count
-        is STATIC at ``k_cap`` (measured ~40 ms cheaper per pass on the v5e
-        than a traced one) without per-``k_n`` recompiles: work-list entries
-        past the plan's real tile count carry the ``_NOOP_TILE_T`` sentinel,
-        whose slots all mask to padding — identity under every backend."""
-        cache_key = (key, width, bs, k_cap)
-        hit = self._resident_dense_folds.get(cache_key)
-        if hit is not None:
-            return hit
-
-        wire = WireFormat(self.spec.registry, dict(key))
-        tile = _make_tile_dense(self.spec, wire, width, bs, self._unroll,
-                                self._dispatch, self.tile_backend)
-
-        def fold(slab_state, dense_words, dense_sides, lens_all, ord_all,
-                 i0s, t_bases):
-            def body(k, st):
-                return tile(st, dense_words, dense_sides, lens_all, ord_all,
-                            i0s[k], t_bases[k], k)
-
-            return jax.lax.fori_loop(0, k_cap, body, slab_state)
-
-        donate = (0,) if self.donate_carry else ()
-        jitted = jax.jit(fold, donate_argnums=donate)
-        self._resident_dense_folds[cache_key] = jitted
-        return jitted
 
     def replay_resident_streamed(self, w: "ResidentWire", *,
                                  segments: int | None = None,
@@ -1990,9 +1766,6 @@ class ReplayEngine:
                     lengths=sub_lens, perm=None, guard=w.guard,
                     num_events=end - base, layout=w.layout)
                 piece = self.upload_resident(sub)  # upload initiates...
-                # folded exactly once: the dense layout's one-time gather would
-                # never amortize (measured 2.5× slower streaming in the r5 sweep)
-                piece.cache["oneshot"] = True
                 slab, pad = self._dispatch_resident(
                     piece,
                     None if init_sorted is None else
@@ -2042,43 +1815,34 @@ class ReplayEngine:
 
     def warm_resident(self, resident: "ResidentCorpus") -> None:
         """Compile every program a :meth:`replay_resident` of this corpus will
-        dispatch, against the real corpus buffers, with zero-trip work lists —
-        and, under the dense layout, run the one-time tile gather — so a
-        timed pass runs with zero in-window compiles and zero data prep."""
+        dispatch, against the real corpus buffers, with zero-trip work lists,
+        so a timed pass runs with zero in-window compiles."""
         b = resident.lengths.shape[0]
         if b == 0:
             return
         plan = self._plan_for(resident)
         key = frozenset(resident.derived_key.items())
-        b_pad = resident.b_pad
-        use_dense = self._use_dense(resident, plan)
-        for bs, i0s, t_bases in ((plan.bs_big, plan.big_i0, plan.big_tb),
-                                 (plan.bs_small, plan.small_i0, plan.small_tb)):
+        for bs, i0s in ((plan.bs_big, plan.big_i0),
+                        (plan.bs_small, plan.small_i0)):
             if len(i0s) == 0:
                 continue
             k_cap = self._plan_cap(len(i0s))
-            slab, ord_d = self._fresh_slab(b_pad)
-            if use_dense:
-                dw, ds, i0s_d, tbs_d = self._dense_tiles(resident, plan, bs,
-                                                         i0s, t_bases, k_cap)
-                fold = self._resident_program_dense(key, plan.width, bs,
-                                                    k_cap)
-                # the dense trip count is static, so the warm pass runs the
-                # REAL fold (into a discarded fresh slab) — that's also what
-                # materializes the dense tile cache
-                out = fold(slab, dw, ds, resident.lens_dev, ord_d,
-                           i0s_d, tbs_d)
-                jax.block_until_ready(out)
-                self._signatures.add(("resident-dense", key, plan.width, bs,
-                                      k_cap, b_pad))
-                continue
+            slab, ord_d = self._fresh_slab(resident.b_pad)
             fold = self._resident_program(key, plan.width, bs, k_cap)
             wl = jnp.zeros((k_cap,), dtype=jnp.int32)
             out = fold(slab, resident.flat_wire, resident.flat_side,
                        resident.starts_dev, resident.lens_dev, ord_d,
                        wl, wl, np.int32(0))
             jax.block_until_ready(out)
-            self._signatures.add(("resident", key, plan.width, bs, k_cap, b_pad, int(resident.flat_wire.shape[0])))
+            self._signatures.add(self._resident_signature(
+                resident, key, plan.width, bs, k_cap))
+
+    @staticmethod
+    def _resident_signature(resident: "ResidentCorpus", key: frozenset,
+                            width: int, bs: int, k_cap: int) -> tuple:
+        """The static shapes one resident fold program compiles for."""
+        return ("resident", key, width, bs, k_cap, resident.b_pad,
+                int(resident.flat_wire.shape[0]))
 
     def _resident_program(self, key: frozenset, width: int, bs: int,
                           k_cap: int):

@@ -16,8 +16,7 @@ replay pass into stages, each a span that is open while the work runs:
   ``block_until_ready``);
 - ``resident`` — the umbrella of one resident fold (``replay_resident`` /
   ``fold_resident_slab``): ``plan`` (lane order, tile plan, work lists),
-  ``densify`` (the dense tile gather's dispatch, ``cached`` when the corpus
-  already holds the tiles), then ``compile``/``dispatch`` and ``fetch``;
+  then ``compile``/``dispatch`` and ``fetch``;
 - ``compile`` — fold dispatches that triggered a fresh XLA compilation
   (detected from the engine's static-shape signature set, never a private
   JAX API);

@@ -630,7 +630,6 @@ class ResidentStatePlane(Controllable):
 
         wire = self.engine.pack_resident(colev)
         corpus = self.engine.upload_resident(wire)
-        corpus.cache["oneshot"] = True  # folded exactly once
         slab_sorted, _ = self.engine.fold_resident_slab(corpus)
         # sorted position of original aggregate i: inv_perm[i]
         b = len(ids)
@@ -745,7 +744,6 @@ class ResidentStatePlane(Controllable):
             return states
         wire = self.engine.pack_resident(colev)
         corpus = self.engine.upload_resident(wire)
-        corpus.cache["oneshot"] = True  # folded exactly once
         slab_sorted, _ = self.engine.fold_resident_slab(corpus)
         if corpus.perm is None:
             inv = np.arange(b, dtype=np.int32)

@@ -516,9 +516,6 @@ def restore_from_segment(
                                 build_id=extra.get("build_id")) if wire_cache
                     else engine.pack_resident(chunk))
             resident = engine.upload_resident(wire)
-            # each restore chunk folds exactly once — the dense layout's
-            # one-time gather would never amortize
-            resident.cache["oneshot"] = True
             res = engine.replay_resident(resident, init_carry=init)
         else:
             res = engine.replay_columnar(chunk, init_carry=init)

@@ -1,8 +1,9 @@
 """The shopping cart's cold rebuild (``pack_resident`` -> ``upload_resident`` ->
 ``replay_resident``) against the benchmark's plain reference
 (``benchmarks/reference_cart.py``): ragged logs, three side columns, a
-four-field state with a bool, both layouts, both tile granularities over
-several rounds, and the state pull that remembers which columns went wide."""
+four-field state with a bool, a corpus folded once and again, both tile
+granularities over several rounds, and the state pull that remembers which
+columns went wide."""
 
 import time
 
@@ -24,10 +25,9 @@ LAW = {"length_law": "lognormal", "length_sigma": 0.6,
 SMALL = dict(LAW, price_cents=[1, 3], length_sigma=0.2)
 
 
-def make_engine(layout="auto", batch=256, chunk=64):
+def make_engine(batch=256, chunk=64):
     cfg = default_config().with_overrides({
-        "surge.replay.batch-size": batch, "surge.replay.time-chunk": chunk,
-        "surge.replay.resident-layout": layout})
+        "surge.replay.batch-size": batch, "surge.replay.time-chunk": chunk})
     return ReplayEngine(shopping_cart.make_replay_spec(), config=cfg)
 
 
@@ -42,13 +42,15 @@ def make_corpus(carts, events, seed, law=LAW):
     return corpus, columns
 
 
-def rebuild(engine, corpus, columns):
+def rebuild(engine, corpus, columns, folds=1):
     """One whole rebuild, all four columns held to the whole-column reference
-    and a sample (the longest log in it) to the scalar fold. Returns the
-    spans it left in the ring."""
+    and a sample (the longest log in it) to the scalar fold; ``folds`` > 1
+    folds the uploaded corpus again and holds the last fold's states. Returns
+    the spans it left in the ring."""
     since = time.monotonic()
-    res = engine.replay_resident(
-        engine.upload_resident(engine.pack_resident(columns)))
+    resident = engine.upload_resident(engine.pack_resident(columns))
+    for _ in range(folds):
+        res = engine.replay_resident(resident)
     spans = default_tracer().spans(since_mono=since)
     want = reference_cart.closed_form(corpus)
     assert res.num_events == corpus.num_events
@@ -72,20 +74,26 @@ def fetches(spans):
             for s in named(spans, "replay.fetch.wait")]
 
 
-def case_layout(layout):
+def case_fold(folds):
     def body():
-        engine = make_engine(layout)
-        spans, _ = rebuild(engine, *make_corpus(3000, 90_000, 11))
-        (fold,) = named(spans, "replay.resident")
-        assert fold.attributes["layout"] == layout
-        assert bool(named(spans, "replay.densify")) == (layout == "dense")
+        engine = make_engine()
+        spans, _ = rebuild(engine, *make_corpus(3000, 90_000, 11), folds=folds)
+        resident = named(spans, "replay.resident")
+        assert len(resident) == folds
+        # every fold of a corpus asks its buffers for the same rows, and only
+        # the first compiles; the second pulls what the first learned
+        assert len({s.attributes["rows_fetched"] for s in resident}) == 1
+        assert len(named(spans, "replay.compile")) == 2
+        assert len(named(spans, "replay.dispatch")) == 2 * (folds - 1)
+        assert [w for w, _ in fetches(spans)] == (
+            ["narrow", "mixed"] + ["mixed"] * (folds - 1))
     return body
 
 
 def case_three_rounds_two_granularities():
     # width 16 over logs of up to a few hundred events: the shrinking prefix
     # is covered by 256-lane tiles and, for its remainder, 32-lane tiles
-    engine = make_engine("flat", batch=256, chunk=16)
+    engine = make_engine(batch=256, chunk=16)
     corpus, columns = make_corpus(3000, 120_000, 2**31 + 77)
     spans, _ = rebuild(engine, corpus, columns)
     (fold,) = named(spans, "replay.resident")
@@ -102,7 +110,7 @@ def case_three_rounds_two_granularities():
 
 def case_empty_carts():
     # a mean of two events a cart: the floor leaves many carts with none
-    engine = make_engine("flat")
+    engine = make_engine()
     corpus, columns = make_corpus(2000, 4000, 5)
     assert int((corpus.lengths == 0).sum()) > 100
     _, want = rebuild(engine, corpus, columns)
@@ -114,14 +122,14 @@ def case_empty_carts():
 def case_totals_leave_int16_on_both_sides():
     # as much removed as added: the totals walk away from zero both ways
     law = dict(LAW, body_mix=[0.5, 0.5], removed_quantity=[1, 5])
-    engine = make_engine("flat")
+    engine = make_engine()
     _, want = rebuild(engine, *make_corpus(2000, 60_000, 23, law))
     assert want["total_cents"].min() < -32768 < 32767 < want["total_cents"].max()
     assert -32768 <= want["item_count"].min() < 0 < want["item_count"].max()
 
 
 def case_the_pull_remembers_wide_columns():
-    engine = make_engine("flat")
+    engine = make_engine()
     b = 2000
     wide_corpus = make_corpus(b, 60_000, 31)
     mixed = 10 * b + 8  # total_cents in four bytes, three columns in two, four flags
@@ -153,7 +161,7 @@ def case_the_pull_remembers_wide_columns():
 
 
 def case_the_ring_carries_the_counts():
-    engine = make_engine("auto", batch=256, chunk=32)
+    engine = make_engine(batch=256, chunk=32)
     corpus, columns = make_corpus(1500, 45_000, 41)
     rebuild(engine, corpus, columns)
     spans, _ = rebuild(engine, corpus, columns)  # the memory is warm
@@ -164,7 +172,7 @@ def case_the_ring_carries_the_counts():
     b, n = corpus.num_aggregates, corpus.num_events
     assert wait.attributes == {"wire": "mixed", "bytes": 10 * b + 8}
     a = fold.attributes
-    assert a["layout"] == "flat"  # auto, on the CPU backend
+    assert a["gather"] == "slices"  # the CPU backend's
     assert a["rounds"] == -(-int(corpus.lengths.max()) // 32)
     assert a["slots_small"] == a["tiles_small"] * 32 * 32
     assert a["padded_slots"] >= n and a["aggregates"] == b
@@ -176,8 +184,8 @@ def case_the_ring_carries_the_counts():
 
 
 CASES = {
-    "flat": case_layout("flat"),
-    "dense": case_layout("dense"),
+    "first": case_fold(1),
+    "again": case_fold(2),
     "three-rounds-two-granularities": case_three_rounds_two_granularities,
     "empty-carts": case_empty_carts,
     "totals-beyond-int16-both-sides": case_totals_leave_int16_on_both_sides,

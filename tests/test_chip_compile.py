@@ -25,8 +25,8 @@ NO_TOPOLOGY = 3  # the child's exit code where no v5e can be described
 
 def compile_report() -> dict:
     """``{program: {gather: [while loops, gathers, temp bytes]}}`` of the
-    counter's densify and the cart's flat fold (assoc backend), fetched both
-    ways, compiled for one chip of a described v5e:2x2."""
+    counter's and the cart's ``jit_fold`` (assoc backend), fetched both ways,
+    compiled for one chip of a described v5e:2x2."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -34,7 +34,7 @@ def compile_report() -> dict:
 
     from surge_tpu.codec.wire import WireFormat
     from surge_tpu.models import counter, shopping_cart
-    from surge_tpu.replay.engine import _make_densify, _make_tile
+    from surge_tpu.replay.engine import _make_tile
 
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without the chip: keep it out
@@ -59,33 +59,29 @@ def compile_report() -> dict:
 
     lanes = shape((LANES,), jnp.int32)
     work = shape((128,), jnp.int32)
-    report = {"densify": {}, "cart_fold": {}}
+    report = {}
+    for name, model in (("counter_fold", counter), ("cart_fold", shopping_cart)):
+        spec = model.make_replay_spec()
+        wire = WireFormat(spec.registry, {"sequence_number": "ordinal"})
+        slab = {f.name: shape((LANES,), f.dtype)
+                for f in spec.registry.state.fields}
+        side = {f.name: shape((N,), f.dtype) for f in wire.side_fields}
+        report[name] = {}
+        for gather in ("rows", "slices"):
+            view, tile = _make_tile(spec, wire, WIDTH, BS, 1, "switch",
+                                    "assoc", gather)
 
-    wire = WireFormat(counter.make_registry(), {"sequence_number": "ordinal"})
-    for gather in ("rows", "slices"):
-        densify = jax.jit(_make_densify(wire, WIDTH, BS, gather))
-        report["densify"][gather] = counts(densify.lower(
-            shape((N, wire.nbytes), jnp.uint8), {}, lanes, work,
-            work).compile())
+            def fold(slab_state, flat_wire, side_flat, starts, lens, ords,
+                     i0s, t_bases, k_n):
+                buffers = view(flat_wire, side_flat)
+                return jax.lax.fori_loop(
+                    0, k_n, lambda k, st: tile(st, buffers, starts, lens,
+                                               ords, i0s[k], t_bases[k]),
+                    slab_state)
 
-    spec = shopping_cart.make_replay_spec()
-    wire = WireFormat(spec.registry, {"sequence_number": "ordinal"})
-    slab = {f.name: shape((LANES,), f.dtype) for f in spec.registry.state.fields}
-    side = {f.name: shape((N,), f.dtype) for f in wire.side_fields}
-    for gather in ("rows", "slices"):
-        view, tile = _make_tile(spec, wire, WIDTH, BS, 1, "switch", "assoc",
-                                gather)
-
-        def fold(slab_state, flat_wire, side_flat, starts, lens, ords, i0s,
-                 t_bases, k_n):
-            buffers = view(flat_wire, side_flat)
-            return jax.lax.fori_loop(
-                0, k_n, lambda k, st: tile(st, buffers, starts, lens, ords,
-                                           i0s[k], t_bases[k]), slab_state)
-
-        report["cart_fold"][gather] = counts(jax.jit(fold).lower(
-            slab, shape((N, wire.nbytes), jnp.uint8), side, lanes, lanes,
-            lanes, work, work, shape((), jnp.int32)).compile())
+            report[name][gather] = counts(jax.jit(fold).lower(
+                slab, shape((N, wire.nbytes), jnp.uint8), side, lanes, lanes,
+                lanes, work, work, shape((), jnp.int32)).compile())
     return report
 
 
@@ -102,18 +98,20 @@ def report():
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def test_densify_fetches_rows_with_one_gather_and_no_lane_loop(report):
-    """The counter's ``jit_densify``: the one ``while`` is the walk over the
-    work list; the parent's second, 8192 trips of one slice, is a gather."""
-    rows, slices = report["densify"]["rows"], report["densify"]["slices"]
-    assert rows[:2] == [1, 1]
-    assert slices[:2] == [2, 0]
+def test_the_counters_fold_fetches_rows_with_one_gather_and_no_lane_loop(report):
+    """The counter's ``jit_fold``, one array: the one ``while`` is the walk
+    over the work list; the slices' second, 8192 trips of one slice, is one
+    gather more."""
+    rows, slices = (report["counter_fold"][g] for g in ("rows", "slices"))
+    assert (rows[0], slices[0]) == (1, 2)
+    # the tree fold's strided halvings are gathers too, in both lowerings
+    assert rows[1] - slices[1] == 1
     # the widened word is the only buffer the fetch adds: 4 B an event
     assert 0 < rows[2] - slices[2] <= 4 * N + (1 << 20)
 
 
-def test_flat_cart_tile_fetches_rows_without_a_lane_loop(report):
-    """The cart's flat ``jit_fold``, assoc backend: one ``while`` over the
+def test_the_carts_fold_fetches_rows_without_a_lane_loop(report):
+    """The cart's ``jit_fold``, assoc backend: one ``while`` over the
     tiles where the parent nests one of 8192 trips for every array read."""
     loops, gathers, _ = report["cart_fold"]["rows"]
     assert loops == 1 and gathers >= 1

@@ -1,9 +1,10 @@
 """A tile's lane rows as aligned rows (``engine._make_lane_fetch``, ``rows``):
 the chip's lowering, forced here on the CPU backend, against the one
 ``dynamic_slice`` a lane it replaced, kept below as the oracle. Element for
-element at the helper, byte for byte in the dense buffers, state for state
-through every layout and tile backend."""
+element at the helper, state for state through every tile backend, on the
+first fold of a corpus and on the next."""
 
+import time
 from dataclasses import make_dataclass
 
 import jax
@@ -15,11 +16,18 @@ from surge_tpu.codec.schema import FieldSpec, SchemaRegistry
 from surge_tpu.codec.tensor import encode_events_columnar
 from surge_tpu.codec.wire import WireFormat
 from surge_tpu.config import default_config
+from surge_tpu.engine.model import fold_events
+from surge_tpu.log import InMemoryLog, LogRecord, TopicSpec
+from surge_tpu.log.columnar import (build_segment_from_topic,
+                                    extend_segment_from_topic)
 from surge_tpu.models import counter, shopping_cart
 from surge_tpu.replay import engine as engine_module
-from surge_tpu.replay.engine import (_LANE_ROW, _NOOP_TILE_T, ReplayEngine,
-                                     _make_densify, _make_lane_fetch,
-                                     _rows_per_lane)
+from surge_tpu.replay.engine import (_LANE_ROW, ReplayEngine,
+                                     _make_lane_fetch, _rows_per_lane)
+from surge_tpu.replay.resident_state import ResidentStatePlane
+from surge_tpu.serialization import SerializedMessage
+from surge_tpu.store import InMemoryKeyValueStore, restore_from_segment
+from surge_tpu.tracing import default_tracer
 
 A = _LANE_ROW
 ROWS = 40  # the buffer: 40 aligned rows
@@ -66,29 +74,9 @@ def oracle_fetch(wire, width, flat_wire, side_flat, p):
     return word.reshape(bs, width).T, sides
 
 
-def oracle_densify(wire, width, bs):
-    """The parent's ``_make_densify``, verbatim."""
-    nbytes = wire.nbytes
-
-    def densify(flat_wire, side_flat, starts_all, i0s, t_bases):
-        def one(args):
-            i0, tb = args
-            starts = jax.lax.dynamic_slice(starts_all, (i0,), (bs,))
-            rows = jax.vmap(lambda s0: jax.lax.dynamic_slice(
-                flat_wire, (s0, 0), (width, nbytes)))(starts + tb)
-            sides = {n: jax.vmap(lambda s0: jax.lax.dynamic_slice(
-                arr, (s0,), (width,)))(starts + tb).T
-                for n, arr in side_flat.items()}
-            return jnp.transpose(rows, (1, 0, 2)), sides
-
-        return jax.lax.map(one, (i0s, t_bases))
-
-    return densify
-
-
 def lane_starts(width):
     """Named vectors of window starts ``start + t_base``, one a lane."""
-    noop = int(_NOOP_TILE_T)
+    far = 1 << 29  # past any buffer, short of int32 overflow
     return {
         "offset_0": [0, A, 7 * A, (ROWS - 8) * A],
         "offset_1": [1, A + 1, 7 * A + 1, 33],
@@ -98,7 +86,7 @@ def lane_starts(width):
         "ends_in_guard_rows": [N - width - 5, N - 2 * width, N - width - A + 1,
                                N - width - 63],
         "padding_lane": [0, 0, width, 3 * width],  # start 0, any t_base
-        "noop_worklist_entry": [noop, noop + 100, noop + N - 1, noop + 4 * A],
+        "far_past_the_end": [far, far + 100, far + N - 1, far + 4 * A],
         "past_the_end": [N - width + 1, N - 1, N, N + 5 * A],
         "every_offset": list(range(3 * A, 4 * A + 1)),
     }
@@ -132,31 +120,6 @@ def test_rows_per_lane_cover_any_offset():
         assert (r - 1) * A < (A - 1) + width <= r * A  # no row too many
         assert _rows_per_lane(width, "slices") == 1
     assert _rows_per_lane(512, "rows") == 5
-
-
-@pytest.mark.parametrize("word_bits", [5, 20], ids=["word1", "word3"])
-def test_densify_builds_the_oracles_buffers(word_bits):
-    """Work lists with ``_NOOP_TILE_T`` padding entries and a padding lane."""
-    wire = make_wire(word_bits)
-    width, bs = 64, 8
-    flat_wire, side_flat = make_buffers(wire, N)
-    starts = np.zeros(32, dtype=np.int32)
-    starts[:27] = np.sort(np.random.default_rng(1).integers(
-        0, N - 4 * width, size=27))
-    i0s = jnp.asarray([0, 8, 16, 24, 0, 8, 0, 0], dtype=jnp.int32)
-    t_bases = np.full(8, _NOOP_TILE_T, dtype=np.int32)
-    t_bases[:6] = [0, 0, 0, 0, width, width]
-    args = (flat_wire, side_flat, jnp.asarray(starts), i0s,
-            jnp.asarray(t_bases))
-    want_w, want_s = jax.jit(oracle_densify(wire, width, bs))(*args)
-    got_w, got_s = jax.jit(_make_densify(wire, width, bs, "rows"))(*args)
-    assert got_w.dtype == jnp.uint8
-    assert got_w.shape == (8, width, bs, wire.nbytes)
-    np.testing.assert_array_equal(np.asarray(got_w), np.asarray(want_w))
-    for name, want in want_s.items():
-        assert got_s[name].dtype == want.dtype
-        np.testing.assert_array_equal(np.asarray(got_s[name]),
-                                      np.asarray(want), name)
 
 
 # -- through the engine -------------------------------------------------------
@@ -200,73 +163,202 @@ def cart_logs(n_agg=260, seed=9):
     return logs
 
 
-MODELS = {"counter": (counter, counter_logs), "cart": (shopping_cart, cart_logs)}
+#: name -> (the model's module, its logs, its scalar model)
+MODELS = {"counter": (counter, counter_logs, counter.CounterModel),
+          "cart": (shopping_cart, cart_logs, shopping_cart.CartModel)}
 
 
-def make_engine(model, layout, tile, **overrides):
+def make_engine(model, tile, **overrides):
     cfg = default_config().with_overrides({
         "surge.replay.batch-size": 64, "surge.replay.time-chunk": 32,
-        "surge.replay.resident-layout": layout,
         "surge.replay.tile-backend": tile, **overrides})
     return ReplayEngine(model.make_replay_spec(), config=cfg)
 
 
+def resident_spans(since):
+    return [s for s in default_tracer().spans(since_mono=since)
+            if s.name == "replay.resident"]
+
+
 @pytest.mark.parametrize("tile", ["xla", "assoc"])
-@pytest.mark.parametrize("layout", ["flat", "dense"])
+@pytest.mark.parametrize("fold", ["first", "again"])
 @pytest.mark.parametrize("name", sorted(MODELS))
-def test_rebuild_on_rows_matches_replay_ragged(rows_on_cpu, name, layout, tile):
-    model, make_logs = MODELS[name]
+def test_rebuild_on_rows_matches_replay_ragged(rows_on_cpu, name, fold, tile):
+    """``again``: the same uploaded corpus folded a second time, the first
+    fold's slab donated and gone: nothing of a fold outlives it but the plan,
+    and the second asks the buffers for the same rows."""
+    model, make_logs, _ = MODELS[name]
     logs = make_logs()
-    engine = make_engine(model, layout, tile)
-    assert engine.lane_gather == "rows"
+    engine = make_engine(model, tile)
+    assert engine.lane_gather == "rows" and engine.donate_carry
     want = engine.replay_ragged(logs)
     wire = engine.pack_resident(
         encode_events_columnar(model.make_registry(), logs))
     assert (wire.perm is not None) == (name == "cart")
     resident = engine.upload_resident(wire)
+    since = time.monotonic()
     got = engine.replay_resident(resident)
+    if fold == "again":
+        got = engine.replay_resident(resident)
+        first, second = resident_spans(since)
+        assert second.attributes["rows_fetched"] == (
+            first.attributes["rows_fetched"]) > 0
+        assert engine.stats["rows_fetched"] == (
+            2 * first.attributes["rows_fetched"])
+        assert [k if isinstance(k, str) else k[0]
+                for k in resident.cache] == ["plan", "invperm"]
     assert got.num_events == sum(len(log) for log in logs)
     for field, col in want.states.items():
         assert got.states[field].dtype == col.dtype, field
         np.testing.assert_array_equal(got.states[field], col, field)
-    # a second fold of the same corpus (dense: from the cached tiles)
-    again = engine.replay_resident(resident)
-    for field, col in want.states.items():
-        np.testing.assert_array_equal(again.states[field], col, field)
 
 
+# -- the callers that fold an uploaded corpus once, as the chip runs them ------
+
+def assert_columns_are(states, want):
+    """Every state column ``{field: [B]}`` against scalar states (None: no
+    event)."""
+    for field, col in states.items():
+        got = np.asarray(col)
+        exp = np.asarray([getattr(st, field) if st is not None else 0
+                          for st in want]).astype(got.dtype)
+        np.testing.assert_array_equal(got, exp, field)
+
+
+@pytest.mark.parametrize("tile", ["xla", "assoc"])
+@pytest.mark.parametrize("start", ["fresh", "resumed"])
 @pytest.mark.parametrize("name", sorted(MODELS))
-def test_dense_buffers_on_rows_are_the_slice_buffers(monkeypatch, name):
-    """``_dense_tiles``' ``dw`` and ``ds`` of one corpus, fetched both ways."""
-    model, make_logs = MODELS[name]
-    events = encode_events_columnar(model.make_registry(), make_logs())
-    built = {}
-    for gather in ("slices", "rows"):
-        monkeypatch.setattr(engine_module, "_lane_gather", lambda g=gather: g)
-        engine = make_engine(model, "dense", "xla")
-        resident = engine.upload_resident(engine.pack_resident(events))
-        engine.replay_resident(resident)
-        built[gather] = {k: v for k, v in resident.cache.items()
-                         if k[0] == "dense"}
-        assert built[gather]
-    assert sorted(built["rows"]) == sorted(built["slices"])
-    for key, (dw, ds, _, _) in built["rows"].items():
-        want_dw, want_ds, _, _ = built["slices"][key]
-        assert dw.dtype == jnp.uint8 and dw.shape == want_dw.shape
-        np.testing.assert_array_equal(np.asarray(dw), np.asarray(want_dw))
-        assert sorted(ds) == sorted(want_ds)
-        for col in ds:
-            assert ds[col].dtype == want_ds[col].dtype
-            np.testing.assert_array_equal(np.asarray(ds[col]),
-                                          np.asarray(want_ds[col]), col)
+def test_streamed_fold_on_rows_matches_the_scalar_fold(rows_on_cpu, name, start,
+                                                       tile):
+    """The counter's equal logs tile the buffer in lane order (pieces are lane
+    ranges); the cart's perm over grouped input makes an indirect wire (pieces
+    re-sorted by length). ``resumed``: every log's later half streamed onto
+    the states and ordinals its first half left, each piece taking its own
+    lanes' share."""
+    module, make_logs, model = MODELS[name]
+    logs = make_logs()
+    engine = make_engine(module, tile)
+    resume, tails = {}, logs
+    if start == "resumed":
+        heads = [log[:len(log) // 2] for log in logs]
+        tails = [log[len(log) // 2:] for log in logs]
+        resume = {
+            "init_carry": engine.replay_resident(engine.prepare_resident(
+                encode_events_columnar(module.make_registry(), heads))).states,
+            "ordinal_base": np.asarray([len(h) for h in heads], np.int32)}
+    wire = engine.pack_resident(
+        encode_events_columnar(module.make_registry(), tails))
+    rows_before = engine.stats["rows_fetched"]
+    since = time.monotonic()
+    got = engine.replay_resident_streamed(wire, segments=3, **resume)
+    (umbrella,) = resident_spans(since)
+    assert umbrella.attributes["segments"] == 3
+    assert umbrella.attributes["gather"] == "rows"
+    assert umbrella.attributes["rows_fetched"] == (
+        engine.stats["rows_fetched"] - rows_before) > 0
+    assert got.num_events == sum(len(log) for log in tails)
+    assert len(got.states) == len(module.make_registry().state.field_names)
+    assert_columns_are(got.states,
+                       [fold_events(model(), None, log) for log in logs])
 
 
-@pytest.mark.parametrize("layout", ["flat", "dense"])
-def test_exact_bucket_rounds_the_device_buffers_up(rows_on_cpu, layout):
+def counter_topic():
+    """A two-partition events topic holding 40 ragged counter logs, as
+    ``(log, {aggregate: events}, send)``; ``send(agg, n)`` appends ``n``."""
+    log = InMemoryLog()
+    log.create_topic(TopicSpec("counter-events", 2))
+    fmt = counter.event_formatting()
+    logs = {}
+
+    def send(agg, n):
+        prod = log.transactional_producer("lane-rows")
+        prod.begin()
+        for _ in range(n):
+            ev = counter.CountIncremented(agg, 1 + len(agg) % 3,
+                                          len(logs.setdefault(agg, [])) + 1)
+            logs[agg].append(ev)
+            prod.send(LogRecord(topic="counter-events", key=agg,
+                                value=fmt.write_event(ev).value,
+                                partition=int(agg.rsplit("-", 1)[1]) % 2))
+        prod.commit()
+
+    for i in range(40):
+        send(f"agg-{i}", 1 + (7 * i) % 45)
+    return log, logs, send
+
+
+@pytest.mark.parametrize("segment", ["base", "extended"])
+def test_segment_restore_on_rows_matches_the_scalar_fold(rows_on_cpu, tmp_path,
+                                                         segment):
+    """``restore_from_segment`` folds every chunk through the resident path,
+    once; an extended segment's delta chunks resume from ``init_carry``."""
+    log, logs, send = counter_topic()
+    fmt, sfmt = counter.event_formatting(), counter.state_formatting()
+    path = str(tmp_path / "seg.scol")
+    build_segment_from_topic(
+        log, "counter-events", counter.make_registry(), fmt.read_event, path,
+        derived_cols={"sequence_number": "ordinal"}, chunk_aggregates=16)
+    if segment == "extended":  # longer logs and new ones, after the build
+        for i in range(0, 46, 3):
+            send(f"agg-{i}", 5)
+    info = extend_segment_from_topic(
+        log, "counter-events", counter.make_registry(), fmt.read_event, path)
+    assert (info.get("num_extends", 0) > 0) == (segment == "extended")
+    store = InMemoryKeyValueStore()
+    since = time.monotonic()
+    res = restore_from_segment(
+        path, store, replay_spec=counter.make_replay_spec(),
+        serialize_state=lambda a, s: sfmt.write_state(s).value,
+        config=default_config().with_overrides({
+            "surge.replay.batch-size": 64, "surge.replay.time-chunk": 32,
+            "surge.replay.segment-wire-cache": False}))
+    folds = resident_spans(since)
+    assert len(folds) == info["num_chunks"] >= 3
+    assert {s.attributes["gather"] for s in folds} == {"rows"}
+    assert res.num_events == sum(len(evs) for evs in logs.values())
+    model = counter.CounterModel()
+    for agg, events in logs.items():
+        truth = fold_events(model, None, events)
+        got = sfmt.read_state(store.get(agg))
+        assert (got.count, got.version) == (truth.count, truth.version), agg
+
+
+@pytest.mark.parametrize("caller", ["seed_from_log", "shadow_replay_rows"])
+def test_the_planes_cold_folds_on_rows_match_the_scalar_fold(rows_on_cpu,
+                                                             caller):
+    """The resident plane's seed and the auditor's shadow replay: an uploaded
+    corpus folded once through ``fold_resident_slab``, rows left on device."""
+    log, logs, _ = counter_topic()
+    fmt, sfmt = counter.event_formatting(), counter.state_formatting()
+    plane = ResidentStatePlane(
+        log, "counter-events", counter.make_replay_spec(),
+        config=default_config().with_overrides({
+            "surge.replay.resident.capacity": 64,
+            "surge.replay.batch-size": 16, "surge.replay.time-chunk": 8}),
+        deserialize_event=lambda raw: fmt.read_event(
+            SerializedMessage(key="", value=raw)),
+        serialize_state=lambda a, s: sfmt.write_state(s).value)
+    model = counter.CounterModel()
+    want = {agg: fold_events(model, None, evs) for agg, evs in logs.items()}
+    since = time.monotonic()
+    plane.seed_from_log()
+    if caller == "seed_from_log":
+        assert plane.snapshot_states() == want
+    else:  # the auditor's, over a seeded plane
+        since = time.monotonic()
+        ids = sorted(logs)
+        rows = plane.shadow_replay_rows([logs[a] for a in ids])
+        assert_columns_are(rows, [want[a] for a in ids])
+    (fold,) = resident_spans(since)
+    assert fold.attributes["gather"] == "rows"
+    assert fold.attributes["rows_fetched"] > 0
+
+
+def test_exact_bucket_rounds_the_device_buffers_up(rows_on_cpu):
     """``resident-len-bucket = exact`` with a length ``A`` does not divide:
     the host puts the wire as it is and the device pads it to whole rows."""
     logs = cart_logs(n_agg=90, seed=2)
-    engine = make_engine(shopping_cart, layout, "assoc", **{
+    engine = make_engine(shopping_cart, "assoc", **{
         "surge.replay.resident-len-bucket": "exact"})
     wire = engine.pack_resident(
         encode_events_columnar(shopping_cart.make_registry(), logs))
@@ -288,7 +380,7 @@ def test_exact_bucket_rounds_the_device_buffers_up(rows_on_cpu, layout):
 def test_a_cpu_host_keeps_the_slices():
     """No accelerator here: the backend's own choice is the old slice, and the
     buffers stay as the host put them."""
-    engine = make_engine(counter, "flat", "xla", **{
+    engine = make_engine(counter, "xla", **{
         "surge.replay.resident-len-bucket": "exact"})
     assert engine.lane_gather == "slices"
     wire = engine.pack_resident(

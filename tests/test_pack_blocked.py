@@ -326,11 +326,11 @@ def test_one_descent_anywhere_is_ungrouped(at):
     assert grouped_lengths(agg, 10) is None
 
 
-@pytest.mark.parametrize("layout", ["flat", "dense"])
+@pytest.mark.parametrize("tile", ["xla", "assoc"])
 @pytest.mark.parametrize("ungrouped", [False, True])
-def test_a_wire_of_many_blocks_folds_to_the_right_states(layout, ungrouped):
+def test_a_wire_of_many_blocks_folds_to_the_right_states(tile, ungrouped):
     engine = make_engine("counter-1B",
-                         **{"surge.replay.resident-layout": layout})
+                         **{"surge.replay.tile-backend": tile})
     events = make_events("counter-1B", "gaps", seed=19, ungrouped=ungrouped)
     res = engine.replay_resident(
         engine.upload_resident(engine.pack_resident(events)))

@@ -230,7 +230,7 @@ def test_length_sorted_chunking_cuts_padding_and_stays_exact():
 
 
 def test_resident_corpus_replay_matches_streaming_and_scalar():
-    """Resident-corpus replay (one flat upload + on-device gather densify) must
+    """Resident-corpus replay (one flat upload + on-device gather) must
     produce byte-identical states to the streaming window path and the scalar
     fold, in the caller's original aggregate order, while shipping exactly
     wire_bytes_per_event() per event."""

@@ -43,10 +43,9 @@ def make_events(n_agg=48, n_per=20):
         derived_cols={"sequence_number": "ordinal"})
 
 
-def make_engine(layout="auto", **kw):
+def make_engine(**kw):
     cfg = default_config().with_overrides({
-        "surge.replay.batch-size": 64, "surge.replay.time-chunk": 16,
-        "surge.replay.resident-layout": layout})
+        "surge.replay.batch-size": 64, "surge.replay.time-chunk": 16})
     return ReplayEngine(make_replay_spec(), config=cfg, **kw)
 
 
@@ -79,36 +78,45 @@ def assert_children(spans, parent, names):
         assert any(s.name == name for s in spans), name
 
 
-@pytest.mark.parametrize("layout", ["auto", "dense"])
-def test_one_rebuild_is_one_trace_with_the_whole_tree(layout):
-    engine = make_engine(layout)  # no profiler, tracer or config key passed
+@pytest.mark.parametrize("fold", ["first", "again"])
+def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
+    """``again``: a second fold of the uploaded corpus is the same subtree in
+    the same trace, with a steady dispatch where the first compiled."""
+    engine = make_engine()  # no profiler, tracer or config key passed
     since = time.monotonic()
     wire, resident, res = rebuild(engine, make_events())
     spans = ring_since(since)
     assert len({s.context.trace_id for s in spans}) == 1
     encode, h2d, resident_span = (one(spans, n) for n in UMBRELLAS)
-    fetch = one(spans, "replay.fetch")
     # the trace replay.encode opened: the upload continues the pack's context
     # and the fold the upload's, each after the one it follows
     assert encode.parent_id is None
     assert h2d.parent_id == encode.context.span_id
-    assert resident_span.parent_id == h2d.context.span_id
     assert encode.end_mono <= h2d.start_mono <= h2d.end_mono
-    assert h2d.end_mono <= resident_span.start_mono
     assert wire.trace_ctx == encode.context
     assert resident.trace_ctx == h2d.context
     assert_children(spans, encode, ENCODE_CHILDREN)
     assert_children(spans, h2d, H2D_CHILDREN)
-    fold_children = ["replay.plan", "replay.compile", "replay.fetch"]
-    if layout == "dense":
-        fold_children.append("replay.densify")
-        assert [s.attributes["cached"] for s in spans
-                if s.name == "replay.densify"] == [False]
-    else:
-        assert not [s for s in spans if s.name == "replay.densify"]
-    assert_children(spans, resident_span, fold_children)
-    assert_children(spans, fetch, FETCH_CHILDREN)
     assert all(s.parent_id is not None for s in spans if s is not encode)
+    dispatched = "replay.compile"
+    if fold == "again":
+        since = time.monotonic()
+        res = engine.replay_resident(resident)
+        spans = ring_since(since)
+        assert {s.context.trace_id for s in spans} == {encode.context.trace_id}
+        first, resident_span = resident_span, one(spans, "replay.resident")
+        assert resident_span.attributes == first.attributes
+        dispatched = "replay.dispatch"
+    fetch = one(spans, "replay.fetch")
+    assert resident_span.parent_id == h2d.context.span_id
+    assert h2d.end_mono <= resident_span.start_mono
+    assert_children(spans, resident_span,
+                    ["replay.plan", dispatched, "replay.fetch"])
+    assert_children(spans, fetch, FETCH_CHILDREN)
+    # one layout: plan, one dispatch a granularity, the pull, nothing else
+    assert sorted({s.name for s in spans
+                   if s.parent_id == resident_span.context.span_id}) == sorted(
+        ["replay.plan", dispatched, "replay.fetch"])
     assert all(s.status == "ok" and s.end_mono is not None for s in spans)
     # the counts ride as attributes
     n = make_events().num_events
@@ -122,63 +130,44 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(layout):
     assert encode.attributes["lanes_from"] == "boundaries"
     assert h2d.attributes["wire_bytes"] == encode.attributes["wire_bytes"]
     assert h2d.attributes["put_bytes"] == resident.wire_bytes == 1 << 16
+    assert sorted(resident_span.attributes) == [
+        "aggregates", "events", "gather", "padded_slots", "rounds",
+        "rows_fetched", "slots_small", "tiles", "tiles_small"]
     assert resident_span.attributes["aggregates"] == 48
     assert resident_span.attributes["events"] == n
     assert resident_span.attributes["padded_slots"] == res.padded_events
     assert resident_span.attributes["tiles"] == 2
     # engine.stats keeps its keys, fed by the same intervals
+    assert sorted(engine.stats) == ["h2d_s", "pack_s", "rows_fetched",
+                                    "windows"]
     assert engine.stats["pack_s"] == encode.seconds
     assert engine.stats["h2d_s"] == h2d.seconds
-    assert engine.stats["densify_s"] == sum(
-        s.seconds for s in spans if s.name == "replay.densify")
-    # a second fold of the same corpus: steady dispatch, cached tiles, same trace
-    since = time.monotonic()
-    engine.replay_resident(resident)
-    again = ring_since(since)
-    assert {s.context.trace_id for s in again} == {encode.context.trace_id}
-    assert not [s for s in again if s.name == "replay.compile"]
-    assert [s for s in again if s.name == "replay.dispatch"]
-    assert all(s.attributes["cached"] for s in again
-               if s.name == "replay.densify")
 
 
-@pytest.mark.parametrize("layout", ["flat", "dense"])
 @pytest.mark.parametrize("gather, per_lane", [("slices", 1), ("rows", 2)])
 def test_the_fold_spans_say_how_the_lane_rows_were_fetched(monkeypatch, gather,
-                                                           per_lane, layout):
-    """``gather`` and ``rows_fetched`` on ``replay.resident`` (both layouts)
-    and ``replay.densify`` (dense): a window of 16 events starting anywhere
-    needs two aligned rows of 128, or one slice; the counter's wire is one
-    array. A dense corpus fetches once, in the fold that builds its tiles."""
+                                                           per_lane):
+    """``gather`` and ``rows_fetched`` on ``replay.resident``: a window of 16
+    events starting anywhere needs two aligned rows of 128, or one slice; the
+    counter's wire is one array. Every fold of a corpus fetches the same."""
     monkeypatch.setattr(engine_module, "_lane_gather", lambda: gather)
-    engine = make_engine(layout)
+    engine = make_engine()
     since = time.monotonic()
     _, resident, _ = rebuild(engine, make_events())
     spans = ring_since(since)
     plan = engine._plan_for(resident)
-    work = [(len(i0), bs) for i0, bs in ((plan.big_i0, plan.bs_big),
-                                         (plan.small_i0, plan.bs_small))
-            if len(i0)]
+    want = sum(len(i0) * bs * per_lane
+               for i0, bs in ((plan.big_i0, plan.bs_big),
+                              (plan.small_i0, plan.bs_small)))
     fold = one(spans, "replay.resident")
     assert fold.attributes["gather"] == gather
-    densify = [s for s in spans if s.name == "replay.densify"]
-    if layout == "dense":
-        # densify fetches its whole padded work list (_plan_cap entries)
-        want = [engine._plan_cap(k) * bs * per_lane for k, bs in work]
-        assert [s.attributes["rows_fetched"] for s in densify] == want
-        assert {s.attributes["gather"] for s in densify} == {gather}
-    else:
-        want = [k * bs * per_lane for k, bs in work]
-        assert not densify
-    assert fold.attributes["rows_fetched"] == sum(want) > 0
-    assert engine.stats["rows_fetched"] == sum(want)
+    assert fold.attributes["rows_fetched"] == want > 0
+    assert engine.stats["rows_fetched"] == want
     since = time.monotonic()
     engine.replay_resident(resident)
-    again = ring_since(since)
-    assert one(again, "replay.resident").attributes["rows_fetched"] == (
-        0 if layout == "dense" else sum(want))
-    assert all(s.attributes["rows_fetched"] == 0 for s in again
-               if s.name == "replay.densify")
+    again = one(ring_since(since), "replay.resident")
+    assert again.attributes["rows_fetched"] == want
+    assert engine.stats["rows_fetched"] == 2 * want
 
 
 @pytest.mark.parametrize("grouped, block, blocks, lanes_from", [
@@ -369,27 +358,16 @@ def test_cold_path_jit_names_are_pinned():
     name the pinned tuple holds, and the benchmark's prefix file maps each
     ``jit_<name>``: a rename would unmap a program from its layer."""
     tree = ast.parse(inspect.getsource(engine_module))
-    returned = {}  # module-level factory -> the inner function it returns
-    for node in tree.body:
-        if isinstance(node, ast.FunctionDef):
-            names = [r.value.id for r in ast.walk(node)
-                     if isinstance(r, ast.Return)
-                     and isinstance(r.value, ast.Name)]
-            if names:
-                returned[node.name] = names[-1]
     jitted = []
     for call in ast.walk(tree):
         if (isinstance(call, ast.Call) and isinstance(call.func, ast.Attribute)
                 and call.func.attr == "jit"
                 and isinstance(call.func.value, ast.Name)
                 and call.func.value.id == "jax"):
-            made_from = call.args[0]
-            if isinstance(made_from, ast.Name):
-                jitted.append(made_from.id)
-            else:  # jax.jit(_make_densify(...))
-                assert isinstance(made_from, ast.Call), ast.dump(made_from)
-                jitted.append(returned[made_from.func.id])
-    assert len(jitted) >= 7
+            made_from = call.args[0]  # a function the engine defines, by name
+            assert isinstance(made_from, ast.Name), ast.dump(made_from)
+            jitted.append(made_from.id)
+    assert len(jitted) >= 5
     assert set(jitted) == set(COLD_PATH_JIT_NAMES)
     with open(os.path.join(ROOT, "benchmarks", "programs", "cold-fold.json"),
               encoding="utf-8") as f:
@@ -397,11 +375,9 @@ def test_cold_path_jit_names_are_pinned():
     for name in COLD_PATH_JIT_NAMES:
         assert any(f"jit_{name}".startswith(p) for p in prefixes), name
     # and the programs a driven engine holds carry those names
-    engine = make_engine("dense")
+    engine = ReplayEngine(make_replay_spec())
     rebuild(engine, make_events())
-    held = [*engine._densify_programs.values(),
-            *engine._resident_dense_folds.values(),
+    held = [*engine._resident_folds.values(),
             *engine._slab_programs.values(),
             *engine._finalize_programs.values()]
-    assert len(held) >= 4
-    assert {p.__name__ for p in held} <= set(COLD_PATH_JIT_NAMES)
+    assert {p.__name__ for p in held} == set(COLD_PATH_JIT_NAMES)

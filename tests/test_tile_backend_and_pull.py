@@ -1,34 +1,25 @@
-"""Dense resident layout + single-round-trip state pull.
+"""The resident fold's tile backend and its single-round-trip state pull.
 
-The r5 on-chip measurements (BENCH_ONCHIP.json) drove two engine changes:
-
-1. ``surge.replay.resident-layout = dense`` pre-gathers every tile once per
-   corpus (the per-lane gather was HALF the on-chip fold wall time);
+1. ``surge.replay.tile-backend = auto`` resolves by the backend and the spec;
 2. ``replay_resident`` pulls states in ONE device→host fetch — a u16 matrix
    with device-computed fit flags when every column is integer/bool, falling
    back to a wide u32 refetch when a value overflows 16 bits (the pull grows
-   with the aggregate count).
-
-These run the dense path explicitly on the CPU backend (where ``auto``
-resolves to flat to keep restores bounded-memory).
+   with the aggregate count); float columns always ride full width.
 """
 
 from __future__ import annotations
 
 import numpy as np
-import pytest
 
 from surge_tpu.codec.tensor import encode_events_columnar
 from surge_tpu.config import Config
 from surge_tpu.models import bank_account as ba
 from surge_tpu.models import counter
-from surge_tpu.replay.corpus import synth_counter_corpus
 from surge_tpu.replay.engine import ReplayEngine
 
 
-def _replay(layout: str, tile: str, events, **cfg):
+def _replay(tile: str, events, **cfg):
     eng = ReplayEngine(counter.make_replay_spec(), config=Config({
-        "surge.replay.resident-layout": layout,
         "surge.replay.tile-backend": tile,
         "surge.replay.batch-size": 256,
         "surge.replay.time-chunk": 16,
@@ -48,21 +39,6 @@ def test_auto_tile_backend_resolves_per_backend():
     assert eng2.tile_backend == "assoc"
 
 
-@pytest.mark.parametrize("tile", ["xla", "assoc"])
-def test_dense_layout_matches_flat(tile):
-    """Dense pre-gathered tiles fold to exactly the flat-gather states."""
-    corpus = synth_counter_corpus(731, 14_000, seed=5, sort_by_length=True)
-    flat = _replay("flat", tile, corpus.events)
-    dense = _replay("dense", tile, corpus.events)
-    np.testing.assert_array_equal(flat.states["count"], dense.states["count"])
-    np.testing.assert_array_equal(flat.states["version"],
-                                  dense.states["version"])
-    np.testing.assert_array_equal(dense.states["count"], corpus.expected_count)
-    np.testing.assert_array_equal(dense.states["version"],
-                                  corpus.expected_version)
-    assert flat.padded_events == dense.padded_events
-
-
 def test_narrow_pull_overflow_falls_back_wide():
     """A version past 32767 must trip the device fit flag and refetch wide —
     the u16 fast path can never silently truncate."""
@@ -70,14 +46,14 @@ def test_narrow_pull_overflow_falls_back_wide():
     logs = [[counter.CountIncremented("big", 1, k + 1) for k in range(n)],
             [counter.CountIncremented("small", 1, 1)]]
     ev = encode_events_columnar(counter.make_registry(), logs)
-    res = _replay("dense", "assoc", ev, **{"surge.replay.time-chunk": 64})
+    res = _replay("assoc", ev, **{"surge.replay.time-chunk": 64})
     assert int(res.states["count"][0]) == n
     assert int(res.states["version"][0]) == n  # exact despite the u16 fast path
     assert int(res.states["count"][1]) == 1
 
 
-def test_dense_layout_with_float_state_pulls_wide():
-    """bank_account's f32 balance forces the wide (bitcast u32) pull; dense
+def test_float_state_pulls_wide():
+    """bank_account's f32 balance forces the wide (bitcast u32) pull; the
     tiles must carry its side column correctly."""
     rng = np.random.default_rng(11)
     vocab = ba.Vocab()
@@ -92,7 +68,6 @@ def test_dense_layout_with_float_state_pulls_wide():
         logs.append([ba.encode_event(vocab, e) for e in evs])
     ev = encode_events_columnar(ba.make_registry(), logs)
     eng = ReplayEngine(ba.make_replay_spec(), config=Config({
-        "surge.replay.resident-layout": "dense",
         "surge.replay.batch-size": 64,
         "surge.replay.time-chunk": 8,
     }))
