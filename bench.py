@@ -122,8 +122,6 @@ def make_engine():
         # auto: assoc tree fold for models with an AssociativeFold on
         # accelerators (the r5 on-chip redesign)
         "surge.replay.tile-backend": os.environ.get("SURGE_BENCH_TILE", "auto"),
-        "surge.replay.upload-chunk-mb": int(
-            os.environ.get("SURGE_BENCH_UPLOAD_CHUNK_MB", 0)),
         # single corpus, explicit warm: exact buffer length, no bucket padding
         # on the (timed) upload
         "surge.replay.resident-len-bucket": "exact",
@@ -296,9 +294,7 @@ def replay_child(corpus_dir: str) -> None:
         "num_aggregates": corpus.num_aggregates,
         "knobs": {"dispatch": engine._dispatch, "unroll": engine._unroll,
                   "time_chunk": engine.time_chunk, "batch": engine.batch_size,
-                  "tile": engine.tile_backend,
-                  "upload_chunk_mb": engine.config.get_int(
-                      "surge.replay.upload-chunk-mb", 0)},
+                  "tile": engine.tile_backend},
         **extra_timing,
     }
     if engine.profiler is not None:

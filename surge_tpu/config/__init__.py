@@ -148,13 +148,10 @@ DEFAULTS: dict[str, Any] = {
     # picks the scanless assoc tree fold for models shipping AssociativeFold)
     "surge.replay.dispatch": "switch",  # switch | select
     "surge.replay.tile-backend": "auto",  # auto | xla | pallas | assoc
-    # bucket resident-corpus row lengths to powers of two ("pow2") so the
-    # jit cache sees few shapes, or keep exact lengths ("exact")
+    # bucket the resident corpus's device buffers to powers of two rows
+    # ("pow2", padded on the device) so the jit cache sees few shapes, or keep
+    # exact lengths ("exact")
     "surge.replay.resident-len-bucket": "pow2",  # pow2 | exact
-    # chunked H2D upload: pieces of this many MB pipeline over high-latency
-    # links and reassemble on device (0 = single put; single-device resident
-    # path only — the sharded upload already ships per-device pieces)
-    "surge.replay.upload-chunk-mb": 0,
     # overlap segment-stream uploads with replay dispatches in N segments
     # (0/1 = plain upload+replay)
     "surge.replay.upload-stream-segments": 0,

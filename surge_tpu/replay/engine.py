@@ -38,7 +38,7 @@ from surge_tpu.tracing import SpanContext, default_tracer
 #: XLA names a program ``jit_<function>``, and the benchmark's trace reduction
 #: maps programs to layers by those names (benchmarks/programs/cold-fold.json):
 #: renaming one unmaps its program. Held by tests/test_replay_spans.py.
-COLD_PATH_JIT_NAMES = ("fold", "finalize", "mk")
+COLD_PATH_JIT_NAMES = ("fold", "finalize", "mk", "mk_bucket", "mk_wire")
 
 #: the checkout's own persistent compile cache (listed in .gitignore). The
 #: path is part of every cache key, so it is fixed: never a temp, pid or
@@ -409,22 +409,6 @@ def _make_tile(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
     return view, tile
 
 
-def _chunked_put(arr: np.ndarray, chunk_mb: int):
-    """``jax.device_put`` in row pieces of ~chunk_mb, reassembled on device
-    with one concatenate; 0 (the default) keeps the single put.
-
-    Caveat: reassembly transiently holds BOTH the pieces and the concatenated
-    output in HBM (~2× the buffer); keep the knob off for corpora sized near
-    device memory."""
-    if chunk_mb <= 0 or arr.nbytes <= chunk_mb * 1024 * 1024:
-        return jax.device_put(arr)
-    row_bytes = max(arr.nbytes // max(arr.shape[0], 1), 1)
-    rows = max((chunk_mb * 1024 * 1024) // row_bytes, 1)
-    parts = [jax.device_put(arr[i: i + rows])
-             for i in range(0, arr.shape[0], rows)]
-    return jnp.concatenate(parts, axis=0)
-
-
 def _apply_perm(perm: Optional[np.ndarray],
                 init_carry: Mapping[str, Any] | None,
                 ordinal_base: np.ndarray | None):
@@ -471,16 +455,83 @@ def _bucket_len(n: int) -> int:
     return target
 
 
-def _bucket_rows(arr: np.ndarray, pow2: bool) -> np.ndarray:
-    """Zero-pad the leading axis to the next power of two (min 64Ki rows) so
-    program shapes bucket; identity when bucketing is off or already sized."""
-    if not pow2:
-        return np.ascontiguousarray(arr)
-    target = _bucket_len(arr.shape[0])
-    if target == arr.shape[0]:
-        return np.ascontiguousarray(arr)
-    pad = [(0, target - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
-    return np.pad(arr, pad)
+#: rows of one piece of the bucketed upload (:func:`_bucket_pieces`). A power
+#: of two no smaller than the least bucket, so it divides every bucket longer
+#: than itself. Chosen once on the chip (PERF.md, PR 30); not a setting.
+_PIECE_ROWS = 1 << 22
+
+#: pieces whose transfer may be under way while the next is handed over: the
+#: device holds the bucket and these, never a second wire. Four is where the
+#: waits stopped costing on the chip (PERF.md, PR 30).
+_PIECES_AHEAD = 4
+
+
+def mk_bucket(rows: int, tail: tuple, dtype):
+    """The device buffer of a bucketed upload, as zeros."""
+    return jnp.zeros((rows, *tail), dtype)
+
+
+def mk_wire(bucket, piece, at):
+    """One piece of the wire placed at row ``at`` of its donated bucket."""
+    return jax.lax.dynamic_update_slice(
+        bucket, piece, (at,) + (0,) * (piece.ndim - 1))
+
+
+#: compile keys (bucket rows, trailing shape, dtype) and (bucket shape, piece
+#: shape, dtype): one program a bucket and array kind, whatever the length
+_zero_bucket = jax.jit(mk_bucket, static_argnums=(0, 1, 2))
+_place_piece = jax.jit(mk_wire, donate_argnums=0)
+
+
+def _pad_rows(arr: np.ndarray, rows: int) -> np.ndarray:
+    """``arr`` in a fresh buffer of ``rows`` rows, zeros after its own."""
+    out = np.zeros((rows,) + arr.shape[1:], arr.dtype)
+    out[:arr.shape[0]] = arr
+    return out
+
+
+def _bucket_pieces(arr: np.ndarray, piece_rows: int):
+    """The host's half of a bucketed upload: ``arr`` as the row pieces that
+    go to the device, and the bytes copied to make them.
+
+    The bucket (:func:`_bucket_len` rows, zeros after the array's own) is the
+    device buffer's shape, for the compile keys of the programs that read it;
+    the host holds no padded copy. An array longer than one piece goes up in
+    pieces of ``piece_rows`` rows, contiguous views of ``arr`` itself (fresh,
+    mmapped or a slice of either); only the last, partial piece is padded, in
+    a buffer of its own. An array of at most one piece is one piece, padded
+    to its bucket (which is at most a piece)."""
+    rows = arr.shape[0]
+    pieces = [arr[at: at + piece_rows]
+              for at in range(0, max(rows, 1), piece_rows)]
+    whole = piece_rows if len(pieces) > 1 else _bucket_len(rows)
+    if pieces[-1].shape[0] == whole:
+        return pieces, 0
+    pieces[-1] = _pad_rows(pieces[-1], whole)
+    return pieces, pieces[-1].nbytes
+
+
+def _put_pieces(pieces: list):
+    """The device's half: the pieces of :func:`_bucket_pieces` as one buffer
+    of the array's bucket, element for element ``np.pad(arr, bucket)``. One
+    piece is put as it is. Several are each placed at their row offset by
+    :func:`mk_wire` right after their put, into a bucket of device zeros
+    (whole pieces fill a power of two of them: the bucket of the rows)."""
+    if len(pieces) == 1:
+        return jax.device_put(pieces[0])
+    piece_rows = pieces[0].shape[0]
+    out = _zero_bucket(_bucket_len(len(pieces) * piece_rows),
+                       pieces[0].shape[1:], pieces[0].dtype)
+    ahead: list = []
+    for i, host in enumerate(pieces):
+        if len(ahead) == _PIECES_AHEAD:
+            # a piece is freed once placed; wait for an earlier transfer
+            # before handing over another, or every piece is in flight
+            ahead.pop(0).block_until_ready()
+        piece = jax.device_put(host)
+        out = _place_piece(out, piece, np.int32(i * piece_rows))
+        ahead.append(piece)
+    return out
 
 
 @dataclass
@@ -1129,12 +1180,23 @@ class ReplayEngine:
         """Device-side half of :meth:`prepare_resident`: ship a packed wire
         corpus (fresh or mmapped from disk) and return the replay handle.
 
-        Buffer lengths are bucketed to powers of two by default
+        The device buffers' lengths are bucketed to powers of two by default
         (``surge.replay.resident-len-bucket = pow2``), so consecutive uploads
         of different-sized corpora — segment chunks in a restore — reuse one
-        compiled program per bucket instead of recompiling per exact length;
-        ``exact`` skips the padding for single-corpus workloads that warm
-        explicitly (bench)."""
+        compiled program per bucket instead of recompiling per exact length.
+        The bucket is the device buffer's: the wire's own buffers go up as
+        they are, in fixed-shape row pieces where they are longer than one,
+        and the host copies at most one piece an array
+        (:func:`_bucket_pieces`, :func:`_put_pieces`). ``exact`` puts each
+        buffer whole at its own length, for single-corpus workloads that warm
+        explicitly (bench).
+
+        Spans: ``h2d.bucket`` is what the host still copies (``starts`` /
+        ``lens`` and the padded last pieces: ``copied_bytes``); ``h2d.put``
+        is every put and placement through ``block_until_ready`` of the
+        packed buffer (``put_bytes``: the bytes handed to ``device_put``;
+        ``pieces``: the puts, 1 an array of at most one piece). ``h2d``
+        carries all three."""
         if self.mesh is not None:
             raise NotImplementedError(
                 "this engine is mesh-backed; use prepare_resident_sharded / "
@@ -1145,12 +1207,9 @@ class ReplayEngine:
         with stage("h2d", follows=w.trace_ctx,
                    wire_bytes=_wire_nbytes(w.packed, w.side),
                    side_bytes=_side_nbytes(w.side)) as h2d:
-            with stage("h2d.bucket"):
-                pow2 = self.config.get_str(
-                    "surge.replay.resident-len-bucket", "pow2") == "pow2"
-                packed_b = _bucket_rows(w.packed, pow2)
-                side_b = {k: _bucket_rows(v, pow2) for k, v in w.side.items()}
-                put_bytes = _wire_nbytes(packed_b, side_b)
+            pow2 = self.config.get_str(
+                "surge.replay.resident-len-bucket", "pow2") == "pow2"
+            with stage("h2d.bucket") as bucket:
                 bs = min(self.batch_size,
                          _round_up(max(b, 1), self._lane_multiple()))
                 b_pad = _round_up(max(b, 1), bs)
@@ -1159,26 +1218,27 @@ class ReplayEngine:
                     while chunks * bs < b_pad:
                         chunks *= 2
                     b_pad = chunks * bs
-                starts_p = np.zeros((b_pad,), dtype=np.int32)
-                starts_p[:b] = w.starts
-                lens_p = np.zeros((b_pad,), dtype=np.int32)
-                lens_p[:b] = w.lengths
-            with stage("h2d.put", put_bytes=put_bytes):
-                # chunked H2D: on a high-latency link a single large put can
-                # fall off the fast path; pieces upload pipelined and are
-                # reassembled on-device with one concatenate
-                chunk_mb = self.config.get_int("surge.replay.upload-chunk-mb", 0)
-                flat_wire = _chunked_put(packed_b, chunk_mb)
-                flat_side = {k: _chunked_put(v, chunk_mb)
-                             for k, v in side_b.items()}
-                if self.lane_gather == "rows":
-                    flat_wire = _round_rows(flat_wire)
-                    flat_side = {k: _round_rows(v)
-                                 for k, v in flat_side.items()}
+                starts_p = _pad_rows(w.starts, b_pad)
+                lens_p = _pad_rows(w.lengths, b_pad)
+                copied_bytes = starts_p.nbytes + lens_p.nbytes
+                # the packed buffer first: the put's wait is for it alone
+                host = [_bucket_pieces(arr, _PIECE_ROWS) if pow2 else ([arr], 0)
+                        for arr in (w.packed, *w.side.values())]
+                copied_bytes += sum(copied for _, copied in host)
+                pieces = sum(len(ps) for ps, _ in host)
+                put_bytes = sum(p.nbytes for ps, _ in host for p in ps)
+                bucket.set_attribute("copied_bytes", copied_bytes)
+            with stage("h2d.put", put_bytes=put_bytes, pieces=pieces):
+                placed = [_put_pieces(ps) for ps, _ in host]
+                if not pow2 and self.lane_gather == "rows":
+                    placed = [_round_rows(dev) for dev in placed]
+                flat_wire, flat_side = placed[0], dict(zip(w.side, placed[1:]))
                 starts_dev = jax.device_put(starts_p)
                 lens_dev = jax.device_put(lens_p)
                 jax.block_until_ready(flat_wire)
             h2d.set_attribute("put_bytes", put_bytes)
+            h2d.set_attribute("pieces", pieces)
+            h2d.set_attribute("copied_bytes", copied_bytes)
         self.stats["h2d_s"] += h2d.seconds
         return ResidentCorpus(
             derived_key=dict(w.derived_key), flat_wire=flat_wire,

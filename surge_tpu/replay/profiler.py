@@ -11,9 +11,10 @@ replay pass into stages, each a span that is open while the work runs:
   ``encode.bytes`` (the byte split) and ``encode.guard`` (guard padding,
   lane starts);
 - ``h2d``     — host→device transfer of windows / the resident corpus
-  (``upload_resident``), with children ``h2d.bucket`` (the host copy that
-  pads the buffers to their bucket) and ``h2d.put`` (``device_put`` through
-  ``block_until_ready``);
+  (``upload_resident``), with children ``h2d.bucket`` (what the host still
+  copies for the bucket: lane starts and lengths, each array's last partial
+  piece) and ``h2d.put`` (the puts, and each piece's placement into the
+  bucket-shaped device buffer, through ``block_until_ready``);
 - ``resident`` — the umbrella of one resident fold (``replay_resident`` /
   ``fold_resident_slab``): ``plan`` (lane order, tile plan, work lists),
   then ``compile``/``dispatch`` and ``fetch``;

@@ -358,30 +358,6 @@ def test_streamed_resident_replay_matches_plain():
         np.testing.assert_array_equal(one.states[name], plain.states[name])
 
 
-def test_chunked_upload_reassembles_exactly():
-    """_chunked_put must round-trip arbitrary arrays byte-exactly (it carries
-    the wire bytes the fold decodes) and the chunked replay must match the
-    single-put replay."""
-    from surge_tpu.replay.corpus import synth_counter_corpus
-    from surge_tpu.replay.engine import _chunked_put
-
-    rng = np.random.default_rng(3)
-    for shape in ((1_500_000, 1), (1_234_567,), (3, 5)):
-        a = rng.integers(0, 255, size=shape).astype(np.uint8)
-        np.testing.assert_array_equal(np.asarray(_chunked_put(a, 1)), a)
-
-    corpus = synth_counter_corpus(2000, 150_000, seed=14)
-    outs = {}
-    for mb in (0, 1):
-        eng = ReplayEngine(counter.make_replay_spec(), config=Config(overrides={
-            "surge.replay.batch-size": 256,
-            "surge.replay.upload-chunk-mb": mb}))
-        outs[mb] = eng.replay_resident(eng.prepare_resident(corpus.events))
-    for name in outs[0].states:
-        np.testing.assert_array_equal(outs[0].states[name], outs[1].states[name])
-    np.testing.assert_array_equal(outs[1].states["count"], corpus.expected_count)
-
-
 def test_pallas_tile_backend_matches_xla():
     """surge.replay.tile-backend=pallas must fold byte-identically to the XLA
     scan (interpret mode on CPU runs the same kernel program), across models

@@ -97,6 +97,7 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
     assert resident.trace_ctx == h2d.context
     assert_children(spans, encode, ENCODE_CHILDREN)
     assert_children(spans, h2d, H2D_CHILDREN)
+    bucket, put = (one(spans, name) for name in H2D_CHILDREN)
     assert all(s.parent_id is not None for s in spans if s is not encode)
     dispatched = "replay.compile"
     if fold == "again":
@@ -130,6 +131,14 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
     assert encode.attributes["lanes_from"] == "boundaries"
     assert h2d.attributes["wire_bytes"] == encode.attributes["wire_bytes"]
     assert h2d.attributes["put_bytes"] == resident.wire_bytes == 1 << 16
+    # a wire of under one piece: one put an array (the one-byte word alone),
+    # padded on the host to its bucket, with the two int32 lane vectors
+    assert h2d.attributes["pieces"] == 1
+    assert h2d.attributes["copied_bytes"] == (
+        (1 << 16) + 2 * 4 * resident.b_pad)
+    assert bucket.attributes == {
+        "copied_bytes": h2d.attributes["copied_bytes"]}
+    assert put.attributes == {"put_bytes": 1 << 16, "pieces": 1}
     assert sorted(resident_span.attributes) == [
         "aggregates", "events", "gather", "padded_slots", "rounds",
         "rows_fetched", "slots_small", "tiles", "tiles_small"]
@@ -292,6 +301,7 @@ def test_stages_are_trace_annotations_of_the_same_names(tmp_path):
     # the counts known when a stage opens are the annotation's metadata
     assert host["replay.encode"][2]["events"] == events.num_events
     assert host["replay.h2d.put"][2]["put_bytes"] == 1 << 17
+    assert host["replay.h2d.put"][2]["pieces"] == 1
 
 
 def test_the_ring_is_bounded_and_keeps_the_newest():
@@ -379,5 +389,6 @@ def test_cold_path_jit_names_are_pinned():
     rebuild(engine, make_events())
     held = [*engine._resident_folds.values(),
             *engine._slab_programs.values(),
-            *engine._finalize_programs.values()]
+            *engine._finalize_programs.values(),
+            engine_module._zero_bucket, engine_module._place_piece]
     assert {p.__name__ for p in held} == set(COLD_PATH_JIT_NAMES)
