@@ -132,7 +132,7 @@ class ReplaySpec:
     #: optional AssociativeFold (surge_tpu.replay.seqpar) — when present, the
     #: replay engine's ``auto`` tile backend folds each tile by lift +
     #: order-preserving tree reduction instead of a sequential time scan
-    #: (~58 µs/step loop machinery on the v5e, BENCH_ONCHIP.json), and the
+    #: (12.5 µs a step of 8192 lanes on the v5e: PERF.md, PR 31), and the
     #: time axis can shard across a mesh. Law-checked on first use.
     associative: Any = None
 

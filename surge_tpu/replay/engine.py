@@ -1594,6 +1594,11 @@ class ReplayEngine:
                 umbrella.set_attribute("rounds", plan.rounds)
                 umbrella.set_attribute("tiles_small", len(plan.small_i0))
                 umbrella.set_attribute("slots_small", plan.slots_small)
+                # the steps a sequential tile takes one after the other: a
+                # tile's width, every tile; the assoc tree takes none
+                umbrella.set_attribute(
+                    "scan_steps", 0 if self.tile_backend == "assoc"
+                    else plan.tiles * plan.width)
             if init_sorted is None and ord_sorted is None:
                 # fresh replay: build the init slab ON DEVICE (no host
                 # transfer on the replay's critical path); its dispatch is
@@ -1669,9 +1674,12 @@ class ReplayEngine:
         """The resolved tile backend. ``auto`` picks the scanless assoc tree
         fold only where it measured faster: models shipping a (law-checked)
         ``AssociativeFold``, power-of-two tile width, and a non-CPU backend —
-        on chip the scan pays ~58 µs/step loop machinery (assoc fold ~7× the
-        scan at full scale, BENCH_ONCHIP.json r5), while the 1-core host runs the
-        scan ~2× FASTER than the tree (401M vs 188M ev/s). Only an EXPLICIT
+        on chip the scan is step-bound (12.5 µs a step of 8192 lanes under
+        the mixed spec's nine-way switch: ``scan_step_us`` in the cell
+        ``rebuild-mixed-opaque``, PERF.md, PR 31; 62,500 steps for 100M
+        events, where the assoc tree folds the counter's in 0.072 s), while
+        the 1-core host runs the scan ~2× FASTER than the tree (401M vs 188M
+        ev/s). Only an EXPLICIT
         ``tile-backend = assoc`` raises on an unsupported spec/width."""
         if self._tile_backend != "auto":
             return self._tile_backend

@@ -141,7 +141,7 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
     assert put.attributes == {"put_bytes": 1 << 16, "pieces": 1}
     assert sorted(resident_span.attributes) == [
         "aggregates", "events", "gather", "padded_slots", "rounds",
-        "rows_fetched", "slots_small", "tiles", "tiles_small"]
+        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small"]
     assert resident_span.attributes["aggregates"] == 48
     assert resident_span.attributes["events"] == n
     assert resident_span.attributes["padded_slots"] == res.padded_events
