@@ -283,21 +283,16 @@ class WireFormat:
                                  .reshape(hi - lo, -1)[:, :self.nbytes])
         return packed, len(blocks)
 
-    def side_columns(self, cols: Mapping[str, np.ndarray], guard: int = 0
+    def side_columns(self, cols: Mapping[str, np.ndarray]
                      ) -> dict[str, np.ndarray]:
-        """The side half of the flat pack with ``guard`` zero rows appended:
-        ``{name: [N + guard]}`` in each column's wire dtype, cast straight into
-        its preallocated buffer (numpy casts in strides of its own: no
-        temporary, so no loop over blocks here)."""
-        side: dict[str, np.ndarray] = {}
-        for f in self.side_fields:
-            col = np.asarray(cols[f.name])
-            n = col.shape[0]
-            buf = np.empty(n + guard, dtype=f.dtype)
-            buf[:n] = col
-            buf[n:] = 0
-            side[f.name] = buf
-        return side
+        """The side half of the flat pack, ``{name: [N]}`` in each column's
+        wire dtype, by :meth:`split_flat`'s own expression: a column already
+        in that dtype and contiguous is the caller's array itself (no host
+        copy: :meth:`ReplayEngine.upload_resident` supplies the rows past
+        ``N`` as device zeros), any other (an int64 or strided column from a
+        decoder) is cast into a fresh ``[N]`` buffer."""
+        return {f.name: np.ascontiguousarray(cols[f.name], dtype=f.dtype)
+                for f in self.side_fields}
 
     def pack_flat(self, type_ids: np.ndarray, cols: Mapping[str, np.ndarray]
                   ) -> tuple[np.ndarray, dict[str, np.ndarray]]:
@@ -329,9 +324,7 @@ class WireFormat:
         for k in range(self.nbytes):
             packed[:, k] = ((word >> np.asarray(8 * k, dtype=word.dtype))
                             & np.asarray(0xFF, dtype=word.dtype))
-        side = {f.name: np.ascontiguousarray(cols[f.name], dtype=f.dtype)
-                for f in self.side_fields}
-        return packed, side
+        return packed, self.side_columns(cols)
 
     # -- device side ----------------------------------------------------------------------
 

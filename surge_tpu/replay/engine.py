@@ -279,15 +279,6 @@ def _rows_per_lane(width: int, gather: str) -> int:
     return (width + _LANE_ROW - 2) // _LANE_ROW + 1
 
 
-def _round_rows(arr):
-    """A device buffer zero-padded to whole rows of :data:`_LANE_ROW` events
-    (``resident-len-bucket = exact``; a power-of-two bucket already is)."""
-    extra = -arr.shape[0] % _LANE_ROW
-    if not extra:
-        return arr
-    return jnp.pad(arr, [(0, extra)] + [(0, 0)] * (arr.ndim - 1))
-
-
 def _make_lane_fetch(wire: WireFormat, width: int, gather: str):
     """How a tile's lane rows get from the flat wire into ``[width, bs]``.
     Returns ``(view, fetch)``:
@@ -490,38 +481,41 @@ def _pad_rows(arr: np.ndarray, rows: int) -> np.ndarray:
     return out
 
 
-def _bucket_pieces(arr: np.ndarray, piece_rows: int):
+def _bucket_pieces(arr: np.ndarray, piece_rows: int, rows: int | None = None):
     """The host's half of a bucketed upload: ``arr`` as the row pieces that
     go to the device, and the bytes copied to make them.
 
-    The bucket (:func:`_bucket_len` rows, zeros after the array's own) is the
-    device buffer's shape, for the compile keys of the programs that read it;
-    the host holds no padded copy. An array longer than one piece goes up in
-    pieces of ``piece_rows`` rows, contiguous views of ``arr`` itself (fresh,
-    mmapped or a slice of either); only the last, partial piece is padded, in
-    a buffer of its own. An array of at most one piece is one piece, padded
-    to its bucket (which is at most a piece)."""
-    rows = arr.shape[0]
-    pieces = [arr[at: at + piece_rows]
-              for at in range(0, max(rows, 1), piece_rows)]
-    whole = piece_rows if len(pieces) > 1 else _bucket_len(rows)
+    The bucket (:func:`_bucket_len` of ``rows``, zeros after the array's own)
+    is the device buffer's shape, for the compile keys of the programs that
+    read it; the host holds no padded copy. ``rows`` is the array's own count
+    unless the caller buckets it with a longer one (a side column of ``N``
+    rows beside its packed buffer of ``N + guard``): the rows between are the
+    bucket's zeros and make no piece. Where the bucket is longer than one
+    piece the array goes up in pieces of ``piece_rows`` rows, contiguous views
+    of ``arr`` itself (fresh, mmapped, the caller's own or a slice of any);
+    only the last, partial piece is padded, in a buffer of its own. A bucket
+    of at most a piece is one piece, the array padded to it."""
+    whole = min(piece_rows, _bucket_len(arr.shape[0] if rows is None else rows))
+    pieces = [arr[at: at + whole]
+              for at in range(0, max(arr.shape[0], 1), whole)]
     if pieces[-1].shape[0] == whole:
         return pieces, 0
     pieces[-1] = _pad_rows(pieces[-1], whole)
     return pieces, pieces[-1].nbytes
 
 
-def _put_pieces(pieces: list):
+def _put_pieces(pieces: list, bucket: int):
     """The device's half: the pieces of :func:`_bucket_pieces` as one buffer
-    of the array's bucket, element for element ``np.pad(arr, bucket)``. One
-    piece is put as it is. Several are each placed at their row offset by
-    :func:`mk_wire` right after their put, into a bucket of device zeros
-    (whole pieces fill a power of two of them: the bucket of the rows)."""
-    if len(pieces) == 1:
-        return jax.device_put(pieces[0])
+    of ``bucket`` rows, element for element ``np.pad(arr, bucket)``. A piece
+    that is the whole bucket is put as it is. Otherwise each is placed at its
+    row offset by :func:`mk_wire` right after its put, into a bucket of
+    device zeros, which also supplies every row past the last piece (under
+    ``resident-len-bucket = exact`` the one piece is the array, of any
+    length, and the bucket the rows its fold reads)."""
     piece_rows = pieces[0].shape[0]
-    out = _zero_bucket(_bucket_len(len(pieces) * piece_rows),
-                       pieces[0].shape[1:], pieces[0].dtype)
+    if piece_rows == bucket:
+        return jax.device_put(pieces[0])
+    out = _zero_bucket(bucket, pieces[0].shape[1:], pieces[0].dtype)
     ahead: list = []
     for i, host in enumerate(pieces):
         if len(ahead) == _PIECES_AHEAD:
@@ -541,11 +535,20 @@ class ResidentWire:
     Produced by :meth:`ReplayEngine.pack_resident`; consumed by
     :meth:`ReplayEngine.upload_resident`. Saving this next to the log segment
     makes the pack a one-time build cost: every later cold start mmaps the
-    wire bytes and streams them straight onto the device."""
+    wire bytes and streams them straight onto the device.
+
+    ``packed`` carries ``guard`` zero rows past its ``num_events``. A side
+    column needs none: it holds between ``num_events`` rows and the packed
+    buffer's (:meth:`ReplayEngine.check_wire`), and the upload supplies the
+    rest as device zeros. ``pack_resident`` makes it ``[N]``; a wire saved by
+    an older build holds ``[N + guard]`` and still loads. A side column may
+    be the caller's own array (:meth:`ReplayEngine.pack_resident`): it must
+    not be written between ``pack_resident`` and the return of
+    ``upload_resident``."""
 
     derived_key: dict
     packed: np.ndarray  # u8 [N+guard, nbytes]
-    side: dict  # {name: np [N+guard]}
+    side: dict  # {name: np [N]}, possibly the caller's arrays
     starts: np.ndarray  # i32 [B] (length-sorted order)
     lengths: np.ndarray  # i32 [B]
     perm: Optional[np.ndarray]  # sorted-rank -> original index
@@ -1063,16 +1066,27 @@ class ReplayEngine:
         - ``encode.words``: :meth:`WireFormat.pack_blocks`, the word build per
           block, stored straight into the ``[N + guard, nbytes]`` buffer.
         - ``encode.bytes``: :meth:`WireFormat.side_columns`, the side columns
-          cast into their ``[N + guard]`` buffers (the counter has none).
+          ``[N]`` in their wire dtypes: a column that already is one is handed
+          over as it is, any other is cast into a fresh buffer (the counter
+          has none).
         - ``encode.guard``: ``starts``, the lane view under ``perm``, the
-          :class:`ResidentWire`. The guard rows are part of the buffers the
-          two stages before allocate; nothing is copied to append them.
+          :class:`ResidentWire`. The guard rows are the word buffer's alone,
+          part of what ``encode.words`` allocates; nothing is copied to
+          append them, and a side column has none (the upload's bucket of
+          device zeros is what the fold reads past its last row).
+
+        Ownership: the wire's side columns may be ``colev``'s own arrays.
+        They must not be written between this call and the return of
+        :meth:`upload_resident`; after it the device holds its own copy.
 
         The ``replay.encode`` span says which way it went: ``grouped``,
-        ``lanes_from`` (``boundaries`` or ``bincount``) and ``blocks`` (how
-        many blocks the word pass ran)."""
+        ``lanes_from`` (``boundaries`` or ``bincount``), ``blocks`` (how
+        many blocks the word pass ran), ``side_aliased`` (side columns handed
+        over as the caller's arrays) and ``side_copied_bytes`` (bytes cast
+        into fresh buffers)."""
         stage = self.profiler.stage
         b = colev.num_aggregates
+        given = colev.cols  # the caller's arrays, whatever is packed below
         with stage("encode", events=colev.num_events, aggregates=b) as enc:
             with stage("encode.lanes"):
                 lengths = grouped_lengths(colev.agg_idx, b)
@@ -1115,7 +1129,7 @@ class ReplayEngine:
                 packed, blocks = wire.pack_blocks(to_pack.type_ids,
                                                   to_pack.cols, guard)
             with stage("encode.bytes"):
-                side_flat = wire.side_columns(to_pack.cols, guard)
+                side_flat = wire.side_columns(to_pack.cols)
             with stage("encode.guard"):
                 # lengths/starts are in the PACKED stream's aggregate-id
                 # order; the grouped path then permutes the lane VIEW only
@@ -1135,6 +1149,10 @@ class ReplayEngine:
                     layout=wire.layout_fingerprint(), trace_ctx=enc.context)
             enc.set_attribute("wire_bytes", _wire_nbytes(packed, side_flat))
             enc.set_attribute("side_bytes", _side_nbytes(side_flat))
+            copied = [v.nbytes for k, v in side_flat.items()
+                      if not np.may_share_memory(v, given[k])]
+            enc.set_attribute("side_aliased", len(side_flat) - len(copied))
+            enc.set_attribute("side_copied_bytes", sum(copied))
             enc.set_attribute("blocks", blocks)
             enc.set_attribute("grouped", grouped)
             enc.set_attribute("lanes_from",
@@ -1143,16 +1161,29 @@ class ReplayEngine:
         return out
 
     def check_wire(self, w: "ResidentWire") -> WireFormat:
-        """Validate a (possibly disk-loaded) wire against this engine: guard
-        rows cover the tile width, and the packing layout matches the engine's
-        schema bit-for-bit. Returns the engine's WireFormat for the wire's
-        derived-column declaration. Shared by the single-device and sharded
-        upload paths — a stale wire must never decode silently-wrong states."""
+        """Validate a (possibly disk-loaded) wire against this engine: the
+        packed buffer's guard rows cover the tile width, every side column
+        holds the wire's events and no more rows than the packed buffer, and
+        the packing layout matches the engine's schema bit-for-bit. Returns
+        the engine's WireFormat for the wire's derived-column declaration.
+        Shared by the single-device and sharded upload paths — a stale wire
+        must never decode silently-wrong states."""
         if w.guard < self.resident_tile_width():
             raise ValueError(
                 f"wire guard {w.guard} is smaller than the engine's tile width "
                 f"{self.resident_tile_width()}; repack or lower "
                 "surge.replay.time-chunk")
+        rows = w.packed.shape[0]
+        if rows < w.num_events + w.guard:
+            raise ValueError(
+                f"wire holds {rows} packed rows, fewer than its {w.num_events} "
+                f"events and {w.guard} guard rows; rebuild the wire")
+        for name, col in w.side.items():
+            if not w.num_events <= col.shape[0] <= rows:
+                raise ValueError(
+                    f"wire side column {name!r} holds {col.shape[0]} rows: "
+                    f"need at least the wire's {w.num_events} events and at "
+                    f"most the packed buffer's {rows}; rebuild the wire")
         # layout fingerprint check: never decode a wire packed under a
         # different schema (misaligned BITS would fold silently-wrong states —
         # the fingerprint pins field order, widths, shifts and type count, not
@@ -1184,19 +1215,27 @@ class ReplayEngine:
         (``surge.replay.resident-len-bucket = pow2``), so consecutive uploads
         of different-sized corpora — segment chunks in a restore — reuse one
         compiled program per bucket instead of recompiling per exact length.
-        The bucket is the device buffer's: the wire's own buffers go up as
-        they are, in fixed-shape row pieces where they are longer than one,
-        and the host copies at most one piece an array
-        (:func:`_bucket_pieces`, :func:`_put_pieces`). ``exact`` puts each
-        buffer whole at its own length, for single-corpus workloads that warm
-        explicitly (bench).
+        The bucket is the device buffer's, and one bucket serves the whole
+        wire: the packed buffer's (``N + guard`` rows), which a side column
+        of ``N`` rows shares, so every buffer a fold reads has one shape and
+        zeros after the last event. The wire's own buffers go up as they are,
+        in fixed-shape row pieces where the bucket is longer than one, and
+        the host copies at most one piece an array (:func:`_bucket_pieces`,
+        :func:`_put_pieces`). ``exact`` puts each buffer whole at its own
+        length, a side column then placed into device zeros of the packed
+        buffer's rows, for single-corpus workloads that warm explicitly
+        (bench).
+
+        The wire's side columns may be the caller's arrays
+        (:meth:`pack_resident`): every buffer has landed in a device buffer
+        of its own before this returns, so the caller may write them after.
 
         Spans: ``h2d.bucket`` is what the host still copies (``starts`` /
         ``lens`` and the padded last pieces: ``copied_bytes``); ``h2d.put``
         is every put and placement through ``block_until_ready`` of the
-        packed buffer (``put_bytes``: the bytes handed to ``device_put``;
-        ``pieces``: the puts, 1 an array of at most one piece). ``h2d``
-        carries all three."""
+        wire's buffers (``put_bytes``: the bytes handed to ``device_put``;
+        ``pieces``: the puts, 1 an array where the bucket is one piece).
+        ``h2d`` carries all three."""
         if self.mesh is not None:
             raise NotImplementedError(
                 "this engine is mesh-backed; use prepare_resident_sharded / "
@@ -1221,21 +1260,29 @@ class ReplayEngine:
                 starts_p = _pad_rows(w.starts, b_pad)
                 lens_p = _pad_rows(w.lengths, b_pad)
                 copied_bytes = starts_p.nbytes + lens_p.nbytes
-                # the packed buffer first: the put's wait is for it alone
-                host = [_bucket_pieces(arr, _PIECE_ROWS) if pow2 else ([arr], 0)
+                # one bucket a wire, the packed buffer's: a side column of
+                # fewer rows gets the same device shape
+                rows = w.packed.shape[0]
+                host = [_bucket_pieces(arr, _PIECE_ROWS, rows) if pow2
+                        else ([arr], 0)
                         for arr in (w.packed, *w.side.values())]
                 copied_bytes += sum(copied for _, copied in host)
                 pieces = sum(len(ps) for ps, _ in host)
                 put_bytes = sum(p.nbytes for ps, _ in host for p in ps)
                 bucket.set_attribute("copied_bytes", copied_bytes)
             with stage("h2d.put", put_bytes=put_bytes, pieces=pieces):
-                placed = [_put_pieces(ps) for ps, _ in host]
-                if not pow2 and self.lane_gather == "rows":
-                    placed = [_round_rows(dev) for dev in placed]
+                # exact: the packed buffer's own rows, whole rows of
+                # _LANE_ROW events where the fetch reads rows
+                whole = (_bucket_len(rows) if pow2 else rows
+                         if self.lane_gather == "slices"
+                         else _round_up(rows, _LANE_ROW))
+                placed = [_put_pieces(ps, whole) for ps, _ in host]
                 flat_wire, flat_side = placed[0], dict(zip(w.side, placed[1:]))
                 starts_dev = jax.device_put(starts_p)
                 lens_dev = jax.device_put(lens_p)
-                jax.block_until_ready(flat_wire)
+                # every buffer, not the packed one alone: a side column may
+                # be the caller's array, theirs to write once this returns
+                jax.block_until_ready(placed)
             h2d.set_attribute("put_bytes", put_bytes)
             h2d.set_attribute("pieces", pieces)
             h2d.set_attribute("copied_bytes", copied_bytes)
@@ -1826,6 +1873,8 @@ class ReplayEngine:
                                           starts64[lanes] - base, 0)
                     sub_lens = w.lengths[lanes]
                 first_piece = False
+                # a side column ends with the events: the last piece's
+                # slice of one is short of guard rows, which the upload fills
                 sub = ResidentWire(
                     derived_key=dict(w.derived_key),
                     packed=w.packed[base: end + w.guard],

@@ -8,13 +8,17 @@ replay pass into stages, each a span that is open while the work runs:
 - ``encode``  — host-side wire packing / bucketing (CPU-bound); the cold
   rebuild's ``pack_resident``, with children ``encode.lanes`` (length count
   and sort, grouped check), ``encode.words`` (the word build),
-  ``encode.bytes`` (the byte split) and ``encode.guard`` (guard padding,
-  lane starts);
+  ``encode.bytes`` (the side columns: one already in its wire dtype is
+  handed over as the caller's array, any other cast into a fresh ``[N]``
+  buffer; ``encode`` counts them as ``side_aliased`` and
+  ``side_copied_bytes``) and ``encode.guard`` (lane starts; the guard rows
+  are the word buffer's alone);
 - ``h2d``     — host→device transfer of windows / the resident corpus
   (``upload_resident``), with children ``h2d.bucket`` (what the host still
   copies for the bucket: lane starts and lengths, each array's last partial
   piece) and ``h2d.put`` (the puts, and each piece's placement into the
-  bucket-shaped device buffer, through ``block_until_ready``);
+  bucket-shaped device buffer, whose zeros are every row past an array's
+  last, through ``block_until_ready`` of them all);
 - ``resident`` — the umbrella of one resident fold (``replay_resident`` /
   ``fold_resident_slab``): ``plan`` (lane order, tile plan, work lists),
   then ``compile``/``dispatch`` and ``fetch``;

@@ -176,11 +176,13 @@ def case_the_ring_carries_the_counts():
     assert a["rounds"] == -(-int(corpus.lengths.max()) // 32)
     assert a["slots_small"] == a["tiles_small"] * 32 * 32
     assert a["padded_slots"] >= n and a["aggregates"] == b
-    # three int32 side columns, guard rows and all, beside a one-byte word
+    # three int32 side columns of the events' rows, the caller's own arrays,
+    # beside a one-byte word that alone carries guard rows
     side = encode.attributes["side_bytes"]
-    assert side == h2d.attributes["side_bytes"]
-    assert side == 12 * (encode.attributes["wire_bytes"] - side)
-    assert side >= 12 * n
+    assert side == h2d.attributes["side_bytes"] == 12 * n
+    assert encode.attributes["wire_bytes"] - side > n
+    assert encode.attributes["side_aliased"] == 3
+    assert encode.attributes["side_copied_bytes"] == 0
 
 
 CASES = {

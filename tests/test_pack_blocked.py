@@ -150,8 +150,7 @@ def plain_pack(engine, colev):
         for k in range(1, wire.nbytes)),
         plain_words(wire, to_pack.type_ids, to_pack.cols))
     guard = max(engine.resident_tile_width(), _WIRE_GUARD_MIN)
-    packed = np.pad(packed, ((0, guard), (0, 0)))
-    side = {k: np.pad(v, (0, guard)) for k, v in side.items()}
+    packed = np.pad(packed, ((0, guard), (0, 0)))  # the word buffer alone
     starts = np.zeros(b + 1, dtype=np.int64)
     np.cumsum(lengths, out=starts[1:])
     starts_lane, lens_lane = starts[:-1], lengths

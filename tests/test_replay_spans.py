@@ -211,6 +211,51 @@ def test_the_encode_span_says_how_the_pack_went(monkeypatch, grouped, block,
     assert (res.states["count"] == 20).all()
 
 
+@pytest.mark.parametrize("derived, dtypes, aliased, copied_columns", [
+    ({"sequence_number": "ordinal"}, {}, 0, 0),  # the counter cell: no side
+    ({}, {"sequence_number": np.int32}, 1, 0),
+    ({}, {"sequence_number": np.int64}, 0, 1),
+    ({}, {"sequence_number": "strided"}, 0, 1),
+    ({}, {"sequence_number": "ungrouped"}, 0, 1)])
+def test_the_encode_span_says_whose_the_side_columns_are(
+        derived, dtypes, aliased, copied_columns):
+    """``side_aliased`` and ``side_copied_bytes`` on ``replay.encode``: a side
+    column already in its wire dtype and contiguous is handed over as the
+    caller's array; an int64 or strided one is cast into a fresh ``[N]``
+    buffer, an ungrouped stream is sorted into one, and its bytes are
+    counted. The stage keeps its name either way."""
+    events = make_events()
+    events.derived_cols = dict(derived)
+    n = events.num_events
+    for name, dtype in dtypes.items():
+        seq = np.tile(np.arange(1, 21), 48)
+        events.cols[name] = (
+            np.repeat(seq, 2).astype(np.int32)[::2] if dtype == "strided"
+            else seq.astype(np.int32 if dtype == "ungrouped" else dtype))
+        if dtype == "ungrouped":  # aggregates interleaved, each log in order
+            order = np.argsort(np.tile(np.arange(20), 48), kind="stable")
+            events.agg_idx = events.agg_idx[order]
+            events.cols[name] = events.cols[name][order]
+    engine = make_engine()
+    since = time.monotonic()
+    wire = engine.pack_resident(events)
+    spans = ring_since(since)
+    encode = one(spans, "replay.encode")
+    assert_children(spans, encode, ENCODE_CHILDREN)
+    assert sorted(wire.side) == sorted(dtypes)
+    assert encode.attributes["side_aliased"] == aliased
+    assert encode.attributes["side_copied_bytes"] == 4 * n * copied_columns
+    assert encode.attributes["side_bytes"] == 4 * n * len(dtypes)
+    assert encode.attributes["wire_bytes"] == (
+        n + wire.guard + 4 * n * len(dtypes))
+    for name, col in wire.side.items():
+        assert col.shape == (n,) and col.dtype == np.int32
+        assert np.shares_memory(col, events.cols[name]) == bool(aliased)
+    res = engine.replay_resident(engine.upload_resident(wire))
+    assert (res.states["count"] == 20).all()
+    assert (res.states["version"] == 20).all()
+
+
 def test_a_callers_open_span_stays_the_parent_of_all_three():
     engine = make_engine()
     caller = InMemoryTracer(service="caller")  # whoever the caller is

@@ -57,7 +57,7 @@ def test_the_pieces_make_the_padded_buffer(rows, kind, source, tmp_path):
     assert copied <= PIECE * row_bytes
     assert copied == (0 if np.shares_memory(pieces[-1], arr)
                       else pieces[-1].nbytes)
-    dev = _put_pieces(pieces)
+    dev = _put_pieces(pieces, _bucket_len(n))
     want = np.pad(arr, [(0, _bucket_len(n) - n)] + [(0, 0)] * (arr.ndim - 1))
     assert dev.shape == want.shape and dev.dtype == want.dtype
     np.testing.assert_array_equal(np.asarray(dev), want)
@@ -93,11 +93,13 @@ def h2d_spans(since):
 
 
 def assert_device_buffers_are_the_padded_wire(resident, wire):
+    """One bucket a wire, the packed buffer's: a side column's fewer rows
+    (``[N]`` beside ``[N + guard]``) are padded to the same device shape."""
+    bucket = _bucket_len(wire.packed.shape[0])
     for dev, host in ((resident.flat_wire, wire.packed),
                       *((resident.flat_side[k], v)
                         for k, v in wire.side.items())):
-        rows = host.shape[0]
-        want = np.pad(host, [(0, _bucket_len(rows) - rows)]
+        want = np.pad(host, [(0, bucket - host.shape[0])]
                       + [(0, 0)] * (host.ndim - 1))
         assert dev.dtype == want.dtype
         np.testing.assert_array_equal(np.asarray(dev), want)
