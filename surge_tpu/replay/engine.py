@@ -1296,10 +1296,11 @@ class ReplayEngine:
             upload_s=h2d.seconds, trace_ctx=h2d.context)
 
     def prepare_resident_sharded(self, source):
-        """Mesh form of :meth:`prepare_resident`: deal the packed corpus's
-        lanes round-robin across the mesh axis and upload each device's shard
-        (surge_tpu.replay.resident_mesh). ``source`` is a ColumnarEvents or an
-        already-packed ResidentWire."""
+        """Mesh form of :meth:`prepare_resident`: cut the packed corpus at
+        event-count boundaries into one contiguous slice of its buffers a
+        device of the mesh axis and upload each through the pieces of
+        :meth:`upload_resident` (surge_tpu.replay.resident_mesh). ``source``
+        is a ColumnarEvents or an already-packed ResidentWire."""
         from surge_tpu.replay.resident_mesh import ShardedResident
 
         wire = (source if isinstance(source, ResidentWire)
@@ -1589,7 +1590,9 @@ class ReplayEngine:
         def finalize(sl, ip):
             parts, fits = [], {}
             for name, dt in order:
-                v = sl[name][ip]  # gather = un-perm + [:b] in one op
+                # gather = un-perm + [:b] in one op; a mesh's [n_dev, b_pad]
+                # slab is read flat (resident_mesh.ShardedResident.slab_rows)
+                v = sl[name].reshape(-1)[ip]
                 if np.issubdtype(dt, np.floating):
                     fits[name] = jnp.bool_(False)
                     bits = jax.lax.bitcast_convert_type(

@@ -153,6 +153,85 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
     assert engine.stats["h2d_s"] == h2d.seconds
 
 
+@pytest.mark.parametrize("fold", ["first", "again"])
+def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold):
+    """The mesh form (``replay/resident_mesh.py``): pack, deal, upload, fold
+    and pull of one rebuild over four devices, the one-chip names where the
+    work is the same, ``replay.shard`` for the deal, ``devices`` on all
+    three of its umbrellas."""
+    import jax
+
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("data",))
+    engine = make_engine(mesh=mesh)
+    since = time.monotonic()
+    wire = engine.pack_resident(make_events())
+    sharded = engine.prepare_resident_sharded(wire)
+    res = engine.replay_resident_sharded(sharded)
+    assert (res.states["count"] == 20).all()
+    spans = ring_since(since)
+    assert len({s.context.trace_id for s in spans}) == 1
+    encode, shard, h2d, resident_span = (one(spans, n) for n in (
+        "replay.encode", "replay.shard", "replay.h2d", "replay.resident"))
+    # each continues the one before it, after it
+    assert encode.parent_id is None
+    assert shard.parent_id == encode.context.span_id
+    assert h2d.parent_id == shard.context.span_id
+    assert encode.end_mono <= shard.start_mono <= shard.end_mono
+    assert shard.end_mono <= h2d.start_mono
+    assert sharded.trace_ctx == h2d.context
+    assert_children(spans, h2d, H2D_CHILDREN)
+    bucket, put = (one(spans, name) for name in H2D_CHILDREN)
+    dispatched = "replay.compile"
+    if fold == "again":
+        since = time.monotonic()
+        res = engine.replay_resident_sharded(sharded)
+        spans = ring_since(since)
+        assert {s.context.trace_id for s in spans} == {encode.context.trace_id}
+        first, resident_span = resident_span, one(spans, "replay.resident")
+        assert resident_span.attributes == first.attributes
+        dispatched = "replay.dispatch"
+    fetch = one(spans, "replay.fetch")
+    assert resident_span.parent_id == h2d.context.span_id
+    assert h2d.end_mono <= resident_span.start_mono
+    assert_children(spans, resident_span,
+                    ["replay.plan", dispatched, "replay.fetch"])
+    assert_children(spans, fetch, FETCH_CHILDREN)
+    assert sorted({s.name for s in spans
+                   if s.parent_id == resident_span.context.span_id}) == sorted(
+        ["replay.plan", dispatched, "replay.fetch"])
+    assert all(s.status == "ok" and s.end_mono is not None for s in spans)
+    # the deal: 48 logs of 20 events, 12 lanes and 240 events a device, one
+    # tile each; equal logs tile the buffer in lane order, nothing is copied
+    n = make_events().num_events
+    assert shard.attributes == {
+        "aggregates": 48, "events": n, "devices": 4, "lanes_min": 12,
+        "lanes_max": 12, "events_min": 240, "events_max": 240,
+        "tiles_min": 2, "tiles_max": 2, "copied_bytes": 0}
+    # the upload: one piece (the bucket) a device for the one-byte word, and
+    # the two int32 lane vectors of every device
+    assert h2d.attributes == {
+        "wire_bytes": encode.attributes["wire_bytes"], "side_bytes": 0,
+        "devices": 4, "put_bytes": 4 * (1 << 16), "pieces": 4,
+        "copied_bytes": 4 * (1 << 16) + 2 * 4 * 4 * sharded.b_pad}
+    assert bucket.attributes == {
+        "copied_bytes": h2d.attributes["copied_bytes"]}
+    assert put.attributes == {"put_bytes": 4 * (1 << 16), "pieces": 4}
+    assert sorted(resident_span.attributes) == [
+        "aggregates", "devices", "events", "gather", "padded_slots", "rounds",
+        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small"]
+    a = resident_span.attributes
+    assert (a["aggregates"], a["events"], a["devices"]) == (48, n, 4)
+    assert a["padded_slots"] == res.padded_events
+    # two tiles of 16 events a device; the steps ONE device takes in sequence
+    assert (a["tiles"], a["rounds"], a["scan_steps"]) == (8, 2, 2 * 16)
+    assert fetch.attributes == {"aggregates": 48}
+    wait = one(spans, "replay.fetch.wait")
+    assert wait.attributes == {"wire": "narrow", "bytes": 2 * (2 * 48 + 2)}
+    assert sorted(engine.stats) == ["h2d_s", "pack_s", "rows_fetched",
+                                    "windows"]
+    assert engine.stats["h2d_s"] == h2d.seconds
+
+
 @pytest.mark.parametrize("gather, per_lane", [("slices", 1), ("rows", 2)])
 def test_the_fold_spans_say_how_the_lane_rows_were_fetched(monkeypatch, gather,
                                                            per_lane):
@@ -437,3 +516,30 @@ def test_cold_path_jit_names_are_pinned():
             *engine._finalize_programs.values(),
             engine_module._zero_bucket, engine_module._place_piece]
     assert {p.__name__ for p in held} == set(COLD_PATH_JIT_NAMES)
+
+
+def test_the_sharded_programs_carry_the_cold_path_names():
+    """The mesh form's programs (``replay/resident_mesh.py``: the ``shard_map``
+    fold, the slab on its devices, the one-chip finalize) are made from
+    functions of the pinned names, so XLA calls them ``jit_fold``, ``jit_mk``
+    and ``jit_finalize`` and some ``programs/*.json`` prefix maps each."""
+    import jax
+
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("data",))
+    engine = make_engine(mesh=mesh)
+    res = engine.replay_resident_sharded(
+        engine.prepare_resident_sharded(make_events()))
+    assert (res.states["count"] == 20).all()
+    held = [*engine._resident_folds.values(),
+            *engine._slab_programs.values(),
+            *engine._finalize_programs.values()]
+    assert {p.__name__ for p in held} == {"fold", "mk", "finalize"}
+    assert {p.__name__ for p in held} <= set(COLD_PATH_JIT_NAMES)
+    prefixes = []
+    for path in glob.glob(os.path.join(ROOT, "benchmarks", "programs",
+                                       "*.json")):
+        with open(path, encoding="utf-8") as f:
+            prefixes += json.load(f)["prefixes"]
+    for program in held:
+        assert any(f"jit_{program.__name__}".startswith(p)
+                   for p in prefixes), program.__name__
