@@ -122,7 +122,11 @@ DEFAULTS: dict[str, Any] = {
     "surge.replay.backend": "tpu",  # tpu | cpu (scalar fold)
     "surge.replay.restore-on-start": False,  # engine cold start folds the events topic
     "surge.replay.batch-size": 8192,  # aggregates per device step
-    "surge.replay.time-chunk": 512,  # events scanned per lax.scan segment
+    # events scanned per lax.scan segment of a streamed window. For the
+    # resident fold it is a cap, the widest tile a plan may take: the plan
+    # chooses its width from the corpus's own lengths, this or a narrower
+    # (128 for logs of 100 events: replay/engine.py:_tile_width)
+    "surge.replay.time-chunk": 512,
     # tail windows shrink through a power-of-two ladder down to this width instead
     # of padding to a full time-chunk (pad_ratio lever; 0/neg disables the ladder)
     "surge.replay.min-time-window": 8,

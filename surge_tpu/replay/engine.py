@@ -279,6 +279,74 @@ def _rows_per_lane(width: int, gather: str) -> int:
     return (width + _LANE_ROW - 2) // _LANE_ROW + 1
 
 
+def _tile_sizes(batch_size: int, lane: int, b: int) -> tuple[int, int]:
+    """The two lane granularities of a resident plan over ``b`` lanes, as
+    ``(bs_big, bs_small)``: the batch size (or all the lanes, if fewer) and an
+    eighth of it, for the remainder of a round."""
+    bs_big = min(batch_size, _round_up(max(b, 1), lane))
+    bs_small = min(bs_big, max(lane, bs_big // 8))
+    # bs_small MUST divide bs_big: the small-tile walk covers
+    # [n_big*bs_big, active) in bs_small steps while the device buffer is
+    # only padded to a bs_big multiple (upload_resident b_pad) — a
+    # non-divisor's last tile would start within bs_small of the buffer
+    # end, dynamic_slice would clamp the lane start, and the tile would
+    # silently RE-APPLY events to lanes the previous tile already folded
+    # (ADVICE r4). Today bs_big is always a multiple of 8*lane so
+    # bs_big//8 divides exactly; this guard keeps the invariant explicit
+    # against future knob/rounding changes.
+    if bs_big % bs_small:
+        bs_small = max(c for c in range(lane, bs_small + 1, lane)
+                       if bs_big % c == 0)  # lane | bs_big, so non-empty
+    assert bs_big % bs_small == 0, (bs_big, bs_small)
+    return bs_big, bs_small
+
+
+def _tile_width(lengths: np.ndarray, bs_big: int, bs_small: int,
+                widths: Sequence[int], gather: str) -> int:
+    """The tile width a resident plan over logs of these ``lengths`` folds
+    at: of ``widths`` (ascending; the last is the widest tile allowed) the one
+    whose plan costs least, the wider of two that cost the same. Host only,
+    and a function of the lengths as a multiset: any order gives the same
+    answer.
+
+    The cost of a width is what its plan makes the device pass over, in
+    events of one array (the fold reads every slot, and the fetch every row,
+    in each array alike, so their number cancels): the padded slots its tiles
+    fold (``w`` a lane and tile) and the events its fetch moves (a lane and
+    tile: :func:`_rows_per_lane` aligned rows of :data:`_LANE_ROW` events, or
+    under ``slices`` the ``w`` events of the slice, so that there the cost is
+    the slots alone). A narrow tile stops close behind each lane's last event
+    and a wide one fetches fewer rows an event, so on the chip logs of 100
+    events fold at 128 (1.28 slots and 2.56 fetched events an event), not at
+    512 (5.12 and 6.40) nor at 64 (1.28 and 5.12). That sum chose the width
+    that measured best in each of eight sweeps on the chip (the ``assoc`` tree
+    and the scan tile, one to eight arrays, logs of 30, 100 and 300 events:
+    PERF.md, PR 34). The tiles are counted as
+    :meth:`ReplayEngine._resident_plan` makes them over lanes sorted by
+    length: each round's active lanes in ``bs_big`` tiles and the remainder
+    in ``bs_small`` ones; one ``searchsorted`` a width."""
+    b = lengths.shape[0]
+    if b == 0 or len(widths) == 1:
+        return widths[-1]
+    # the wire's lanes lie longest first: one compare says so
+    asc = (np.ascontiguousarray(lengths[::-1])
+           if (lengths[:-1] >= lengths[1:]).all() else np.sort(lengths))
+    longest = int(asc[-1])
+
+    def cost(w: int) -> int:
+        # lanes still folding at each round's first event (the offsets in
+        # the lengths' own dtype, or searchsorted widens all of ``asc``)
+        active = b - np.searchsorted(
+            asc, np.arange(0, longest, w, dtype=asc.dtype), side="right")
+        rest = active % bs_big
+        tiled = int((active - rest + _round_up(rest, bs_small)).sum())
+        moved = (w if gather == "slices"
+                 else _rows_per_lane(w, gather) * _LANE_ROW)
+        return tiled * (w + moved)
+
+    return min(reversed(widths), key=cost)  # of equals the widest
+
+
 def _make_lane_fetch(wire: WireFormat, width: int, gather: str):
     """How a tile's lane rows get from the flat wire into ``[width, bs]``.
     Returns ``(view, fetch)``:
@@ -1337,9 +1405,15 @@ class ReplayEngine:
                 "replay_resident_sharded for the resident path")
         return self.upload_resident(self.pack_resident(colev))
 
-    def _resident_plan(self, resident: "ResidentCorpus") -> "ResidentPlan":
+    def _resident_plan(self, resident: "ResidentCorpus",
+                       width: int | None = None) -> "ResidentPlan":
         """Host-side tile schedule. Tile k of a granularity folds events
         ``[t_bases[k], t_bases[k]+width)`` of lanes ``[i0s[k], i0s[k]+bs)``.
+
+        ``width`` is the corpus's own: chosen from its lengths among the
+        widths up to :meth:`resident_tile_width`, the widest tile allowed
+        (:func:`_tile_width`), unless the caller has chosen one for several
+        plans that share a program (a mesh: one width for every device).
 
         Lanes are length-sorted descending, so the lanes still active in round
         r form a shrinking prefix. Each round covers it with full-width
@@ -1349,23 +1423,10 @@ class ReplayEngine:
         shrinks, so running ALL big tiles (in round order) before ALL small
         tiles (in round order) preserves per-lane event order."""
         b = resident.lengths.shape[0]
-        lane = self._lane_multiple()
-        bs_big = min(self.batch_size, _round_up(max(b, 1), lane))
-        bs_small = min(bs_big, max(lane, bs_big // 8))
-        # bs_small MUST divide bs_big: the small-tile walk covers
-        # [n_big*bs_big, active) in bs_small steps while the device buffer is
-        # only padded to a bs_big multiple (upload_resident b_pad) — a
-        # non-divisor's last tile would start within bs_small of the buffer
-        # end, dynamic_slice would clamp the lane start, and the tile would
-        # silently RE-APPLY events to lanes the previous tile already folded
-        # (ADVICE r4). Today bs_big is always a multiple of 8*lane so
-        # bs_big//8 divides exactly; this guard keeps the invariant explicit
-        # against future knob/rounding changes.
-        if bs_big % bs_small:
-            bs_small = max(c for c in range(lane, bs_small + 1, lane)
-                           if bs_big % c == 0)  # lane | bs_big, so non-empty
-        assert bs_big % bs_small == 0, (bs_big, bs_small)
-        width = self.resident_tile_width()
+        bs_big, bs_small = _tile_sizes(self.batch_size, self._lane_multiple(),
+                                       b)
+        if width is None:
+            width = self._chosen_width(resident.lengths)
         lens_host = resident.lengths
         max_len = int(lens_host.max(initial=0)) if b else 0
         sorted_desc = bool((np.diff(lens_host) <= 0).all()) if b > 1 else True
@@ -1374,10 +1435,12 @@ class ReplayEngine:
         small_i0: list[int] = []
         small_tb: list[int] = []
         if sorted_desc:
-            lens_asc = lens_host[::-1]
-            t_base = 0
-            while t_base < max_len:
-                active = b - int(np.searchsorted(lens_asc, t_base, side="right"))
+            # every round's active lanes in one searchsorted (the offsets in
+            # the lengths' own dtype, or it widens the whole array)
+            lens_asc = np.ascontiguousarray(lens_host[::-1])
+            t_bases = np.arange(0, max_len, width, dtype=lens_asc.dtype)
+            actives = b - np.searchsorted(lens_asc, t_bases, side="right")
+            for t_base, active in zip(t_bases.tolist(), actives.tolist()):
                 n_big = active // bs_big
                 for k in range(n_big):
                     big_i0.append(k * bs_big)
@@ -1385,7 +1448,6 @@ class ReplayEngine:
                 for i0 in range(n_big * bs_big, active, bs_small):
                     small_i0.append(i0)
                     small_tb.append(t_base)
-                t_base += width
         else:
             # unsorted corpus: schedule each contiguous lane range only up to
             # its own local max length (the streaming path's per-chunk bound),
@@ -1639,6 +1701,8 @@ class ReplayEngine:
                                                   ordinal_base)
             plan = self._plan_for(resident)
             if umbrella is not None:
+                umbrella.set_attribute("width", plan.width)
+                umbrella.set_attribute("width_cap", self.resident_tile_width())
                 umbrella.set_attribute("padded_slots", plan.padded_slots)
                 umbrella.set_attribute("tiles", plan.tiles)
                 umbrella.set_attribute("rounds", plan.rounds)
@@ -1724,12 +1788,14 @@ class ReplayEngine:
         """The resolved tile backend. ``auto`` picks the scanless assoc tree
         fold only where it measured faster: models shipping a (law-checked)
         ``AssociativeFold``, power-of-two tile width, and a non-CPU backend —
-        on chip the scan is step-bound (12.5 µs a step of 8192 lanes under
-        the mixed spec's nine-way switch: ``scan_step_us`` in the cell
-        ``rebuild-mixed-opaque``, PERF.md, PR 31; 62,500 steps for 100M
-        events, where the assoc tree folds the counter's in 0.072 s), while
+        on chip the scan is step-bound (about 10 µs a step of 8192 lanes
+        under the mixed spec's nine-way switch: ``scan_step_us`` in the cell
+        ``rebuild-mixed-opaque``; some 20,500 steps for its 100M events in
+        tiles of 128, 64,000 when every tile was 512 wide: PERF.md, PR 34;
+        the assoc tree folds the counter's 100M in 0.033 s), while
         the 1-core host runs the scan ~2× FASTER than the tree (401M vs 188M
-        ev/s). Only an EXPLICIT
+        ev/s). The width tested is the widest tile allowed: every narrower
+        one a plan may choose is a power of two with it. Only an EXPLICIT
         ``tile-backend = assoc`` raises on an unsupported spec/width."""
         if self._tile_backend != "auto":
             return self._tile_backend
@@ -1923,15 +1989,31 @@ class ReplayEngine:
         return w
 
     def resident_tile_width(self) -> int:
-        """The fixed tile width of :meth:`replay_resident` tiles: the
-        time-chunk rounded up to a power of two, inside the HBM cap. One width
-        → one compiled program for the whole replay."""
-        w = max(self.min_time_window, 1)
+        """The widest tile a :meth:`replay_resident` plan may fold at: the
+        time-chunk rounded up to a power of two, inside the HBM cap. A plan
+        folds at the width its corpus's lengths make cheapest, this one or a
+        narrower (:meth:`_resident_plan`); one width a plan → one compiled
+        program a tile size for the whole replay. The wire's guard rows cover
+        this width, whatever a plan chooses."""
+        return self._tile_widths()[-1]
+
+    def _tile_widths(self) -> list[int]:
+        """The widths a plan chooses among, ascending: the min window doubled
+        up to :meth:`resident_tile_width`."""
+        widths = [max(self.min_time_window, 1)]
         target = max(self.time_chunk, 1)
         cap = self.resident_cap_width()
-        while w < target and w < cap:
-            w *= 2
-        return w
+        while widths[-1] < target and widths[-1] < cap:
+            widths.append(widths[-1] * 2)
+        return widths
+
+    def _chosen_width(self, lengths: np.ndarray) -> int:
+        """The tile width of a plan over logs of these ``lengths``:
+        :func:`_tile_width` under this engine's tile sizes and gather."""
+        bs_big, bs_small = _tile_sizes(self.batch_size, self._lane_multiple(),
+                                       lengths.shape[0])
+        return _tile_width(lengths, bs_big, bs_small, self._tile_widths(),
+                           self.lane_gather)
 
     def warm_resident(self, resident: "ResidentCorpus") -> None:
         """Compile every program a :meth:`replay_resident` of this corpus will

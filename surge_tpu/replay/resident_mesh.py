@@ -162,6 +162,9 @@ class ShardedResident:
 
         with stage("shard", follows=wire.trace_ctx, aggregates=b,
                    events=wire.num_events, devices=n_dev) as shard:
+            # one shard_map program folds every device's tiles: one width,
+            # chosen from the whole corpus's lengths before they are dealt
+            self.width = engine._chosen_width(wire.lengths)
             deals, shards, rows, copied = _deal(wire, n_dev)
             self.deals = deals
             b_local_max = max(len(lanes) for lanes in deals)
@@ -170,7 +173,6 @@ class ShardedResident:
             self.bs = bs
             b_pad = _round_up(max(b_local_max, 1), bs)
             self.b_pad = b_pad
-            self.width = engine.resident_tile_width()
             starts_l = np.zeros((n_dev, b_pad), dtype=np.int32)
             lens_l = np.zeros((n_dev, b_pad), dtype=np.int32)
             for d, (lanes, (_, _, starts)) in enumerate(zip(deals, shards)):
@@ -184,7 +186,8 @@ class ShardedResident:
             # shapes hold everywhere.
             plan_fn = type(engine)._resident_plan  # unbound: the view's bs
             plans: list[ResidentPlan] = [
-                plan_fn(_PlanView(engine, bs), _FakeResident(lens_l[d]))
+                plan_fn(_PlanView(engine, bs), _FakeResident(lens_l[d]),
+                        self.width)
                 for d in range(n_dev)]
             self.plans = plans
             assert all(p.bs_big == bs for p in plans)
@@ -392,6 +395,8 @@ def _dispatch_sharded(engine, sharded: ShardedResident,
 
     with stage("plan"):
         plans = sharded.plans
+        umbrella.set_attribute("width", sharded.width)
+        umbrella.set_attribute("width_cap", engine.resident_tile_width())
         umbrella.set_attribute("padded_slots", sharded.padded_slots)
         umbrella.set_attribute("tiles", sum(p.tiles for p in plans))
         umbrella.set_attribute("rounds", max(p.rounds for p in plans))

@@ -91,17 +91,19 @@ def case_fold(folds):
 
 
 def case_three_rounds_two_granularities():
-    # width 16 over logs of up to a few hundred events: the shrinking prefix
-    # is covered by 256-lane tiles and, for its remainder, 32-lane tiles
+    # a width of at most 16 (the plan's own choice under that cap) over logs
+    # of up to a few hundred events: the shrinking prefix is covered by
+    # 256-lane tiles and, for its remainder, 32-lane tiles
     engine = make_engine(batch=256, chunk=16)
     corpus, columns = make_corpus(3000, 120_000, 2**31 + 77)
     spans, _ = rebuild(engine, corpus, columns)
     (fold,) = named(spans, "replay.resident")
     a = fold.attributes
-    assert a["rounds"] == -(-int(corpus.lengths.max()) // 16) >= 3
+    assert a["width"] in (8, 16) and a["width_cap"] == 16
+    assert a["rounds"] == -(-int(corpus.lengths.max()) // a["width"]) >= 3
     assert int((corpus.lengths > 32).sum()) > 0  # lanes that take three rounds
     assert 0 < a["tiles_small"] < a["tiles"]
-    assert a["slots_small"] == a["tiles_small"] * 32 * 16
+    assert a["slots_small"] == a["tiles_small"] * 32 * a["width"]
     assert 0 < a["slots_small"] < a["padded_slots"]
     batches = sorted(s.attributes["batch"] for s in spans
                      if s.name in ("replay.compile", "replay.dispatch"))
@@ -173,8 +175,9 @@ def case_the_ring_carries_the_counts():
     assert wait.attributes == {"wire": "mixed", "bytes": 10 * b + 8}
     a = fold.attributes
     assert a["gather"] == "slices"  # the CPU backend's
-    assert a["rounds"] == -(-int(corpus.lengths.max()) // 32)
-    assert a["slots_small"] == a["tiles_small"] * 32 * 32
+    assert a["width"] <= a["width_cap"] == 32
+    assert a["rounds"] == -(-int(corpus.lengths.max()) // a["width"])
+    assert a["slots_small"] == a["tiles_small"] * 32 * a["width"]
     assert a["padded_slots"] >= n and a["aggregates"] == b
     # three int32 side columns of the events' rows, the caller's own arrays,
     # beside a one-byte word that alone carries guard rows

@@ -230,9 +230,11 @@ def test_mixed_rebuild_equals_the_plain_reference(monkeypatch, batch, chunk,
     (fold,) = named(spans, "replay.resident")
     a = fold.attributes
     assert a["gather"] == gather
-    assert a["rounds"] == -(-int(corpus.lengths().max()) // chunk) >= 3
+    # the width is the plan's own choice, at most the cap
+    assert a["width"] <= a["width_cap"] == chunk
+    assert a["rounds"] == -(-int(corpus.lengths().max()) // a["width"]) >= 3
     assert 0 < a["tiles_small"] < a["tiles"]
-    assert a["scan_steps"] == a["tiles"] * chunk
+    assert a["scan_steps"] == a["tiles"] * a["width"]
     assert a["padded_slots"] == res.padded_events >= corpus.num_events
     # the pull: the float always wide; the codes and the totals after one guess
     waits = [(s.attributes["wire"], s.attributes["bytes"])

@@ -141,11 +141,16 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
     assert put.attributes == {"put_bytes": 1 << 16, "pieces": 1}
     assert sorted(resident_span.attributes) == [
         "aggregates", "events", "gather", "padded_slots", "rounds",
-        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small"]
+        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small",
+        "width", "width_cap"]
     assert resident_span.attributes["aggregates"] == 48
     assert resident_span.attributes["events"] == n
     assert resident_span.attributes["padded_slots"] == res.padded_events
-    assert resident_span.attributes["tiles"] == 2
+    # logs of 20 under a cap of 16, one slice a lane: three tiles of 8 (24
+    # slots a lane) beat two of 16
+    assert resident_span.attributes["tiles"] == 3
+    assert resident_span.attributes["width"] == 8
+    assert resident_span.attributes["width_cap"] == 16
     # engine.stats keeps its keys, fed by the same intervals
     assert sorted(engine.stats) == ["h2d_s", "pack_s", "rows_fetched",
                                     "windows"]
@@ -201,12 +206,13 @@ def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold):
         ["replay.plan", dispatched, "replay.fetch"])
     assert all(s.status == "ok" and s.end_mono is not None for s in spans)
     # the deal: 48 logs of 20 events, 12 lanes and 240 events a device, one
-    # tile each; equal logs tile the buffer in lane order, nothing is copied
+    # tile a round each; equal logs tile the buffer in lane order, nothing is
+    # copied
     n = make_events().num_events
     assert shard.attributes == {
         "aggregates": 48, "events": n, "devices": 4, "lanes_min": 12,
         "lanes_max": 12, "events_min": 240, "events_max": 240,
-        "tiles_min": 2, "tiles_max": 2, "copied_bytes": 0}
+        "tiles_min": 3, "tiles_max": 3, "copied_bytes": 0}
     # the upload: one piece (the bucket) a device for the one-byte word, and
     # the two int32 lane vectors of every device
     assert h2d.attributes == {
@@ -218,12 +224,14 @@ def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold):
     assert put.attributes == {"put_bytes": 4 * (1 << 16), "pieces": 4}
     assert sorted(resident_span.attributes) == [
         "aggregates", "devices", "events", "gather", "padded_slots", "rounds",
-        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small"]
+        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small",
+        "width", "width_cap"]
     a = resident_span.attributes
     assert (a["aggregates"], a["events"], a["devices"]) == (48, n, 4)
+    assert (a["width"], a["width_cap"]) == (sharded.width, 16) == (8, 16)
     assert a["padded_slots"] == res.padded_events
-    # two tiles of 16 events a device; the steps ONE device takes in sequence
-    assert (a["tiles"], a["rounds"], a["scan_steps"]) == (8, 2, 2 * 16)
+    # three tiles of 8 events a device; the steps ONE device takes in sequence
+    assert (a["tiles"], a["rounds"], a["scan_steps"]) == (12, 3, 3 * 8)
     assert fetch.attributes == {"aggregates": 48}
     wait = one(spans, "replay.fetch.wait")
     assert wait.attributes == {"wire": "narrow", "bytes": 2 * (2 * 48 + 2)}
@@ -256,6 +264,43 @@ def test_the_fold_spans_say_how_the_lane_rows_were_fetched(monkeypatch, gather,
     again = one(ring_since(since), "replay.resident")
     assert again.attributes["rows_fetched"] == want
     assert engine.stats["rows_fetched"] == 2 * want
+
+
+@pytest.mark.parametrize("gather", ["slices", "rows"])
+@pytest.mark.parametrize("sharded", [False, True])
+def test_the_fold_span_says_which_width_the_plan_chose(monkeypatch, gather,
+                                                       sharded):
+    """``width`` and ``width_cap`` on ``replay.resident``, on one device or
+    four: under the default cap of 512, logs of 100 events fold at 128 in one
+    round where the fetch reads aligned rows (the chip's), and at 8 in 13
+    where it reads a slice a lane; ``scan_steps`` is the tiles of one device
+    times that width, and the padded slots stay under two an event."""
+    import jax
+
+    monkeypatch.setattr(engine_module, "_lane_gather", lambda: gather)
+    mesh = (jax.sharding.Mesh(np.array(jax.devices()[:4]), ("data",))
+            if sharded else None)
+    engine = ReplayEngine(make_replay_spec(), mesh=mesh,
+                          config=default_config().with_overrides(
+                              {"surge.replay.batch-size": 64}))
+    events = make_events(n_agg=256, n_per=100)
+    since = time.monotonic()
+    wire = engine.pack_resident(events)
+    if sharded:
+        res = engine.replay_resident_sharded(
+            engine.prepare_resident_sharded(wire))
+    else:
+        res = engine.replay_resident(engine.upload_resident(wire))
+    assert (res.states["count"] == 100).all()
+    a = one(ring_since(since), "replay.resident").attributes
+    width, rounds = (128, 1) if gather == "rows" else (8, 13)
+    assert (a["width"], a["width_cap"]) == (width, 512)
+    assert engine.resident_tile_width() == 512 <= wire.guard
+    assert a["rounds"] == rounds and a["tiles"] == 4 * rounds
+    assert a["scan_steps"] == (1 if sharded else 4) * rounds * width
+    assert a["padded_slots"] == res.padded_events == 256 * rounds * width
+    assert a["padded_slots"] / a["events"] < 2
+    assert a["rows_fetched"] == 256 * (2 if gather == "rows" else 13)
 
 
 @pytest.mark.parametrize("grouped, block, blocks, lanes_from", [

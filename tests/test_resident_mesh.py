@@ -188,6 +188,47 @@ def test_a_resumed_sharded_fold_equals_the_whole(name, wire_kind):
                        [fold_events(model(), None, log) for log in logs])
 
 
+@pytest.mark.parametrize("gather", ["slices", "rows"])
+@pytest.mark.parametrize("devices", [2, 4])
+def test_every_shard_folds_at_the_one_width_of_the_whole_corpus(monkeypatch,
+                                                                devices, gather):
+    """One ``shard_map`` program folds every device's tiles, so the width is
+    chosen once, from the whole corpus's lengths before the deal. A
+    length-sorted contiguous wire deals the few long logs to the first device
+    and short ones to the rest: left to itself each device would choose its
+    own."""
+    monkeypatch.setattr(engine_module, "_lane_gather", lambda: gather)
+    rng = np.random.default_rng(devices)
+    lengths = np.concatenate([np.full(40, 160), rng.integers(1, 7, size=1200)])
+    logs = [[counter.CountIncremented(f"c{a}", 1, k + 1) for k in range(n)]
+            for a, n in enumerate(rng.permutation(lengths).tolist())]
+    engine = make_engine(counter.make_replay_spec(), devices, chunk=64)
+    wire = engine.pack_resident(interleaved(
+        encode_events_columnar(counter.make_registry(), logs)))
+    since = time.monotonic()
+    sharded = engine.prepare_resident_sharded(wire)
+    res = engine.replay_resident_sharded(sharded)
+    assert_columns_are(res.states, [fold_events(counter.CounterModel(), None,
+                                                log) for log in logs])
+    whole = engine._chosen_width(wire.lengths)
+    assert sharded.width == whole in engine._tile_widths()
+    assert [p.width for p in sharded.plans] == [whole] * devices
+    own = [engine._chosen_width(wire.lengths[lanes])
+           for lanes in sharded.deals]
+    assert len(set(own)) > 1, own
+    (fold,) = spans_since(since, "replay.resident")
+    a = fold.attributes
+    assert (a["width"], a["width_cap"]) == (whole, 64)
+    assert a["scan_steps"] == max(p.tiles for p in sharded.plans) * whole
+    assert a["padded_slots"] == sum(p.padded_slots for p in sharded.plans)
+    # a second corpus of other lengths under the same engine: its own width
+    other = engine.prepare_resident_sharded(engine.pack_resident(
+        encode_events_columnar(counter.make_registry(),
+                               [log[:16] for log in logs if len(log) > 64])))
+    assert other.width == 16 != whole
+    assert (engine.replay_resident_sharded(other).states["count"] == 16).all()
+
+
 @pytest.mark.parametrize("devices", [2, 4])
 def test_a_tiling_wire_goes_up_as_it_lies(monkeypatch, devices):
     """Several pieces a shard: every shard is a slice of the wire's own arrays

@@ -81,9 +81,9 @@ MODELS = {"counter": (counter, counter_corpus, 1),
           "cart": (shopping_cart, cart_corpus, 4)}
 
 
-def make_engine(model):
+def make_engine(model, **keys):
     cfg = default_config().with_overrides({
-        "surge.replay.batch-size": 256, "surge.replay.time-chunk": 64})
+        "surge.replay.batch-size": 256, "surge.replay.time-chunk": 64, **keys})
     return ReplayEngine(model.make_replay_spec(), config=cfg)
 
 
@@ -162,9 +162,12 @@ def test_a_streamed_folds_sub_wires_go_up_in_pieces(monkeypatch, name):
 def test_a_second_length_in_the_bucket_compiles_no_placement(monkeypatch):
     """The placement's compile key is (bucket rows, piece rows, dtype,
     ``nbytes``): wires of other lengths in the same bucket reuse it, as a
-    restore over many segment lengths must."""
+    restore over many segment lengths must. The fold's key holds the tile
+    width too, which a plan takes from its corpus's log lengths: pinned here
+    (a ladder of one width), the fold compiles nothing either."""
     monkeypatch.setattr(engine_module, "_PIECE_ROWS", PIECE)
-    engine = make_engine(counter)
+    engine = make_engine(counter, **{"surge.replay.min-time-window": 64})
+    assert engine._tile_widths() == [64]
     first = engine.upload_resident(engine.pack_resident(counter_corpus(150_000)))
     engine.replay_resident(first)
     placements, folds = _place_piece._cache_size(), engine.num_compiles()
