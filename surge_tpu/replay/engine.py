@@ -279,6 +279,15 @@ def _rows_per_lane(width: int, gather: str) -> int:
     return (width + _LANE_ROW - 2) // _LANE_ROW + 1
 
 
+def _slots_per_lane(width: int, gather: str) -> int:
+    """The event slots of ONE array that one lane's fetch for one tile moves:
+    its aligned rows of :data:`_LANE_ROW` events, or under ``slices`` the
+    ``width`` events of the slice."""
+    if gather == "slices":
+        return width
+    return _rows_per_lane(width, gather) * _LANE_ROW
+
+
 def _tile_sizes(batch_size: int, lane: int, b: int) -> tuple[int, int]:
     """The two lane granularities of a resident plan over ``b`` lanes, as
     ``(bs_big, bs_small)``: the batch size (or all the lanes, if fewer) and an
@@ -340,9 +349,7 @@ def _tile_width(lengths: np.ndarray, bs_big: int, bs_small: int,
             asc, np.arange(0, longest, w, dtype=asc.dtype), side="right")
         rest = active % bs_big
         tiled = int((active - rest + _round_up(rest, bs_small)).sum())
-        moved = (w if gather == "slices"
-                 else _rows_per_lane(w, gather) * _LANE_ROW)
-        return tiled * (w + moved)
+        return tiled * (w + _slots_per_lane(w, gather))
 
     return min(reversed(widths), key=cost)  # of equals the widest
 
@@ -687,9 +694,14 @@ class ResidentPlan:
     small_tb: np.ndarray  # i32 [k_small]
 
     @property
-    def padded_slots(self) -> int:
+    def lanes_tiled(self) -> int:
+        """The lane windows the plan's tiles cover, padding lanes and all."""
         return (len(self.big_i0) * self.bs_big
-                + len(self.small_i0) * self.bs_small) * self.width
+                + len(self.small_i0) * self.bs_small)
+
+    @property
+    def padded_slots(self) -> int:
+        return self.lanes_tiled * self.width
 
     @property
     def tiles(self) -> int:
@@ -1084,10 +1096,10 @@ class ReplayEngine:
         stage = self.profiler.stage
         for s, width in self._window_plan(t):
             e = min(s + width, t)
-            with stage("encode", width=width) as enc:
+            with stage("encode") as enc:
                 packed, side = wire.pack_window(type_ids, cols, s, e, width, bs)
                 ord_base = base + np.int32(t_base + s)
-            with stage("h2d", width=width) as h2d:
+            with stage("h2d") as h2d:
                 window = self._device_window(packed, side, ord_base)
             self.stats["pack_s"] += enc.seconds
             self.stats["h2d_s"] += h2d.seconds
@@ -1746,13 +1758,13 @@ class ReplayEngine:
 
         # two chained dispatches (big tiles, then small); per-lane order holds
         # because a lane only ever migrates big→small as the prefix shrinks
-        rows_before = self.stats["rows_fetched"]
+        rows_fetched = self._rows_fetched(resident, plan.width,
+                                          plan.lanes_tiled)
+        self.stats["rows_fetched"] += rows_fetched
         for bs, k_n, k_cap, i0s_d, tbs_d in work:
             self.stats["windows"] += k_n
             self.profiler.count_windows(k_n)
             fold = self._resident_program(key, plan.width, bs, k_cap)
-            self.stats["rows_fetched"] += self._rows_fetched(
-                resident, plan.width, k_n * bs)
             sig = self._resident_signature(resident, key, plan.width, bs,
                                            k_cap)
             # a fresh signature means this dispatch pays the XLA compile
@@ -1765,8 +1777,8 @@ class ReplayEngine:
                             i0s_d, tbs_d, np.int32(k_n))
         if umbrella is not None:
             umbrella.set_attribute("gather", self.lane_gather)
-            umbrella.set_attribute(
-                "rows_fetched", self.stats["rows_fetched"] - rows_before)
+            umbrella.set_attribute("rows_fetched", rows_fetched)
+            umbrella.set_attribute("fetched_slots", self._fetched_slots(plan))
         return slab, plan.padded_slots
 
     @property
@@ -1782,6 +1794,13 @@ class ReplayEngine:
         of every side column (before XLA drops a column no handler reads)."""
         return (lanes * _rows_per_lane(width, self.lane_gather)
                 * (1 + len(resident.flat_side)))
+
+    def _fetched_slots(self, plan: "ResidentPlan") -> int:
+        """The slots of ONE array that a plan's fetches ask for: what
+        ``rows_fetched`` is over the arrays fetched, times the slots a row
+        (or slice) holds. Beside ``padded_slots``, the slots folded."""
+        return plan.lanes_tiled * _slots_per_lane(plan.width,
+                                                  self.lane_gather)
 
     @property
     def tile_backend(self) -> str:
@@ -1920,7 +1939,7 @@ class ReplayEngine:
                                  segments=segments,
                                  gather=self.lane_gather) as umbrella:
             pieces: list = []
-            padded = 0
+            padded = fetched = 0
             first_piece = True
             for lo, hi in zip(bounds[:-1], bounds[1:]):
                 if hi <= lo:
@@ -1958,11 +1977,13 @@ class ReplayEngine:
                     {k: v[lanes] for k, v in init_sorted.items()},
                     None if ord_sorted is None else ord_sorted[lanes])
                 padded += pad
+                fetched += self._fetched_slots(self._plan_for(piece))
                 # hold ONLY what the sync pass needs — keeping the piece corpus
                 # itself would pin every piece's wire buffers in HBM at once
                 pieces.append((lanes, slab))  # ...fold dispatched, NOT synced
             umbrella.set_attribute(
                 "rows_fetched", self.stats["rows_fetched"] - rows_before)
+            umbrella.set_attribute("fetched_slots", fetched)
             # one sync pass over every piece — a single packed fetch per piece
             # (every materialized buffer is its own device→host round trip; the
             # old per-piece-per-field np.asarray paid pieces × fields of them),

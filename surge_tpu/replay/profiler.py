@@ -46,6 +46,11 @@ stage, so a captured device profile shows the stages on its own clock beside
 the XLA programs — and on exit adds the span's one measured interval to the
 stage's seconds/count and, for the top-level stages, to the DEBUG-level
 ``surge.replay.profile.*`` timers in :class:`~surge_tpu.metrics.EngineMetrics`.
+Each stage span also says what it cost the host, in ``Span.usage``: the
+operating system's own counters read as the span opens and as it closes
+(:func:`_os_counters`; the keys are in :func:`_spent`): the calling thread's
+on every stage, the whole process's on the outermost (``encode``, ``shard``,
+``h2d``, ``resident``).
 :meth:`ReplayProfiler.record` (an interval measured beforehand, a span dated
 back to it) remains for the resident plane's per-round callers only.
 
@@ -98,6 +103,41 @@ _STAGE_TIMERS = {
 #: where a profiler without a tracer opens its stage spans: real spans
 #: (nesting and the measured interval need them), exported nowhere
 _UNEXPORTED = NoopTracer()
+
+
+def _os_counters(process: bool):
+    """What the operating system has charged the calling thread so far and,
+    with ``process``, the whole process: ``(getrusage(RUSAGE_THREAD),
+    getrusage(RUSAGE_SELF) or None)``, a system call each and nothing else.
+    None on a host without ``resource`` or without ``RUSAGE_THREAD`` (one
+    that is not Linux)."""
+    try:
+        from resource import RUSAGE_SELF, RUSAGE_THREAD, getrusage
+    except ImportError:
+        return None
+    return getrusage(RUSAGE_THREAD), getrusage(RUSAGE_SELF) if process else None
+
+
+def _spent(before, after) -> dict:
+    """A stage's ``Span.usage``: the differences of two :func:`_os_counters`
+    readings. Of the calling thread: ``user_s`` and ``sys_s``, its CPU seconds
+    in user code and in the kernel (a first-touch page fault is kernel time);
+    ``minflt`` and ``majflt``, its minor and major page faults; ``nivcsw``,
+    the times it was taken off its core against its will. Where the readings
+    hold the whole process's too (the runtime's transfer threads, a mesh's
+    upload threads): ``proc_cpu_s``, user and kernel CPU seconds together,
+    and ``proc_minflt``. An umbrella's figures include its children's."""
+    (t0, p0), (t1, p1) = before, after
+    usage = {"user_s": t1.ru_utime - t0.ru_utime,
+             "sys_s": t1.ru_stime - t0.ru_stime,
+             "minflt": t1.ru_minflt - t0.ru_minflt,
+             "majflt": t1.ru_majflt - t0.ru_majflt,
+             "nivcsw": t1.ru_nivcsw - t0.ru_nivcsw}
+    if p0 is not None:
+        usage["proc_cpu_s"] = ((p1.ru_utime + p1.ru_stime)
+                               - (p0.ru_utime + p0.ru_stime))
+        usage["proc_minflt"] = p1.ru_minflt - p0.ru_minflt
+    return usage
 
 
 def _trace_annotation(name: str, **counts):
@@ -205,16 +245,33 @@ class ReplayProfiler:
         new root. A ``jax.profiler.TraceAnnotation`` of the same name with the
         same ``counts`` puts it on a captured device profile's clock. The
         span is yielded, for counts known only later (``set_attribute``) and,
-        once closed, its ``seconds``. It finishes and is accounted even when
+        once closed, its ``seconds`` and its ``usage`` (what the interval
+        cost the host: :func:`_spent`; the process's figures on a stage that
+        no other stage encloses; empty where the host has no such
+        counters). It finishes, with its usage, and is accounted even when
         the block raises — a failing compile/fetch is exactly the pass an
         operator profiles."""
         tracer = self.tracer if self.tracer is not None else _UNEXPORTED
+        enclosing = active_span()
         span = tracer.start_span(f"replay.{name}",
-                                 parent=active_span() or follows)
+                                 parent=enclosing or follows)
         span.attributes.update(counts)
+        # a stage inside another reads the thread's counters alone: the
+        # process's are on the outermost stage (a reading costs 6 us on the
+        # chip's host: PERF.md, PR 35)
+        outermost = (enclosing is None
+                     or not enclosing.name.startswith("replay."))
         try:
-            with span, _trace_annotation(span.name, **counts):
-                yield span
+            with span:
+                before = _os_counters(outermost)
+                try:
+                    with _trace_annotation(span.name, **counts):
+                        yield span
+                finally:
+                    # while the span is open: an exporter writes it as it ends
+                    if before is not None:
+                        span.usage.update(
+                            _spent(before, _os_counters(outermost)))
         finally:
             self._account(name, span.seconds)
 

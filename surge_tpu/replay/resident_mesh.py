@@ -37,7 +37,6 @@ from surge_tpu.replay.engine import (
     _make_tile,
     _put_pieces,
     _round_up,
-    _rows_per_lane,
     _side_nbytes,
     _wire_nbytes,
 )
@@ -437,13 +436,12 @@ def _dispatch_sharded(engine, sharded: ShardedResident,
 
     # each granularity runs its OWN program: small tiles sliced bs-wide
     # would overlap/clamp and re-fold the same lanes' windows
-    rows_fetched = 0
+    rows_fetched = sum(
+        engine._rows_fetched(sharded, sharded.width, p.lanes_tiled)
+        for p in plans)
     for bs, k_cap, tiles, i0s_d, tbs_d, kn_d in work:
         engine.stats["windows"] += tiles
         engine.profiler.count_windows(tiles)
-        rows_fetched += (tiles * bs
-                         * _rows_per_lane(sharded.width, engine.lane_gather)
-                         * (1 + len(sharded.flat_side)))
         fold = _sharded_program(engine, key, sharded.width, bs, k_cap)
         sig = ("resident-sharded", key, sharded.width, bs, k_cap, b_pad,
                int(sharded.flat_wire.shape[0]))
@@ -457,6 +455,8 @@ def _dispatch_sharded(engine, sharded: ShardedResident,
     engine.stats["rows_fetched"] += rows_fetched
     umbrella.set_attribute("gather", engine.lane_gather)
     umbrella.set_attribute("rows_fetched", rows_fetched)
+    umbrella.set_attribute("fetched_slots",
+                           sum(engine._fetched_slots(p) for p in plans))
     return slab_dev
 
 

@@ -126,6 +126,12 @@ class Span:
     attributes: Dict[str, object] = field(default_factory=dict)
     events: List[tuple] = field(default_factory=list)
     status: str = "ok"  # "ok" | "error"
+    #: what the span cost the host by the operating system's own counters
+    #: (``ReplayProfiler.stage`` fills it: CPU seconds, page faults,
+    #: preemptions). Measurements, not counts of work: two runs of the same
+    #: work carry equal ``attributes`` and different ``usage``. Empty unless a
+    #: stage filled it.
+    usage: Dict[str, float] = field(default_factory=dict)
     _tracer: Optional["Tracer"] = field(default=None, repr=False)
     _cv_token: Optional[object] = field(default=None, repr=False, compare=False)
 
@@ -328,8 +334,9 @@ def default_tracer() -> InMemoryTracer:
 
 
 def span_record(span: Span) -> dict:
-    """One finished span as the JSON object the JSONL stream carries."""
-    return {
+    """One finished span as the JSON object the JSONL stream carries; the
+    key ``usage`` only where the span has any."""
+    record = {
         "name": span.name,
         "trace_id": span.context.trace_id,
         "span_id": span.context.span_id,
@@ -342,6 +349,9 @@ def span_record(span: Span) -> dict:
         "events": [{"time": t, "name": n, "attributes": a}
                    for t, n, a in span.events],
     }
+    if span.usage:
+        record["usage"] = span.usage
+    return record
 
 
 class JsonlSpanExporter:

@@ -256,6 +256,10 @@ def test_streamed_fold_on_rows_matches_the_scalar_fold(rows_on_cpu, name, start,
     assert umbrella.attributes["gather"] == "rows"
     assert umbrella.attributes["rows_fetched"] == (
         engine.stats["rows_fetched"] - rows_before) > 0
+    # of ONE array, in slots: the pieces' rows over the arrays fetched, a
+    # row 128 slots
+    assert umbrella.attributes["fetched_slots"] == (
+        umbrella.attributes["rows_fetched"] * 128 // (1 + len(wire.side)))
     assert got.num_events == sum(len(log) for log in tails)
     assert len(got.states) == len(module.make_registry().state.field_names)
     assert_columns_are(got.states,

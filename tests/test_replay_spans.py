@@ -21,7 +21,8 @@ from surge_tpu.replay.engine import (COLD_PATH_JIT_NAMES, ReplayEngine,
                                      ResidentWire)
 from surge_tpu.replay.profiler import ReplayProfiler
 from surge_tpu.tracing import (DEFAULT_RING_CAPACITY, InMemoryTracer,
-                               JsonlSpanExporter, active_span, default_tracer)
+                               JsonlSpanExporter, Tracer, active_span,
+                               default_tracer)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -140,9 +141,9 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
         "copied_bytes": h2d.attributes["copied_bytes"]}
     assert put.attributes == {"put_bytes": 1 << 16, "pieces": 1}
     assert sorted(resident_span.attributes) == [
-        "aggregates", "events", "gather", "padded_slots", "rounds",
-        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small",
-        "width", "width_cap"]
+        "aggregates", "events", "fetched_slots", "gather", "padded_slots",
+        "rounds", "rows_fetched", "scan_steps", "slots_small", "tiles",
+        "tiles_small", "width", "width_cap"]
     assert resident_span.attributes["aggregates"] == 48
     assert resident_span.attributes["events"] == n
     assert resident_span.attributes["padded_slots"] == res.padded_events
@@ -223,9 +224,9 @@ def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold):
         "copied_bytes": h2d.attributes["copied_bytes"]}
     assert put.attributes == {"put_bytes": 4 * (1 << 16), "pieces": 4}
     assert sorted(resident_span.attributes) == [
-        "aggregates", "devices", "events", "gather", "padded_slots", "rounds",
-        "rows_fetched", "scan_steps", "slots_small", "tiles", "tiles_small",
-        "width", "width_cap"]
+        "aggregates", "devices", "events", "fetched_slots", "gather",
+        "padded_slots", "rounds", "rows_fetched", "scan_steps", "slots_small",
+        "tiles", "tiles_small", "width", "width_cap"]
     a = resident_span.attributes
     assert (a["aggregates"], a["events"], a["devices"]) == (48, n, 4)
     assert (a["width"], a["width_cap"]) == (sharded.width, 16) == (8, 16)
@@ -243,8 +244,9 @@ def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold):
 @pytest.mark.parametrize("gather, per_lane", [("slices", 1), ("rows", 2)])
 def test_the_fold_spans_say_how_the_lane_rows_were_fetched(monkeypatch, gather,
                                                            per_lane):
-    """``gather`` and ``rows_fetched`` on ``replay.resident``: a window of 16
-    events starting anywhere needs two aligned rows of 128, or one slice; the
+    """``gather``, ``rows_fetched`` and ``fetched_slots`` on
+    ``replay.resident``: a window of up to 16 events starting anywhere needs
+    two aligned rows of 128 slots, or one slice of its own width; the
     counter's wire is one array. Every fold of a corpus fetches the same."""
     monkeypatch.setattr(engine_module, "_lane_gather", lambda: gather)
     engine = make_engine()
@@ -258,11 +260,15 @@ def test_the_fold_spans_say_how_the_lane_rows_were_fetched(monkeypatch, gather,
     fold = one(spans, "replay.resident")
     assert fold.attributes["gather"] == gather
     assert fold.attributes["rows_fetched"] == want > 0
+    assert fold.attributes["fetched_slots"] == want * (
+        128 if gather == "rows" else plan.width)
     assert engine.stats["rows_fetched"] == want
     since = time.monotonic()
     engine.replay_resident(resident)
     again = one(ring_since(since), "replay.resident")
     assert again.attributes["rows_fetched"] == want
+    assert again.attributes["fetched_slots"] == (
+        fold.attributes["fetched_slots"])
     assert engine.stats["rows_fetched"] == 2 * want
 
 
@@ -301,6 +307,9 @@ def test_the_fold_span_says_which_width_the_plan_chose(monkeypatch, gather,
     assert a["padded_slots"] == res.padded_events == 256 * rounds * width
     assert a["padded_slots"] / a["events"] < 2
     assert a["rows_fetched"] == 256 * (2 if gather == "rows" else 13)
+    # one array: two rows of 128 slots a lane, or 13 slices of 8
+    assert a["fetched_slots"] == 256 * (2 * 128 if gather == "rows"
+                                        else 13 * 8)
 
 
 @pytest.mark.parametrize("grouped, block, blocks, lanes_from", [
@@ -396,6 +405,8 @@ def test_a_callers_open_span_stays_the_parent_of_all_three():
             assert s.parent_id == root.context.span_id, name
             assert root.start_mono <= s.start_mono
             assert s.end_mono <= root.end_mono
+            # no stage encloses it: the process's figures are on it
+            assert "proc_cpu_s" in s.usage, name
     # fold_resident_slab is the same umbrella, without the pull
     folds = [s for s in spans if s.name == "replay.resident"]
     assert len(folds) == 2 and padded == folds[1].attributes["padded_slots"]
@@ -530,6 +541,148 @@ def test_a_stage_whose_body_raises_still_finishes_its_span():
     with bare.stage("encode") as span:
         pass
     assert bare.stage_n["encode"] == 1 and span.end_mono is not None
+
+
+THREAD_KEYS = ["majflt", "minflt", "nivcsw", "sys_s", "user_s"]
+USAGE_KEYS = sorted([*THREAD_KEYS, "proc_cpu_s", "proc_minflt"])
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one-chip", "sharded"])
+def test_every_stage_span_says_what_it_cost_the_host(sharded):
+    """``Span.usage``: the operating system's counters over the span's
+    interval, on every ``replay.*`` span of a rebuild: the calling thread's
+    on every stage, the whole process's too on a stage no other encloses.
+    Measurements: none of them is an attribute, and an umbrella's include
+    its children's."""
+    import jax
+
+    mesh = (jax.sharding.Mesh(np.array(jax.devices()[:4]), ("data",))
+            if sharded else None)
+    engine = make_engine(mesh=mesh)
+    since = time.monotonic()
+    wire = engine.pack_resident(make_events())
+    if sharded:
+        engine.replay_resident_sharded(engine.prepare_resident_sharded(wire))
+    else:
+        engine.replay_resident(engine.upload_resident(wire))
+    spans = ring_since(since)
+    names = {s.name for s in spans}
+    assert names >= {*UMBRELLAS, *ENCODE_CHILDREN, *H2D_CHILDREN,
+                     *FETCH_CHILDREN, "replay.plan", "replay.fetch"}
+    assert ("replay.shard" in names) == sharded
+    outermost = {"replay.encode", "replay.shard", "replay.h2d",
+                 "replay.resident"}
+    for span in spans:
+        assert span.name.startswith("replay.")
+        assert sorted(span.usage) == (
+            USAGE_KEYS if span.name in outermost else THREAD_KEYS), span.name
+        assert all(v >= 0 for v in span.usage.values()), (span.name,
+                                                          span.usage)
+        assert not set(span.attributes) & set(USAGE_KEYS), span.name
+    for umbrella in (s for s in spans if s.name in (*UMBRELLAS,
+                                                    "replay.fetch")):
+        kids = [s for s in spans if s.parent_id == umbrella.context.span_id
+                and s.end_mono <= umbrella.end_mono]  # not what follows it
+        assert kids, umbrella.name
+        for key in ("minflt", "nivcsw"):
+            assert umbrella.usage[key] >= sum(k.usage[key] for k in kids)
+        for key in ("user_s", "sys_s"):
+            assert umbrella.usage[key] >= sum(
+                k.usage[key] for k in kids) - 1e-6, (umbrella.name, key)
+        if umbrella.name in outermost:  # the process holds the thread
+            u = umbrella.usage
+            assert u["proc_minflt"] >= u["minflt"]
+            assert u["proc_cpu_s"] >= u["user_s"] + u["sys_s"] - 0.005
+
+
+def test_a_stage_that_sleeps_costs_no_cpu_and_one_that_spins_costs_its_seconds():
+    prof = ReplayProfiler.counters(tracer=InMemoryTracer())
+    with prof.stage("fetch.wait") as asleep:
+        time.sleep(0.05)
+    cpu = asleep.usage["user_s"] + asleep.usage["sys_s"]
+    assert asleep.seconds >= 0.05 and cpu < 0.02
+    with prof.stage("encode.words") as spinning:
+        until = time.thread_time() + 0.05  # this thread's own CPU clock
+        while time.thread_time() < until:
+            pass
+    cpu = spinning.usage["user_s"] + spinning.usage["sys_s"]
+    assert 0.04 <= cpu <= spinning.seconds + 0.005
+    assert spinning.usage["proc_cpu_s"] >= cpu - 0.005
+
+
+def test_a_stage_that_touches_fresh_pages_reads_their_faults():
+    prof = ReplayProfiler.counters(tracer=InMemoryTracer())
+    with prof.stage("encode.words") as fresh:
+        buf = np.empty(32 << 20, dtype=np.uint8)  # mapped, not yet touched
+        buf[::512] = 1
+    with prof.stage("encode.words") as again:
+        buf[::512] = 2
+    assert fresh.usage["minflt"] > 0
+    assert fresh.usage["proc_minflt"] >= fresh.usage["minflt"]
+    assert again.usage["minflt"] < fresh.usage["minflt"]
+    assert fresh.attributes == again.attributes == {}
+
+
+@pytest.mark.parametrize("missing", ["resource", "RUSAGE_THREAD"])
+def test_a_host_without_the_counters_leaves_usage_empty(monkeypatch, missing):
+    import resource
+    import sys
+
+    if missing == "resource":
+        monkeypatch.setitem(sys.modules, "resource", None)  # unimportable
+    else:
+        monkeypatch.delattr(resource, "RUSAGE_THREAD")
+    tracer = InMemoryTracer()
+    prof = ReplayProfiler.counters(tracer=tracer)
+    with prof.stage("encode", events=3) as outer:
+        with prof.stage("encode.words"):
+            pass
+    assert [s.usage for s in tracer.finished] == [{}, {}]
+    assert outer.attributes == {"events": 3} and outer.end_mono is not None
+    assert prof.stage_n["encode"] == prof.stage_n["encode.words"] == 1
+
+
+def test_a_stage_whose_body_raises_keeps_its_usage():
+    tracer = InMemoryTracer()
+    prof = ReplayProfiler.counters(tracer=tracer)
+    with pytest.raises(RuntimeError):
+        with prof.stage("fetch", aggregates=3):
+            with prof.stage("fetch.wait"):
+                np.empty(8 << 20, dtype=np.uint8)[::512] = 1
+                raise RuntimeError("device lost")
+    wait, fetch = tracer.finished
+    assert wait.status == fetch.status == "error"
+    assert sorted(fetch.usage) == USAGE_KEYS
+    assert sorted(wait.usage) == THREAD_KEYS  # inside fetch
+    assert fetch.usage["minflt"] >= wait.usage["minflt"] > 0
+
+
+def test_usage_is_written_out_only_where_a_span_has_any(tmp_path):
+    """``dump_to`` and the JSONL exporter write ``usage`` beside
+    ``attributes`` for a stage's span (the exporter as the stage closes) and
+    no such key for a span that has none."""
+    streamed = tmp_path / "streamed.jsonl"
+    ring = InMemoryTracer(capacity=8)
+    with JsonlSpanExporter(str(streamed)) as exporter:
+        streaming = Tracer(exporter=exporter)
+        with ReplayProfiler.counters(tracer=streaming).stage("encode",
+                                                             events=7):
+            pass
+    with ReplayProfiler.counters(tracer=ring).stage("encode", events=7):
+        pass
+    ring.start_span("caller.span").finish()  # no stage: no usage
+    dumped = tmp_path / "dumped.jsonl"
+    assert ring.dump_to(str(dumped)) == 2
+    stage, plain = (json.loads(line)
+                    for line in dumped.read_text().splitlines())
+    assert stage["name"] == "replay.encode"
+    assert stage["attributes"] == {"events": 7}
+    assert sorted(stage["usage"]) == USAGE_KEYS
+    assert plain["name"] == "caller.span" and "usage" not in plain
+    (line,) = streamed.read_text().splitlines()
+    assert sorted(json.loads(line)["usage"]) == USAGE_KEYS
+    assert json.loads(line)["attributes"] == {"events": 7}
 
 
 def test_cold_path_jit_names_are_pinned():
