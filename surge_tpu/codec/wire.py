@@ -1,14 +1,16 @@
-"""Bit-packed host→device wire format for event windows.
+"""Bit-packed wire format for event windows.
 
-Host→device transfer is the replay engine's bottleneck (SURVEY.md §7 hard-part 2: a
-100M-event log at 4 int32 columns is 1.6 GB on the wire; the fold itself is a few int
-ops per event). This module shrinks the wire to the information actually present:
+The format dates from a host→device link of 25 MB/s, where the transfer was the
+replay engine's bottleneck (SURVEY.md §7 hard-part 2: a 100M-event log at 4
+int32 columns is 1.6 GB on the wire; the fold itself is a few int ops per
+event). It shrinks what the fold reads to the information actually present,
+which is still what the device buffers hold and what a saved wire stores:
 
 - The **type discriminant** and every union column with a declared ``FieldSpec.bits``
   width are packed into one little-endian word of ``ceil(total_bits/8)`` bytes per
   event (``packed``: uint8 ``[T, B, nbytes]``). The Counter fixture's events — type
   (3 bits incl. padding sentinel) + increment_by (2) + decrement_by (2) — fit in
-  **one byte per event**, 16× less wire than the naive int32 columns.
+  **one byte per event**, 16× less than the naive int32 columns.
 - Columns without ``bits`` ride as full-width **side** arrays ``[T, B]`` (floats,
   wide ints).
 - **Derived columns** never cross the wire at all: a data producer that knows a column
@@ -19,8 +21,16 @@ ops per event). This module shrinks the wire to the information actually present
   stream), so bulk replay of framework-written logs always qualifies; object-encoded
   test logs keep the explicit column.
 
-Packing is pure vectorized NumPy; unpacking is jitted JAX that the fold program fuses
-with the scan, so decode costs no extra HBM round trip.
+On the attached chip the link is no longer the bottleneck (an int32 ``[N]``
+column goes up at 11-12.6 GB/s, PERF.md) and the host's word pass was: so
+*where* the word is built follows the input. The windowed fold and a wire
+that must exist on the host (saved, cut into sub-wires, or made from int64,
+strided or non-integer columns) pack in vectorized NumPy
+(:meth:`WireFormat.pack_window`, :meth:`WireFormat.pack_blocks`); the resident
+upload of columns that can go up as they lie builds the same word on the
+device (``replay/engine.py:mk_word``, by :meth:`WireFormat._pack_words`'s own
+expression). Unpacking is jitted JAX that the fold program fuses with the
+scan, so decode costs no extra HBM round trip.
 """
 
 from __future__ import annotations
@@ -58,6 +68,10 @@ def _outside(a: np.ndarray, top: int) -> bool:
     no mask the size of ``a``."""
     if not a.size:
         return False
+    if a.dtype.kind == "i" and top >> (8 * a.dtype.itemsize - 1):
+        # a dtype so narrow that a negative, read as unsigned, stays under
+        # ``top``: nothing in it reaches past ``top``, a negative is all
+        return bool(a.min() < 0)
     if a.dtype.kind in "iu":
         return int(_as_unsigned(a).max()) > top
     return bool(a.min() < 0 or a.max() > top)
@@ -112,6 +126,17 @@ class _PackedField:
     @property
     def mask(self) -> int:
         return (1 << self.bits) - 1
+
+
+def overflow_error(pf: _PackedField, whole: np.ndarray) -> ValueError:
+    """What a packed column below 0 or past its declared width raises, from
+    the whole column, wherever the word is built (:meth:`WireFormat.
+    _pack_words` on the host, ``replay/engine.py:mk_word`` on the device)."""
+    whole = np.asarray(whole)
+    return ValueError(
+        f"column {pf.name!r} overflows its declared {pf.bits}-bit "
+        f"wire width (max value {int(whole.max())}, "
+        f"min {int(whole.min())})")
 
 
 class WireFormat:
@@ -240,11 +265,7 @@ class WireFormat:
         for pf in self.packed_fields:
             col = np.asarray(cols[pf.name])
             if _outside(col, pf.mask):
-                whole = np.asarray((report or cols)[pf.name])
-                raise ValueError(
-                    f"column {pf.name!r} overflows its declared {pf.bits}-bit "
-                    f"wire width (max value {int(whole.max())}, "
-                    f"min {int(whole.min())})")
+                raise overflow_error(pf, (report or cols)[pf.name])
             bits = col.astype(self.word_dtype)
             bits <<= np.asarray(pf.shift, dtype=self.word_dtype)
             word |= bits

@@ -28,7 +28,7 @@ from surge_tpu.codec.tensor import (
     columnar_to_batch,
     encode_states,
 )
-from surge_tpu.codec.wire import WireFormat, grouped_lengths
+from surge_tpu.codec.wire import WireFormat, grouped_lengths, overflow_error
 from surge_tpu.config import Config, default_config
 from surge_tpu.engine.model import ReplaySpec, StateTree
 from surge_tpu.replay.profiler import ReplayProfiler
@@ -38,7 +38,8 @@ from surge_tpu.tracing import SpanContext, default_tracer
 #: XLA names a program ``jit_<function>``, and the benchmark's trace reduction
 #: maps programs to layers by those names (benchmarks/programs/cold-fold.json):
 #: renaming one unmaps its program. Held by tests/test_replay_spans.py.
-COLD_PATH_JIT_NAMES = ("fold", "finalize", "mk", "mk_bucket", "mk_wire")
+COLD_PATH_JIT_NAMES = ("fold", "finalize", "mk", "mk_bucket", "mk_wire",
+                       "mk_word")
 
 #: the checkout's own persistent compile cache (listed in .gitignore). The
 #: path is part of every cache key, so it is fixed: never a temp, pid or
@@ -508,9 +509,11 @@ def _side_nbytes(side: Mapping[str, Any]) -> int:
     return int(sum(v.nbytes for v in side.values()))
 
 
-def _wire_nbytes(packed, side: Mapping[str, Any]) -> int:
-    """Bytes of a wire's event buffers: the packed rows and the side columns."""
-    return int(packed.nbytes) + _side_nbytes(side)
+def _wire_nbytes(w: "ResidentWire") -> int:
+    """Bytes of a wire's event buffers: the packed rows (by their shape: a
+    packed buffer not yet built is not built for this) and the side columns."""
+    rows, nbytes = w.packed_shape
+    return rows * nbytes + _side_nbytes(w.side)
 
 
 def _bucket_len(n: int) -> int:
@@ -603,6 +606,115 @@ def _put_pieces(pieces: list, bucket: int):
     return out
 
 
+def _make_mk_word(wire: WireFormat):
+    """The jitted :func:`mk_word` of one wire layout: the device's build of
+    the packed word, one compile a (bucket shape, piece shape, source dtypes).
+    """
+    fields, pad_code, nbytes = wire.packed_fields, wire.pad_code, wire.nbytes
+
+    def unsigned(a):
+        # 32 bits wide first, a signed column keeping its sign: a negative of
+        # any width reads past every declared width and every type id
+        wide = a.astype(jnp.int32 if a.dtype.kind == "i" else jnp.uint32)
+        return jax.lax.bitcast_convert_type(wide, jnp.uint32)
+
+    def mk_word(bucket, flags, type_ids, cols, at):
+        """One piece of the word's sources built into the packed word, by
+        :meth:`WireFormat._pack_words`'s own expression, and placed at row
+        ``at`` of its donated ``[rows, nbytes]`` bucket. ``flags`` gains, a
+        packed column, whether any element of this piece lies outside its
+        declared width."""
+        word = jnp.minimum(unsigned(type_ids), np.uint32(pad_code))
+        outside = []
+        for pf, col in zip(fields, cols):
+            bits = unsigned(col)
+            outside.append(jnp.any(bits > np.uint32(pf.mask)))
+            word = word | (bits << np.uint32(pf.shift))
+        piece = jnp.stack(
+            [((word >> np.uint32(8 * k)) & np.uint32(0xFF)).astype(jnp.uint8)
+             for k in range(nbytes)], axis=1)
+        if outside:
+            flags = flags | jnp.stack(outside)
+        return jax.lax.dynamic_update_slice(bucket, piece, (at, 0)), flags
+
+    return jax.jit(mk_word, donate_argnums=0)
+
+
+def _put_word(place, sources: list, bucket: int, nbytes: int):
+    """:func:`_put_pieces` for a word the device builds: ``sources`` holds the
+    pieces (:func:`_bucket_pieces`) of the type ids and of each packed
+    column. Piece ``i`` of each is put and ``place`` (a :func:`mk_word`)
+    builds their word at its row offset into a bucket of device zeros, which
+    is every row past the last piece; the zero rows of a padded last piece
+    build word 0. The buffer is element for element ``np.pad`` of the host's
+    packed one. Returns it and the columns' out-of-range flags."""
+    piece_rows = sources[0][0].shape[0]
+    out = _zero_bucket(bucket, (nbytes,), np.uint8)
+    # put, not computed: an eager jnp.zeros is a device program of its own
+    flags = jax.device_put(np.zeros((len(sources) - 1,), np.bool_))
+    ahead: list = []
+    for i, host in enumerate(zip(*sources)):
+        if len(ahead) == _PIECES_AHEAD:
+            jax.block_until_ready(ahead.pop(0))
+        type_ids, *cols = piece = [jax.device_put(h) for h in host]
+        out, flags = place(out, flags, type_ids, cols,
+                           np.int32(i * piece_rows))
+        ahead.append(piece)
+    return out, flags
+
+
+def _put_wire(host: list, bucket: int, wire: WireFormat, place):
+    """The device's half of one wire's (or one shard's) upload: ``host`` is
+    the pieces of its arrays, the word's first. ``place`` is None for a
+    packed buffer, which goes up as it lies; else the word's arrays are its
+    sources (the type ids and ``wire``'s packed columns) and ``place`` the
+    :func:`mk_word` that builds it. Returns ``(flat_wire, side buffers,
+    flags or None)``."""
+    if place is None:
+        n_word, flags = 1, None
+        flat_wire = _put_pieces(host[0][0], bucket)
+    else:
+        n_word = 1 + len(wire.packed_fields)
+        flat_wire, flags = _put_word(
+            place, [ps for ps, _ in host[:n_word]], bucket, wire.nbytes)
+    return flat_wire, [_put_pieces(ps, bucket) for ps, _ in host[n_word:]], flags
+
+
+def _raise_outside(wire: WireFormat, flags, words: "WordSources") -> None:
+    """Raise what the host's word build raises for the first packed column
+    whose device flag is set: the same message, its max and min from the
+    host column the wire still holds."""
+    for pf, outside in zip(wire.packed_fields, np.asarray(flags)):
+        if outside:
+            raise overflow_error(pf, words.cols[pf.name])
+
+
+def _goes_up_as_it_lies(a) -> bool:
+    """Whether a source column of the word can be put on the device as it
+    is: a C-contiguous ``[N]`` integer array of at most four bytes an
+    element (jax without x64 would narrow an int64 silently)."""
+    return (isinstance(a, np.ndarray) and a.ndim == 1
+            and a.dtype.kind in "iu" and a.dtype.itemsize <= 4
+            and a.dtype.isnative and a.flags.c_contiguous)
+
+
+@dataclass
+class WordSources:
+    """What a wire's packed word is built from where the device builds it
+    (:func:`mk_word`): the packed stream's type ids and its packed columns
+    (``WireFormat.packed_fields``, in that order), each ``[N]`` and
+    :func:`_goes_up_as_it_lies`, possibly the caller's own arrays. ``pack``
+    is the host's build of the same word, for whoever reads
+    :attr:`ResidentWire.packed`."""
+
+    type_ids: np.ndarray
+    cols: dict
+    pack: Callable[[], np.ndarray]
+
+    def arrays(self) -> tuple:
+        return (self.type_ids, *self.cols.values())
+
+
 @dataclass
 class ResidentWire:
     """The host/disk wire form of a resident corpus (pure numpy, mmap-able).
@@ -612,17 +724,30 @@ class ResidentWire:
     makes the pack a one-time build cost: every later cold start mmaps the
     wire bytes and streams them straight onto the device.
 
-    ``packed`` carries ``guard`` zero rows past its ``num_events``. A side
-    column needs none: it holds between ``num_events`` rows and the packed
-    buffer's (:meth:`ReplayEngine.check_wire`), and the upload supplies the
-    rest as device zeros. ``pack_resident`` makes it ``[N]``; a wire saved by
-    an older build holds ``[N + guard]`` and still loads. A side column may
-    be the caller's own array (:meth:`ReplayEngine.pack_resident`): it must
-    not be written between ``pack_resident`` and the return of
-    ``upload_resident``."""
+    ``packed`` carries ``guard`` zero rows past its ``num_events``. A wire
+    that carries ``words`` (the word's source columns: ``pack_resident``
+    hands them over where they can go up as they lie) holds no packed buffer
+    until someone reads ``packed``: the first read builds it on the host,
+    byte for byte what ``pack_resident`` would have built (inside a
+    ``replay.encode.words`` stage; a column outside its declared width
+    raises there), and keeps it. ``packed_shape`` is its shape without
+    building it. The default upload of such a wire never reads it: the
+    device builds the word from the sources.
+
+    A side column needs no guard rows: it holds between ``num_events`` rows
+    and the packed buffer's (:meth:`ReplayEngine.check_wire`), and the upload
+    supplies the rest as device zeros. ``pack_resident`` makes it ``[N]``; a
+    wire saved by an older build holds ``[N + guard]`` and still loads.
+
+    Ownership: the side columns, and the type ids and packed columns in
+    ``words``, may be the caller's own arrays
+    (:meth:`ReplayEngine.pack_resident`): they must not be written between
+    ``pack_resident`` and the return of ``upload_resident`` /
+    ``prepare_resident_sharded``."""
 
     derived_key: dict
-    packed: np.ndarray  # u8 [N+guard, nbytes]
+    #: u8 [N+guard, nbytes]; None (with ``words``): built on first read
+    packed: Optional[np.ndarray] = dc_field(repr=False)
     side: dict  # {name: np [N]}, possibly the caller's arrays
     starts: np.ndarray  # i32 [B] (length-sorted order)
     lengths: np.ndarray  # i32 [B]
@@ -636,6 +761,20 @@ class ResidentWire:
     #: context of the ``replay.encode`` span that packed it, so the upload
     #: continues that trace; not saved (a loaded wire starts a new trace)
     trace_ctx: Optional[SpanContext] = None
+    #: the word's sources, where the device is to build it; not saved
+    words: Optional[WordSources] = None
+
+    @property
+    def host_packed(self) -> bool:
+        """Whether the packed buffer exists on the host (given or built)."""
+        return self._packed is not None
+
+    @property
+    def packed_shape(self) -> tuple:
+        """``packed.shape``, known without building the buffer."""
+        if self._packed is not None:
+            return self._packed.shape
+        return self.num_events + self.guard, int(self.layout["nbytes"])
 
     def save(self, root: str) -> None:
         import json
@@ -679,6 +818,22 @@ class ResidentWire:
             perm=np.asarray(mm("perm.npy")) if meta["has_perm"] else None,
             guard=int(meta["guard"]), num_events=int(meta["num_events"]),
             layout=meta.get("layout"))
+
+
+def _read_packed(self: ResidentWire) -> np.ndarray:
+    if self._packed is None:
+        self._packed = self.words.pack()
+    return self._packed
+
+
+def _write_packed(self: ResidentWire, packed: Optional[np.ndarray]) -> None:
+    self._packed = packed
+
+
+# a property over the dataclass's own field: ``ResidentWire(packed=...)``,
+# ``dataclasses.replace`` and ``wire.packed = ...`` all keep working, and a
+# read of a buffer not yet built builds it
+ResidentWire.packed = property(_read_packed, _write_packed)
 
 
 @dataclass
@@ -793,6 +948,8 @@ class ReplayEngine:
         self._resident_folds: dict[frozenset, Any] = {}
         # on-device fresh init-slab builders per b_pad (zero host transfers)
         self._slab_programs: dict = {}
+        # the device's word builds (mk_word), one per wire layout
+        self._word_programs: dict = {}
         # the state-pull finalize programs, one per set of full-width columns,
         # built once per engine — jax.jit's own shape cache handles differing
         # batch sizes (streamed pieces are rebuilt per call; a per-corpus
@@ -1135,35 +1292,55 @@ class ReplayEngine:
         length argsort remains, and it is skipped where every log is as long
         as the next.
 
-        The pack reads each input column once and writes the wire once, in
-        cache-sized blocks (``codec/wire.py:FLAT_PACK_BLOCK`` events; nothing
-        is kept from one call to the next). Its four stages:
+        Where the word's sources (the packed stream's type ids and every
+        packed column) can go up as they lie (:func:`_goes_up_as_it_lies`:
+        C-contiguous integer arrays of at most four bytes an element), the
+        host packs nothing: the wire carries them (:class:`WordSources`) and
+        the upload has the device build the word (:func:`mk_word`). Any other
+        input (an int64 or strided column from a decoder, a float, an object
+        array) is packed here, each input column read once and the wire
+        written once, in cache-sized blocks
+        (``codec/wire.py:FLAT_PACK_BLOCK`` events; nothing is kept from one
+        call to the next). The four stages:
 
         - ``encode.lanes``: :func:`grouped_lengths`, the blocked neighbour
           compare and the lengths from the segment boundaries. Ungrouped input
           (rare: interleaved hand-built columns) falls back to ``bincount``
           and the stable re-sort, whole-column.
-        - ``encode.words``: :meth:`WireFormat.pack_blocks`, the word build per
-          block, stored straight into the ``[N + guard, nbytes]`` buffer.
+        - ``encode.words``: the test of the sources and their hand-over, or
+          :meth:`WireFormat.pack_blocks`, the word build per block, stored
+          straight into the ``[N + guard, nbytes]`` buffer. The same name
+          spans the host build wherever a reader of
+          :attr:`ResidentWire.packed` later asks for it.
         - ``encode.bytes``: :meth:`WireFormat.side_columns`, the side columns
           ``[N]`` in their wire dtypes: a column that already is one is handed
           over as it is, any other is cast into a fresh buffer (the counter
           has none).
         - ``encode.guard``: ``starts``, the lane view under ``perm``, the
           :class:`ResidentWire`. The guard rows are the word buffer's alone,
-          part of what ``encode.words`` allocates; nothing is copied to
+          part of what the word build allocates; nothing is copied to
           append them, and a side column has none (the upload's bucket of
           device zeros is what the fold reads past its last row).
 
-        Ownership: the wire's side columns may be ``colev``'s own arrays.
+        A packed column below 0 or past its declared width raises
+        ``ValueError``: here where the host packs; from
+        :meth:`upload_resident` / :meth:`prepare_resident_sharded`, with the
+        same message and before any corpus exists, where the device does
+        (and from whoever first reads ``packed`` of such a wire).
+        Out-of-range type ids pack as the pad sentinel on both.
+
+        Ownership: the wire's side columns, and the type ids and packed
+        columns it carries for the device, may be ``colev``'s own arrays.
         They must not be written between this call and the return of
-        :meth:`upload_resident`; after it the device holds its own copy.
+        :meth:`upload_resident` / :meth:`prepare_resident_sharded`; after it
+        the device holds its own copy.
 
         The ``replay.encode`` span says which way it went: ``grouped``,
-        ``lanes_from`` (``boundaries`` or ``bincount``), ``blocks`` (how
-        many blocks the word pass ran), ``side_aliased`` (side columns handed
-        over as the caller's arrays) and ``side_copied_bytes`` (bytes cast
-        into fresh buffers)."""
+        ``lanes_from`` (``boundaries`` or ``bincount``), ``words_from``
+        (``device`` or ``host``), ``blocks`` (how many blocks the word pass
+        ran here: 0 where nothing was packed on the host), ``side_aliased``
+        (side columns handed over as the caller's arrays) and
+        ``side_copied_bytes`` (bytes cast into fresh buffers)."""
         stage = self.profiler.stage
         b = colev.num_aggregates
         given = colev.cols  # the caller's arrays, whatever is packed below
@@ -1206,8 +1383,18 @@ class ReplayEngine:
             # sentinel
             guard = max(self.resident_tile_width(), _WIRE_GUARD_MIN)
             with stage("encode.words"):
-                packed, blocks = wire.pack_blocks(to_pack.type_ids,
-                                                  to_pack.cols, guard)
+                type_ids = to_pack.type_ids
+                fields = {pf.name: to_pack.cols[pf.name]
+                          for pf in wire.packed_fields}
+                packed, blocks, words = None, 0, None
+                if all(map(_goes_up_as_it_lies, (type_ids, *fields.values()))):
+                    def pack_on_host() -> np.ndarray:
+                        with stage("encode.words", follows=enc.context):
+                            return wire.pack_blocks(type_ids, fields, guard)[0]
+
+                    words = WordSources(type_ids, fields, pack_on_host)
+                else:
+                    packed, blocks = wire.pack_blocks(type_ids, fields, guard)
             with stage("encode.bytes"):
                 side_flat = wire.side_columns(to_pack.cols)
             with stage("encode.guard"):
@@ -1226,14 +1413,17 @@ class ReplayEngine:
                     side=side_flat, starts=starts_lane.astype(np.int32),
                     lengths=lens_lane.astype(np.int32), perm=perm, guard=guard,
                     num_events=to_pack.num_events,
-                    layout=wire.layout_fingerprint(), trace_ctx=enc.context)
-            enc.set_attribute("wire_bytes", _wire_nbytes(packed, side_flat))
+                    layout=wire.layout_fingerprint(), trace_ctx=enc.context,
+                    words=words)
+            enc.set_attribute("wire_bytes", _wire_nbytes(out))
             enc.set_attribute("side_bytes", _side_nbytes(side_flat))
             copied = [v.nbytes for k, v in side_flat.items()
                       if not np.may_share_memory(v, given[k])]
             enc.set_attribute("side_aliased", len(side_flat) - len(copied))
             enc.set_attribute("side_copied_bytes", sum(copied))
             enc.set_attribute("blocks", blocks)
+            enc.set_attribute("words_from",
+                              "host" if words is None else "device")
             enc.set_attribute("grouped", grouped)
             enc.set_attribute("lanes_from",
                               "boundaries" if grouped else "bincount")
@@ -1253,7 +1443,7 @@ class ReplayEngine:
                 f"wire guard {w.guard} is smaller than the engine's tile width "
                 f"{self.resident_tile_width()}; repack or lower "
                 "surge.replay.time-chunk")
-        rows = w.packed.shape[0]
+        rows, nbytes = w.packed_shape
         if rows < w.num_events + w.guard:
             raise ValueError(
                 f"wire holds {rows} packed rows, fewer than its {w.num_events} "
@@ -1274,9 +1464,9 @@ class ReplayEngine:
                 f"wire layout mismatch: corpus was packed as {w.layout}, "
                 f"engine schema packs {wire.layout_fingerprint()}; "
                 "rebuild the wire with pack_resident")
-        if wire.nbytes != w.packed.shape[1]:  # also guards corrupted buffers
+        if wire.nbytes != nbytes:  # also guards corrupted buffers
             raise ValueError(
-                f"wire layout mismatch: corpus packed {w.packed.shape[1]} "
+                f"wire layout mismatch: corpus packed {nbytes} "
                 f"byte(s)/event but the engine's schema packs {wire.nbytes}; "
                 "rebuild the wire with pack_resident")
         want_sides = {f.name: np.dtype(f.dtype) for f in wire.side_fields}
@@ -1306,25 +1496,40 @@ class ReplayEngine:
         buffer's rows, for single-corpus workloads that warm explicitly
         (bench).
 
-        The wire's side columns may be the caller's arrays
-        (:meth:`pack_resident`): every buffer has landed in a device buffer
-        of its own before this returns, so the caller may write them after.
+        A wire that carries the word's sources (:class:`WordSources`) and
+        no packed buffer yet sends each source column up as a side column
+        goes, and :func:`mk_word` (``jit_mk_word``) builds the word from each
+        piece into the bucket the fold reads: the device buffer is element
+        for element what the host-packed wire's would be. A packed column
+        outside its declared width, which :meth:`pack_resident` raises for
+        where the host packs, raises from here on that path: the device
+        flags it, the flags are read once every buffer is ready, and the
+        ``ValueError`` (the host's message) leaves no corpus behind. A wire
+        whose packed buffer exists (loaded, built for a ``save``, hand-made)
+        goes up as it lies, and ``exact`` reads ``packed``, built on the host
+        if need be.
+
+        The wire's side columns, type ids and packed columns may be the
+        caller's arrays (:meth:`pack_resident`): every buffer has landed in
+        a device buffer of its own before this returns, so the caller may
+        write them after.
 
         Spans: ``h2d.bucket`` is what the host still copies (``starts`` /
         ``lens`` and the padded last pieces: ``copied_bytes``); ``h2d.put``
         is every put and placement through ``block_until_ready`` of the
         wire's buffers (``put_bytes``: the bytes handed to ``device_put``;
         ``pieces``: the puts, 1 an array where the bucket is one piece).
-        ``h2d`` carries all three."""
+        ``h2d`` carries all three and ``word_source_bytes``: the bytes of
+        the type-id and packed-column pieces put in place of the word, 0
+        where the packed buffer went up."""
         if self.mesh is not None:
             raise NotImplementedError(
                 "this engine is mesh-backed; use prepare_resident_sharded / "
                 "replay_resident_sharded for the resident path")
-        self.check_wire(w)
+        wire = self.check_wire(w)
         stage = self.profiler.stage
         b = w.lengths.shape[0]
-        with stage("h2d", follows=w.trace_ctx,
-                   wire_bytes=_wire_nbytes(w.packed, w.side),
+        with stage("h2d", follows=w.trace_ctx, wire_bytes=_wire_nbytes(w),
                    side_bytes=_side_nbytes(w.side)) as h2d:
             pow2 = self.config.get_str(
                 "surge.replay.resident-len-bucket", "pow2") == "pow2"
@@ -1340,15 +1545,21 @@ class ReplayEngine:
                 starts_p = _pad_rows(w.starts, b_pad)
                 lens_p = _pad_rows(w.lengths, b_pad)
                 copied_bytes = starts_p.nbytes + lens_p.nbytes
+                # the word: its sources where the device is to build it,
+                # else the packed buffer (exact: built on the host if need be)
+                on_device = pow2 and not w.host_packed
+                word = w.words.arrays() if on_device else (w.packed,)
                 # one bucket a wire, the packed buffer's: a side column of
                 # fewer rows gets the same device shape
-                rows = w.packed.shape[0]
+                rows = w.packed_shape[0]
                 host = [_bucket_pieces(arr, _PIECE_ROWS, rows) if pow2
                         else ([arr], 0)
-                        for arr in (w.packed, *w.side.values())]
+                        for arr in (*word, *w.side.values())]
                 copied_bytes += sum(copied for _, copied in host)
                 pieces = sum(len(ps) for ps, _ in host)
                 put_bytes = sum(p.nbytes for ps, _ in host for p in ps)
+                source_bytes = sum(p.nbytes for ps, _ in host[:len(word)]
+                                   for p in ps) if on_device else 0
                 bucket.set_attribute("copied_bytes", copied_bytes)
             with stage("h2d.put", put_bytes=put_bytes, pieces=pieces):
                 # exact: the packed buffer's own rows, whole rows of
@@ -1356,16 +1567,21 @@ class ReplayEngine:
                 whole = (_bucket_len(rows) if pow2 else rows
                          if self.lane_gather == "slices"
                          else _round_up(rows, _LANE_ROW))
-                placed = [_put_pieces(ps, whole) for ps, _ in host]
-                flat_wire, flat_side = placed[0], dict(zip(w.side, placed[1:]))
+                flat_wire, sides, flags = _put_wire(
+                    host, whole, wire,
+                    self._word_program(wire) if on_device else None)
+                flat_side = dict(zip(w.side, sides))
                 starts_dev = jax.device_put(starts_p)
                 lens_dev = jax.device_put(lens_p)
-                # every buffer, not the packed one alone: a side column may
-                # be the caller's array, theirs to write once this returns
-                jax.block_until_ready(placed)
+                # every buffer, not the packed one alone: a column may be
+                # the caller's array, theirs to write once this returns
+                jax.block_until_ready((flat_wire, sides))
+                if on_device:
+                    _raise_outside(wire, flags, w.words)
             h2d.set_attribute("put_bytes", put_bytes)
             h2d.set_attribute("pieces", pieces)
             h2d.set_attribute("copied_bytes", copied_bytes)
+            h2d.set_attribute("word_source_bytes", source_bytes)
         self.stats["h2d_s"] += h2d.seconds
         return ResidentCorpus(
             derived_key=dict(w.derived_key), flat_wire=flat_wire,
@@ -1374,6 +1590,14 @@ class ReplayEngine:
             starts_dev=starts_dev, lens_dev=lens_dev, b_pad=b_pad,
             num_events=w.num_events, wire_bytes=put_bytes,
             upload_s=h2d.seconds, trace_ctx=h2d.context)
+
+    def _word_program(self, wire: WireFormat):
+        """The :func:`mk_word` of ``wire``'s layout, made once an engine and
+        layout, so that a restore's chunks compile it once a bucket."""
+        key = repr(wire.layout_fingerprint())
+        if key not in self._word_programs:
+            self._word_programs[key] = _make_mk_word(wire)
+        return self._word_programs[key]
 
     def prepare_resident_sharded(self, source):
         """Mesh form of :meth:`prepare_resident`: cut the packed corpus at

@@ -7,7 +7,9 @@ replay pass into stages, each a span that is open while the work runs:
 
 - ``encode``  — host-side wire packing / bucketing (CPU-bound); the cold
   rebuild's ``pack_resident``, with children ``encode.lanes`` (length count
-  and sort, grouped check), ``encode.words`` (the word build),
+  and sort, grouped check), ``encode.words`` (the word build, or the
+  hand-over of its source columns where the upload's ``mk_word`` builds it on
+  the device: ``encode`` says which as ``words_from``),
   ``encode.bytes`` (the side columns: one already in its wire dtype is
   handed over as the caller's array, any other cast into a fresh ``[N]``
   buffer; ``encode`` counts them as ``side_aliased`` and

@@ -35,7 +35,8 @@ from surge_tpu.replay.engine import (
     _bucket_len,
     _bucket_pieces,
     _make_tile,
-    _put_pieces,
+    _put_wire,
+    _raise_outside,
     _round_up,
     _side_nbytes,
     _wire_nbytes,
@@ -74,16 +75,20 @@ def _deal(w: ResidentWire, n_dev: int):
     - ``deals[d]``: the lanes (sorted ranks) of device ``d``, ascending, so on
       a length-sorted wire the longest first, as the tile plan wants them.
       Empty lanes hold no rows and are dealt round-robin.
-    - ``shards[d] = (packed, side, starts)``: the device's rows of every
-      buffer and its lanes' starts within them. For a wire whose slabs tile
+    - ``shards[d] = (word, side, starts)``: the device's rows of every
+      buffer and its lanes' starts within them; ``word`` is the arrays the
+      word goes up as: the packed buffer alone, or the word's sources (type
+      ids, then the packed columns) where the wire carries them and no
+      packed buffer yet. For a wire whose slabs tile
       its buffer (:func:`_buffer_order`) the rows are a contiguous slice of
-      the wire's own arrays, the caller's side columns included. Where a
+      the wire's own arrays, the caller's columns included. Where a
       shard is longer than one piece of the upload, every slice is widened to
       the same whole number of pieces, forwards into the next shard's events
       or, at the log's end, backwards into the last one's: a device may hold
       rows it never reads, and the host pads no piece. Any other wire
       (hand-built: a subset, overlapping slabs) has its lanes' rows gathered
-      into fresh buffers, lane after lane, in whole-column index arithmetic.
+      into fresh buffers, lane after lane, in whole-column index arithmetic,
+      from the packed buffer (built on the host if need be).
     - ``rows``: the rows a device's buffers need, guard included: the
       upload's bucket of device zeros supplies what a shard lacks of them.
     - ``copied_bytes``: the event bytes gathered, 0 for a tiling wire.
@@ -91,6 +96,8 @@ def _deal(w: ResidentWire, n_dev: int):
     lens = w.lengths.astype(np.int64)
     tiling = _buffer_order(w, lens)
     tiles = tiling is not None
+    word = (w.words.arrays() if tiles and not w.host_packed
+            else (w.packed,))
     if tiles:
         order, first_row = tiling
     else:
@@ -116,18 +123,18 @@ def _deal(w: ResidentWire, n_dev: int):
             if span is not None:
                 base = max(min(base, total - span), 0)
                 end = min(base + span, total)
-            packed = w.packed[base:end]
-            side = {k: v[base:end] for k, v in w.side.items()}
+            rows = slice(base, end)
             starts = np.where(ln > 0, w.starts[lanes] - base, 0)
         else:
             starts = np.cumsum(ln) - ln
             rows = (np.repeat(w.starts[lanes] - starts, ln)
                     + np.arange(int(ln.sum()), dtype=np.int64))
-            packed = w.packed[rows]
-            side = {k: v[rows] for k, v in w.side.items()}
-            copied += _wire_nbytes(packed, side)
+        shard = tuple(a[rows] for a in word), {k: v[rows]
+                                               for k, v in w.side.items()}
+        if not tiles:
+            copied += shard[0][0].nbytes + _side_nbytes(shard[1])
         deals.append(lanes)
-        shards.append((packed, side, starts))
+        shards.append((*shard, starts))
     return deals, shards, (span or most) + w.guard, copied
 
 
@@ -144,7 +151,7 @@ class ShardedResident:
 
         if engine.mesh is None:
             raise ValueError("ShardedResident requires a mesh-backed engine")
-        engine.check_wire(wire)  # layout/guard safety, same as upload_resident
+        fmt = engine.check_wire(wire)  # layout/guard safety, as upload_resident
         self.engine = engine
         self.wire_host = wire
         mesh = engine.mesh
@@ -206,33 +213,44 @@ class ShardedResident:
             shard.set_attribute("copied_bytes", copied)
 
         with stage("h2d", follows=shard.context,
-                   wire_bytes=_wire_nbytes(wire.packed, wire.side),
+                   wire_bytes=_wire_nbytes(wire),
                    side_bytes=_side_nbytes(wire.side), devices=n_dev) as h2d:
+            # the word's arrays, a shard: the sources the device builds it
+            # from, unless the wire holds its packed buffer (the one-chip
+            # upload's rule; a deal that had to read ``packed`` has built it)
+            on_device = not wire.host_packed
+            n_word = 1 + len(fmt.packed_fields) if on_device else 1
             with stage("h2d.bucket") as bucket:
                 # one bucket for every array of every device: one shape a
                 # program. A shard's own rows go up as they lie, in the
                 # one-chip upload's pieces; the bucket's device zeros are
                 # whatever of ``rows`` a shard lacks
                 host = [[_bucket_pieces(arr, _engine._PIECE_ROWS, rows)
-                         for arr in (packed, *side.values())]
-                        for packed, side, _ in shards]
+                         for arr in (*word, *side.values())]
+                        for word, side, _ in shards]
                 copied_bytes = (starts_l.nbytes + lens_l.nbytes + sum(
                     c for per_dev in host for _, c in per_dev))
                 pieces = sum(len(ps) for per_dev in host for ps, _ in per_dev)
                 put_bytes = sum(p.nbytes for per_dev in host
                                 for ps, _ in per_dev for p in ps)
+                source_bytes = sum(
+                    p.nbytes for per_dev in host for ps, _ in per_dev[:n_word]
+                    for p in ps) if on_device else 0
                 bucket.set_attribute("copied_bytes", copied_bytes)
             with stage("h2d.put", put_bytes=put_bytes, pieces=pieces):
                 whole = _bucket_len(rows)
+                place = engine._word_program(fmt) if on_device else None
 
-                def put(d: int) -> list:
+                def put(d: int) -> tuple:
                     # the one-chip upload, on this device: a thread each, so
                     # that every device's link is fed at once
                     with jax.default_device(devices[d]):
-                        return [_put_pieces(ps, whole) for ps, _ in host[d]]
+                        flat_wire, sides, flags = _put_wire(
+                            host[d], whole, fmt, place)
+                    return [flat_wire, *sides], flags
 
                 with ThreadPoolExecutor(n_dev) as pool:
-                    placed = list(pool.map(put, range(n_dev)))
+                    placed, flags = zip(*pool.map(put, range(n_dev)))
                 # the per-device buffers ARE the shards of the arrays the
                 # program reads: [n_dev * whole, ...] split over the axis
                 joined = [jax.make_array_from_single_device_arrays(
@@ -244,12 +262,17 @@ class ShardedResident:
                 shard2 = NamedSharding(mesh, P(axis, None))
                 self.starts_dev = jax.device_put(starts_l, shard2)
                 self.lens_dev = jax.device_put(lens_l, shard2)
-                # every buffer: a side column may be the caller's array,
-                # theirs to write once this returns
+                # every buffer: a column may be the caller's array, theirs
+                # to write once this returns
                 jax.block_until_ready(joined)
+                if on_device:
+                    # a packed column outside its width on any device: the
+                    # host's error, before any fold can run on the word
+                    _raise_outside(fmt, np.any(flags, axis=0), wire.words)
             h2d.set_attribute("put_bytes", put_bytes)
             h2d.set_attribute("pieces", pieces)
             h2d.set_attribute("copied_bytes", copied_bytes)
+            h2d.set_attribute("word_source_bytes", source_bytes)
         engine.stats["h2d_s"] += h2d.seconds
         self.wire_bytes = put_bytes
         #: context of the ``replay.h2d`` span: a fold continues that trace

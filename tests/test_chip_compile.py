@@ -34,7 +34,7 @@ def compile_report() -> dict:
 
     from surge_tpu.codec.wire import WireFormat
     from surge_tpu.models import counter, shopping_cart
-    from surge_tpu.replay.engine import _make_tile
+    from surge_tpu.replay.engine import _PIECE_ROWS, _make_mk_word, _make_tile
 
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without the chip: keep it out
@@ -82,6 +82,20 @@ def compile_report() -> dict:
             report[name][gather] = counts(jax.jit(fold).lower(
                 slab, shape((N, wire.nbytes), jnp.uint8), side, lanes, lanes,
                 lanes, work, work, shape((), jnp.int32)).compile())
+    # the upload's word build, one piece of the sources into the bucket: the
+    # counter's three int32 sources, the cart's type ids alone
+    report["mk_word"] = {}
+    for name, model in (("counter", counter), ("cart", shopping_cart)):
+        wire = WireFormat(model.make_replay_spec().registry,
+                          {"sequence_number": "ordinal"})
+        piece = shape((_PIECE_ROWS,), jnp.int32)
+        memory = _make_mk_word(wire).lower(
+            shape((N, wire.nbytes), jnp.uint8),
+            shape((len(wire.packed_fields),), jnp.bool_), piece,
+            [piece] * len(wire.packed_fields),
+            shape((), jnp.int32)).compile().memory_analysis()
+        report["mk_word"][name] = [memory.temp_size_in_bytes,
+                                   memory.alias_size_in_bytes]
     return report
 
 
@@ -116,6 +130,17 @@ def test_the_carts_fold_fetches_rows_without_a_lane_loop(report):
     loops, gathers, _ = report["cart_fold"]["rows"]
     assert loops == 1 and gathers >= 1
     assert report["cart_fold"]["slices"][0] > 1
+
+
+@pytest.mark.parametrize("cell", ["counter", "cart"])
+def test_the_word_build_places_a_piece_into_its_bucket_in_place(report, cell):
+    """``jit_mk_word`` at the cells' shapes (a piece of 2^22 rows into the
+    bucket of 2^27): the chip's compiler takes it, the donated bucket is
+    the output (no second bucket on the device) and what it adds is under
+    one piece of one int32 source."""
+    temp, aliased = report["mk_word"][cell]
+    assert aliased >= N  # the bucket, at one byte a row or more
+    assert temp < 4 * (1 << 22)
 
 
 if __name__ == "__main__":

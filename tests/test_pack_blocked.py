@@ -189,36 +189,56 @@ def packed_with_span(engine, events):
     return wire, span.attributes
 
 
+#: where the word is built -> a column dtype that sends it there: int32
+#: columns go up as they lie and the device builds the word (the host's
+#: build then runs when ``packed`` is first read: these comparisons); an
+#: int64 column never takes that path and is packed in ``pack_resident``
+WORDS_FROM = {"device": np.int32, "host": np.int64}
+
+
+def assert_words_from(got, attrs, words_from, num_events):
+    """The span's account of the word pass, before anyone reads ``packed``."""
+    assert attrs["words_from"] == words_from
+    assert got.host_packed == (words_from == "host")
+    assert attrs["blocks"] == (-(-num_events // BLOCK)
+                               if words_from == "host" else 0)
+
+
+@pytest.mark.parametrize("words_from", sorted(WORDS_FROM))
 @pytest.mark.parametrize("kind", ["equal", "ragged", "gaps", "one-long",
                                   "sub-block", "empty"])
 @pytest.mark.parametrize("schema", sorted(SCHEMAS))
-def test_grouped_input_packs_byte_for_byte(schema, kind):
+def test_grouped_input_packs_byte_for_byte(schema, kind, words_from):
     engine = make_engine(schema)
-    events = make_events(schema, kind, seed=3)
+    events = make_events(schema, kind, seed=3, dtype=WORDS_FROM[words_from])
     _registry, _derived, nbytes, side = SCHEMAS[schema]
     got, attrs = packed_with_span(engine, events)
+    assert_words_from(got, attrs, words_from, events.num_events)
+    assert got.packed_shape == (events.num_events + got.guard, nbytes)
     assert_same_wire(got, plain_pack(engine, events))
     assert got.packed.shape == (events.num_events + got.guard, nbytes)
     assert sorted(got.side) == side
     assert attrs["grouped"] is True and attrs["lanes_from"] == "boundaries"
-    assert attrs["blocks"] == -(-events.num_events // BLOCK)
     # every log as long as the next: no sort, so no permutation
     if kind in ("equal", "empty", "one-long"):
         assert got.perm is None
     if kind in ("ragged", "gaps"):
-        assert got.perm is not None and attrs["blocks"] > 5
+        assert got.perm is not None
+        assert events.num_events > 5 * BLOCK  # the host's pass: many blocks
 
 
+@pytest.mark.parametrize("words_from", sorted(WORDS_FROM))
 @pytest.mark.parametrize("kind", ["equal", "ragged", "gaps"])
 @pytest.mark.parametrize("schema", ["counter-1B", "counter-1B-side",
                                     "fields-3B"])
-def test_ungrouped_input_keeps_the_bincount_path(schema, kind):
+def test_ungrouped_input_keeps_the_bincount_path(schema, kind, words_from):
     engine = make_engine(schema)
-    events = make_events(schema, kind, seed=5, ungrouped=True)
+    events = make_events(schema, kind, seed=5, ungrouped=True,
+                         dtype=WORDS_FROM[words_from])
     got, attrs = packed_with_span(engine, events)
+    assert_words_from(got, attrs, words_from, events.num_events)
     assert_same_wire(got, plain_pack(engine, events))
     assert attrs["grouped"] is False and attrs["lanes_from"] == "bincount"
-    assert attrs["blocks"] == -(-events.num_events // BLOCK)
 
 
 @pytest.mark.parametrize("dtype", [np.int32, np.int64, np.uint16])

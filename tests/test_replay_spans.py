@@ -23,6 +23,8 @@ from surge_tpu.replay.profiler import ReplayProfiler
 from surge_tpu.tracing import (DEFAULT_RING_CAPACITY, InMemoryTracer,
                                JsonlSpanExporter, Tracer, active_span,
                                default_tracer)
+# where the word is built -> a column dtype that sends it there
+from tests.test_pack_blocked import WORDS_FROM
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -33,14 +35,15 @@ FETCH_CHILDREN = ["replay.fetch.wait", "replay.fetch.decode"]
 UMBRELLAS = ["replay.encode", "replay.h2d", "replay.resident"]
 
 
-def make_events(n_agg=48, n_per=20):
+def make_events(n_agg=48, n_per=20, words_from="host"):
     n = n_agg * n_per
+    dtype = WORDS_FROM[words_from]
     return ColumnarEvents(
         num_aggregates=n_agg,
         agg_idx=np.repeat(np.arange(n_agg, dtype=np.int32), n_per),
         type_ids=np.zeros(n, dtype=np.int32),
-        cols={"increment_by": np.ones(n, dtype=np.int64),
-              "decrement_by": np.zeros(n, dtype=np.int64)},
+        cols={"increment_by": np.ones(n, dtype=dtype),
+              "decrement_by": np.zeros(n, dtype=dtype)},
         derived_cols={"sequence_number": "ordinal"})
 
 
@@ -79,13 +82,16 @@ def assert_children(spans, parent, names):
         assert any(s.name == name for s in spans), name
 
 
+@pytest.mark.parametrize("words_from", sorted(WORDS_FROM))
 @pytest.mark.parametrize("fold", ["first", "again"])
-def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
+def test_one_rebuild_is_one_trace_with_the_whole_tree(fold, words_from):
     """``again``: a second fold of the uploaded corpus is the same subtree in
-    the same trace, with a steady dispatch where the first compiled."""
+    the same trace, with a steady dispatch where the first compiled.
+    ``words_from``: the same tree whether the host packed the word or the
+    upload put its three int32 sources and built it on the device."""
     engine = make_engine()  # no profiler, tracer or config key passed
     since = time.monotonic()
-    wire, resident, res = rebuild(engine, make_events())
+    wire, resident, res = rebuild(engine, make_events(words_from=words_from))
     spans = ring_since(since)
     assert len({s.context.trace_id for s in spans}) == 1
     encode, h2d, resident_span = (one(spans, n) for n in UMBRELLAS)
@@ -124,22 +130,29 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
     n = make_events().num_events
     assert encode.attributes["events"] == n
     assert encode.attributes["aggregates"] == 48
-    guard_rows = wire.packed.shape[0]
+    on_device = words_from == "device"
+    assert wire.host_packed != on_device  # the upload built none on the host
+    guard_rows = wire.packed_shape[0]
     assert encode.attributes["wire_bytes"] == guard_rows == n + wire.guard
-    # how the pack went: 960 grouped events are one block of the word pass
-    assert encode.attributes["blocks"] == 1
+    # how the pack went: 960 grouped events are one block of the host's word
+    # pass, and none where the sources were handed over
+    assert encode.attributes["words_from"] == words_from
+    assert encode.attributes["blocks"] == (0 if on_device else 1)
     assert encode.attributes["grouped"] is True
     assert encode.attributes["lanes_from"] == "boundaries"
     assert h2d.attributes["wire_bytes"] == encode.attributes["wire_bytes"]
-    assert h2d.attributes["put_bytes"] == resident.wire_bytes == 1 << 16
-    # a wire of under one piece: one put an array (the one-byte word alone),
-    # padded on the host to its bucket, with the two int32 lane vectors
-    assert h2d.attributes["pieces"] == 1
+    # a wire of under one piece: one put an array, padded on the host to its
+    # bucket (the one-byte word alone, or its three int32 sources: the type
+    # ids and the two packed columns), with the two int32 lane vectors
+    arrays, put_bytes = (3, 3 * 4 << 16) if on_device else (1, 1 << 16)
+    assert h2d.attributes["put_bytes"] == resident.wire_bytes == put_bytes
+    assert h2d.attributes["pieces"] == arrays
+    assert h2d.attributes["word_source_bytes"] == on_device * put_bytes
     assert h2d.attributes["copied_bytes"] == (
-        (1 << 16) + 2 * 4 * resident.b_pad)
+        put_bytes + 2 * 4 * resident.b_pad)
     assert bucket.attributes == {
         "copied_bytes": h2d.attributes["copied_bytes"]}
-    assert put.attributes == {"put_bytes": 1 << 16, "pieces": 1}
+    assert put.attributes == {"put_bytes": put_bytes, "pieces": arrays}
     assert sorted(resident_span.attributes) == [
         "aggregates", "events", "fetched_slots", "gather", "padded_slots",
         "rounds", "rows_fetched", "scan_steps", "slots_small", "tiles",
@@ -159,18 +172,20 @@ def test_one_rebuild_is_one_trace_with_the_whole_tree(fold):
     assert engine.stats["h2d_s"] == h2d.seconds
 
 
+@pytest.mark.parametrize("words_from", sorted(WORDS_FROM))
 @pytest.mark.parametrize("fold", ["first", "again"])
-def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold):
+def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold, words_from):
     """The mesh form (``replay/resident_mesh.py``): pack, deal, upload, fold
     and pull of one rebuild over four devices, the one-chip names where the
     work is the same, ``replay.shard`` for the deal, ``devices`` on all
-    three of its umbrellas."""
+    three of its umbrellas; the word packed on the host or built on each
+    device from its slice of the sources."""
     import jax
 
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("data",))
     engine = make_engine(mesh=mesh)
     since = time.monotonic()
-    wire = engine.pack_resident(make_events())
+    wire = engine.pack_resident(make_events(words_from=words_from))
     sharded = engine.prepare_resident_sharded(wire)
     res = engine.replay_resident_sharded(sharded)
     assert (res.states["count"] == 20).all()
@@ -214,15 +229,21 @@ def test_a_sharded_rebuild_is_one_trace_with_the_whole_tree(fold):
         "aggregates": 48, "events": n, "devices": 4, "lanes_min": 12,
         "lanes_max": 12, "events_min": 240, "events_max": 240,
         "tiles_min": 3, "tiles_max": 3, "copied_bytes": 0}
-    # the upload: one piece (the bucket) a device for the one-byte word, and
-    # the two int32 lane vectors of every device
+    # the upload: one piece (the bucket) a device for the one-byte word, or
+    # for each of its three int32 sources, and the two int32 lane vectors of
+    # every device
+    on_device = words_from == "device"
+    assert wire.host_packed != on_device
+    assert encode.attributes["words_from"] == words_from
+    arrays, put_bytes = (3, 4 * 3 * 4 << 16) if on_device else (1, 4 << 16)
     assert h2d.attributes == {
         "wire_bytes": encode.attributes["wire_bytes"], "side_bytes": 0,
-        "devices": 4, "put_bytes": 4 * (1 << 16), "pieces": 4,
-        "copied_bytes": 4 * (1 << 16) + 2 * 4 * 4 * sharded.b_pad}
+        "devices": 4, "put_bytes": put_bytes, "pieces": 4 * arrays,
+        "word_source_bytes": on_device * put_bytes,
+        "copied_bytes": put_bytes + 2 * 4 * 4 * sharded.b_pad}
     assert bucket.attributes == {
         "copied_bytes": h2d.attributes["copied_bytes"]}
-    assert put.attributes == {"put_bytes": 4 * (1 << 16), "pieces": 4}
+    assert put.attributes == {"put_bytes": put_bytes, "pieces": 4 * arrays}
     assert sorted(resident_span.attributes) == [
         "aggregates", "devices", "events", "fetched_slots", "gather",
         "padded_slots", "rounds", "rows_fetched", "scan_steps", "slots_small",
@@ -312,16 +333,21 @@ def test_the_fold_span_says_which_width_the_plan_chose(monkeypatch, gather,
                                         else 13 * 8)
 
 
+@pytest.mark.parametrize("words_from", sorted(WORDS_FROM))
 @pytest.mark.parametrize("grouped, block, blocks, lanes_from", [
     (True, 1 << 18, 1, "boundaries"), (True, 100, 10, "boundaries"),
     (True, 7, 138, "boundaries"), (False, 100, 10, "bincount")])
 def test_the_encode_span_says_how_the_pack_went(monkeypatch, grouped, block,
-                                                blocks, lanes_from):
-    """``blocks``, ``grouped`` and ``lanes_from`` ride on ``replay.encode``;
-    its four children keep their names and their parent whichever way the
-    pack went, and ``stats["pack_s"]`` is the umbrella's seconds."""
+                                                blocks, lanes_from,
+                                                words_from):
+    """``blocks``, ``words_from``, ``grouped`` and ``lanes_from`` ride on
+    ``replay.encode``; its four children keep their names and their parent
+    whichever way the pack went, and ``stats["pack_s"]`` is the umbrella's
+    seconds. Where the sources are handed over (``device``) the host runs no
+    block, until someone reads ``packed``: that build is its own
+    ``replay.encode.words`` span, in the pack's trace."""
     monkeypatch.setattr(wire_module, "FLAT_PACK_BLOCK", block)
-    events = make_events()
+    events = make_events(words_from=words_from)
     if not grouped:
         order = np.random.default_rng(0).permutation(events.num_events)
         events.agg_idx = events.agg_idx[order]
@@ -333,15 +359,27 @@ def test_the_encode_span_says_how_the_pack_went(monkeypatch, grouped, block,
     assert sorted(s.name for s in spans if s is not encode) == sorted(
         ENCODE_CHILDREN)
     assert_children(spans, encode, ENCODE_CHILDREN)
-    assert encode.attributes["blocks"] == blocks
+    assert encode.attributes["words_from"] == words_from
+    assert encode.attributes["blocks"] == (
+        blocks if words_from == "host" else 0)
     assert encode.attributes["grouped"] is grouped
     assert encode.attributes["lanes_from"] == lanes_from
     assert encode.attributes["events"] == 960
     assert encode.attributes["wire_bytes"] == 960 + wire.guard
     assert engine.stats["pack_s"] == encode.seconds
     assert sum(s.seconds for s in spans if s is not encode) <= encode.seconds
+    since = time.monotonic()
     res = engine.replay_resident(engine.upload_resident(wire))
     assert (res.states["count"] == 20).all()
+    assert not any(s.name == "replay.encode.words" for s in ring_since(since))
+    assert wire.host_packed == (words_from == "host")
+    since = time.monotonic()
+    assert wire.packed.shape == (960 + wire.guard, 1)  # the first reader
+    late = [s for s in ring_since(since) if s.name == "replay.encode.words"]
+    assert len(late) == (words_from == "device")
+    for span in late:  # the host's build, in the trace the pack opened
+        assert span.context.trace_id == encode.context.trace_id
+        assert span.parent_id == encode.context.span_id
 
 
 @pytest.mark.parametrize("derived, dtypes, aliased, copied_columns", [
@@ -708,10 +746,11 @@ def test_cold_path_jit_names_are_pinned():
         assert any(f"jit_{name}".startswith(p) for p in prefixes), name
     # and the programs a driven engine holds carry those names
     engine = ReplayEngine(make_replay_spec())
-    rebuild(engine, make_events())
+    rebuild(engine, make_events(words_from="device"))
     held = [*engine._resident_folds.values(),
             *engine._slab_programs.values(),
             *engine._finalize_programs.values(),
+            *engine._word_programs.values(),
             engine_module._zero_bucket, engine_module._place_piece]
     assert {p.__name__ for p in held} == set(COLD_PATH_JIT_NAMES)
 

@@ -229,26 +229,34 @@ def test_every_shard_folds_at_the_one_width_of_the_whole_corpus(monkeypatch,
     assert (engine.replay_resident_sharded(other).states["count"] == 16).all()
 
 
+@pytest.mark.parametrize("words_from", ["device", "host"])
 @pytest.mark.parametrize("devices", [2, 4])
-def test_a_tiling_wire_goes_up_as_it_lies(monkeypatch, devices):
+def test_a_tiling_wire_goes_up_as_it_lies(monkeypatch, devices, words_from):
     """Several pieces a shard: every shard is a slice of the wire's own arrays
     (a side column still the caller's), widened to whole pieces, the last
-    backwards, so the host copies no event and pads no piece."""
+    backwards, so the host copies no event and pads no piece. The word's
+    rows are the packed buffer's where the host has built one (``host``:
+    someone read ``packed`` before the deal), else the caller's type ids,
+    the cart's whole word, which each device builds it from."""
     monkeypatch.setattr(engine_module, "_PIECE_ROWS", PIECE)
     n = 75_000 * devices
     events = cart_events(n, seed=devices, carts=500)
     engine = make_engine(MODELS["cart"][0].make_replay_spec(), devices,
                          batch=256, chunk=64)
     wire = engine.pack_resident(events)
+    on_device = words_from == "device"
+    lies_in, row_bytes = ((events.type_ids, 4) if on_device
+                          else (wire.packed, 1))
     deals, shards, rows, copied = _deal(wire, devices)
     assert copied == 0 and rows == 2 * PIECE + wire.guard
-    for packed, side, starts in shards:
-        assert packed.shape[0] == 2 * PIECE
-        assert np.shares_memory(packed, wire.packed)
+    assert wire.host_packed != on_device
+    for (word,), side, starts in shards:
+        assert word.shape[0] == 2 * PIECE
+        assert np.shares_memory(word, lies_in)
         for k, col in side.items():
             assert col.shape == (2 * PIECE,)
             assert np.shares_memory(col, events.cols[k]), k
-    assert np.shares_memory(shards[-1][0], wire.packed[n - 1:])  # backwards
+    assert np.shares_memory(shards[-1][0][0], lies_in[n - 1:])  # backwards
     since = time.monotonic()
     res = engine.replay_resident_sharded(
         engine.prepare_resident_sharded(wire))
@@ -259,7 +267,11 @@ def test_a_tiling_wire_goes_up_as_it_lies(monkeypatch, devices):
     b_pad = h2d.attributes["copied_bytes"] // (2 * 4 * devices)
     assert h2d.attributes["copied_bytes"] == 2 * 4 * devices * b_pad  # lanes
     assert h2d.attributes["pieces"] == devices * 4 * 2
-    assert h2d.attributes["put_bytes"] == devices * 2 * PIECE * (1 + 3 * 4)
+    assert h2d.attributes["put_bytes"] == devices * 2 * PIECE * (
+        row_bytes + 3 * 4)
+    assert h2d.attributes["word_source_bytes"] == (
+        devices * 2 * PIECE * 4 * on_device)
+    assert wire.host_packed != on_device  # the upload read no ``packed``
     assert h2d.attributes["wire_bytes"] == wire.packed.nbytes + 3 * 4 * n
 
 

@@ -156,26 +156,42 @@ def test_pack_resident_hands_the_columns_over_with_no_guard_rows(dtype):
 
 # -- (b) one bucket a wire, the packed buffer's ------------------------------
 
+@pytest.mark.parametrize("words_from", ["device", "host"])
 @pytest.mark.parametrize("size", sorted(SIZES))
-def test_side_columns_take_the_packed_buffers_bucket(small_pieces, size):
+def test_side_columns_take_the_packed_buffers_bucket(small_pieces, size,
+                                                     words_from):
+    """``device``: the type ids (the cart's whole word) go up as a fourth
+    ``[N]`` int32 column and ``mk_word`` builds the packed buffer from their
+    pieces; ``host``: the packed buffer exists before the upload (someone
+    read it) and goes up as it lies. The same device buffers either way."""
     n, side_pieces, packed_pieces, bucket = SIZES[size]
     engine = make_engine()
     events = cart_events(n, seed=n)
     wire = engine.pack_resident(events)
+    if words_from == "host":
+        assert wire.packed.shape == (n + wire.guard, 1)
     assert bucket == _bucket_len(n + wire.guard)
     since = time.monotonic()
     resident = engine.upload_resident(wire)
     (h2d,) = (s.attributes for s in h2d_spans(since))
     assert_one_bucket(resident, wire, bucket)
-    assert h2d["pieces"] == packed_pieces + 3 * side_pieces
     whole = min(PIECE, bucket)
-    assert h2d["put_bytes"] == resident.wire_bytes == whole * (
-        packed_pieces + 4 * 3 * side_pieces)
     # the host copies an array's last piece where it is partial, no other
-    # row of a side column, and none at all of whole pieces
+    # row of a column, and none at all of whole pieces
     lanes = 2 * 4 * resident.b_pad
-    partial = 0 if n % whole == 0 else 4 * 3 * whole
-    assert h2d["copied_bytes"] == lanes + whole + partial
+    partial = 0 if n % whole == 0 else 4 * whole
+    if words_from == "host":
+        assert h2d["pieces"] == packed_pieces + 3 * side_pieces
+        put_bytes = whole * (packed_pieces + 4 * 3 * side_pieces)
+        assert h2d["copied_bytes"] == lanes + whole + 3 * partial
+        assert h2d["word_source_bytes"] == 0
+    else:
+        assert h2d["pieces"] == 4 * side_pieces
+        put_bytes = whole * 4 * 4 * side_pieces
+        assert h2d["copied_bytes"] == lanes + 4 * partial
+        assert h2d["word_source_bytes"] == whole * 4 * side_pieces
+    assert h2d["put_bytes"] == resident.wire_bytes == put_bytes
+    assert h2d["wire_bytes"] == (n + wire.guard) + 3 * 4 * n
     assert_states(engine.replay_resident(resident), scalar_states(events))
 
 

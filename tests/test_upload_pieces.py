@@ -79,6 +79,10 @@ def cart_corpus(events, seed=11):
 #: has three int32 side columns beside the one-byte word)
 MODELS = {"counter": (counter, counter_corpus, 1),
           "cart": (shopping_cart, cart_corpus, 4)}
+#: the arrays a wire fresh from ``pack_resident`` goes up as, the word's
+#: sources in place of the word: the counter's type ids and its two packed
+#: columns; the cart's type ids beside its three side columns
+SOURCE_ARRAYS = {"counter": 3, "cart": 4}
 
 
 def make_engine(model, **keys):
@@ -119,12 +123,16 @@ def test_an_upload_in_pieces_folds_the_one_piece_uploads_states(
         wire.save(str(tmp_path / "wire"))
         wire = ResidentWire.load(str(tmp_path / "wire"))
         assert isinstance(wire.packed, np.memmap)
+    else:  # the device builds the word from its sources' pieces
+        arrays = SOURCE_ARRAYS[name]
     since = time.monotonic()
     whole = engine.upload_resident(wire)
     monkeypatch.setattr(engine_module, "_PIECE_ROWS", PIECE)
     pieced = engine.upload_resident(wire)
+    assert wire.host_packed == (source == "loaded")
     one, many = (s.attributes for s in h2d_spans(since))
     assert one["pieces"] == arrays and many["pieces"] == 4 * arrays
+    assert (many["word_source_bytes"] > 0) == (source == "fresh")
     assert one["wire_bytes"] == many["wire_bytes"] <= many["put_bytes"]
     assert many["put_bytes"] == whole.wire_bytes == pieced.wire_bytes
     lanes = 2 * 4 * whole.b_pad  # starts and lens
@@ -171,6 +179,8 @@ def test_a_second_length_in_the_bucket_compiles_no_placement(monkeypatch):
     first = engine.upload_resident(engine.pack_resident(counter_corpus(150_000)))
     engine.replay_resident(first)
     placements, folds = _place_piece._cache_size(), engine.num_compiles()
+    (mk_word,) = engine._word_programs.values()  # one layout, one program
+    words = mk_word._cache_size()
     for events, seed in ((180_000, 2), (240_000, 3)):
         corpus = synth_counter_corpus(2000, events, seed=seed)
         resident = engine.upload_resident(engine.pack_resident(corpus.events))
@@ -179,4 +189,6 @@ def test_a_second_length_in_the_bucket_compiles_no_placement(monkeypatch):
         np.testing.assert_array_equal(got.states["count"],
                                       corpus.expected_count)
     assert _place_piece._cache_size() == placements
+    assert list(engine._word_programs.values()) == [mk_word]
+    assert mk_word._cache_size() == words == 1
     assert engine.num_compiles() == folds
