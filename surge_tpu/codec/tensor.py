@@ -77,6 +77,11 @@ class ColumnarEvents:
     # chunks are immutable once written, so this is a stable O(1) identity for
     # caches keyed per chunk)
     source_ordinal: int | None = None
+    # how the chunk lay in its segment file (set by read_segment):
+    # ``stored_bytes`` read for it, the ``raw_bytes`` they decode to (columns
+    # and ids), and its ``codec``: "slz", "raw", or "mixed" where the writer
+    # compressed some of its payloads only
+    source_stored: dict | None = None
 
     @property
     def num_events(self) -> int:

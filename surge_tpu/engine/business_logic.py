@@ -53,6 +53,14 @@ class SurgeCommandBusinessLogic:
         fn = getattr(self.model, "replay_spec", None)
         return fn() if fn is not None else None
 
+    @property
+    def decode_state(self):
+        """The model's ``decode_state(aggregate_id, state)``, or None: what a
+        restore calls on every state decoded from tensor columns (the bulk
+        restores of ``store/restore.py``, the resident plane's reads) to put
+        back what the columns cannot carry, such as a cart's string id."""
+        return getattr(self.model, "decode_state", None)
+
 
 class SurgeModel:
     """Serialization executor around a business-logic bundle (SurgeModel.scala:20-66).

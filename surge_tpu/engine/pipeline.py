@@ -858,15 +858,22 @@ class SurgeEngine(Controllable):
         segment if absent (always covering EVERY partition — it is a shared
         artifact), then stream-restore only this node's ``owned`` partitions'
         chunks from it."""
+        from surge_tpu.replay import ReplayEngine
         from surge_tpu.store.restore import restore_from_segment
 
         state_fmt = self.logic.state_format
         self._ensure_segment(segment_path, spec)
+        # one engine a pipeline: the programs a rebuild compiled serve the
+        # next rebuild of this process
+        reng = getattr(self, "_restore_replay_engine", None)
+        if reng is None:
+            reng = self._restore_replay_engine = ReplayEngine(
+                spec, config=self.config, mesh=mesh)
         return restore_from_segment(
             segment_path, self.indexer.store, replay_spec=spec,
             serialize_state=lambda agg_id, st: state_fmt.write_state(st).value,
             decode_state=getattr(self.logic, "decode_state", None),
-            config=self.config, mesh=mesh, partitions=owned)
+            config=self.config, mesh=mesh, partitions=owned, engine=reng)
 
     def _ensure_segment(self, segment_path: str, spec) -> None:
         """Build the columnar segment if absent (covering EVERY partition — it

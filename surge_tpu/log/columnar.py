@@ -327,6 +327,8 @@ def read_segment(path: str,
             c_derived = (dict(meta["chunk_derived"]) if "chunk_derived" in meta
                          else dict(derived))
             arrays = {}
+            stored_bytes = raw_bytes = 0
+            codecs = set()
             for name, codec, stored_len, raw_len in meta["cols"]:
                 if (wanted is not None
                         and name not in ("agg_idx", "type_ids")
@@ -337,9 +339,15 @@ def read_segment(path: str,
                          else c_type if name == "type_ids"
                          else c_cols[name])
                 arrays[name] = _decode_array(f.read(stored_len), codec, raw_len, dtype)
+                stored_bytes += stored_len
+                raw_bytes += raw_len
+                codecs.add(codec)
             ids = None
             if "ids" in meta:
                 codec, stored_len, raw_len = meta["ids"]
+                stored_bytes += stored_len
+                raw_bytes += raw_len
+                codecs.add(codec)
                 raw = f.read(stored_len)
                 if codec == seg.CODEC_SLZ:
                     raw = seg.slz_decompress(raw, raw_len)
@@ -355,7 +363,11 @@ def read_segment(path: str,
                 cols=arrays,
                 derived_cols=c_derived,
                 aggregate_ids=ids,
-                source_ordinal=ordinal)
+                source_ordinal=ordinal,
+                source_stored={
+                    "stored_bytes": stored_bytes, "raw_bytes": raw_bytes,
+                    "codec": ("mixed" if len(codecs) > 1 else
+                              "slz" if codecs == {seg.CODEC_SLZ} else "raw")})
 
 
 def segment_info(path: str) -> dict:

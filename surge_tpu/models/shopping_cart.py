@@ -116,6 +116,13 @@ class CartModel:
     def replay_spec(self) -> ReplaySpec:
         return make_replay_spec()
 
+    def decode_state(self, aggregate_id: str, state: Cart) -> Cart:
+        """A cart decoded from its tensor columns, with the id the columns
+        cannot carry (``cart_id`` is a string, outside the tensor schema):
+        the restore hook of ``SurgeCommandBusinessLogic.decode_state``."""
+        return Cart(aggregate_id, state.item_count, state.total_cents,
+                    state.checked_out, state.version)
+
 
 ADDED, REMOVED, CHECKED_OUT = 0, 1, 2
 

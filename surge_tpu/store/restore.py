@@ -18,7 +18,12 @@ SURVEY.md §5.4 TPU mapping).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import json
 import logging
+import os
+import shutil
+import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence
 
@@ -279,8 +284,6 @@ def _restore_events_via_segment(log, events_topic: str, store, parts, *,
     chunk at a time) → restore_from_segment (mmapped chunks, per-AGGREGATE
     writeback only). Peak host memory is one chunk's decoded events, set by
     ``surge.replay.restore-chunk-aggregates``."""
-    import os
-    import shutil
     import tempfile
 
     from surge_tpu.log.columnar import build_segment_from_topic
@@ -361,8 +364,11 @@ def _restore_events_cpu_ranges(log, events_topic: str, store, parts, *,
         watermarks=watermarks, backend="cpu")
 
 
-def _chunk_wire(engine, segment_path: str, chunk, build_id: str | None = None):
+def _chunk_wire(engine, segment_path: str, chunk,
+                build_id: str | None = None) -> tuple:
     """Per-chunk wire cache beside the segment: ``<segment>.wires/<key>/``.
+    Returns ``(wire, hit)``: the chunk's packed wire, and whether it was
+    loaded from the cache (mmapped) rather than packed here.
 
     The host-side flat pack is the expensive half of a resident replay on a
     1-core host, and segment chunks are IMMUTABLE once written (extends append
@@ -378,18 +384,11 @@ def _chunk_wire(engine, segment_path: str, chunk, build_id: str | None = None):
     cache instead of corrupting states. Cold starts after the first mmap
     straight from disk — the same pack-once contract as ResidentWire in the
     bench."""
-    import hashlib
-    import json
-    import logging
-    import os
-    import shutil
-    import time
-
     from surge_tpu.codec.wire import WireFormat
     from surge_tpu.replay.engine import ResidentWire
 
     if chunk.source_ordinal is None:
-        return engine.pack_resident(chunk)  # not from a segment reader
+        return engine.pack_resident(chunk), False  # not from a segment reader
     # O(1) key: chunks are immutable once written (extends append, never
     # rewrite), so (build id, global chunk ordinal) identifies the content;
     # the engine's wire-layout fingerprint is part of the key so schema
@@ -406,14 +405,13 @@ def _chunk_wire(engine, segment_path: str, chunk, build_id: str | None = None):
         try:
             wire = ResidentWire.load(root)
             engine.check_wire(wire)
-            return wire
+            return wire, True
         except Exception as exc:  # noqa: BLE001 — fall through to repack
             # never silent: a corrupt/stale entry is expected after a schema
             # change, but masking e.g. a failing disk here would look like a
             # mysteriously slow restore (VERDICT r4 weak #8)
-            logging.getLogger(__name__).warning(
-                "wire cache entry %s unusable (%s: %s); repacking",
-                root, type(exc).__name__, exc)
+            _log.warning("wire cache entry %s unusable (%s: %s); repacking",
+                         root, type(exc).__name__, exc)
     wire = engine.pack_resident(chunk)
     # crash hygiene: tmp dirs orphaned by an earlier kill are swept once they
     # are plausibly dead (older than an hour); live writers are younger
@@ -440,7 +438,7 @@ def _chunk_wire(engine, segment_path: str, chunk, build_id: str | None = None):
     except BaseException:
         shutil.rmtree(tmp, ignore_errors=True)
         raise
-    return wire
+    return wire, False
 
 
 def restore_from_segment(
@@ -449,18 +447,46 @@ def restore_from_segment(
         serialize_state: Callable[[str, Any], bytes],
         decode_state: Callable[[str, Any], Any] | None = None,
         config: Config | None = None, mesh=None,
-        partitions: Optional[Sequence[int]] = None) -> RestoreResult:
+        partitions: Optional[Sequence[int]] = None,
+        engine=None) -> RestoreResult:
     """Rebuild the store from a columnar segment (log/columnar.py) — the scalable
     cold-start path: per-event Python objects never exist; chunks stream through
-    :meth:`ReplayEngine.replay_columnar` and only the per-AGGREGATE writeback is
-    host-side Python. The segment's snapshot section (state-only aggregates) and
+    the replay engine and only the per-AGGREGATE write-back is host-side Python.
+    The segment's snapshot section (state-only aggregates) and
     build-time watermarks make it a complete cold-start image, so no state-topic
     scan follows (the restore-throughput knob this replaces: restore consumer
     max.poll.records, common reference.conf:198-199).
 
+    A chunk at a time: :func:`~surge_tpu.log.columnar.read_segment` decodes its
+    columns; on one device (``surge.replay.segment-backend = resident``) its
+    packed wire comes from the cache beside the segment (:func:`_chunk_wire`,
+    unless ``surge.replay.segment-wire-cache`` is off) and goes through
+    ``upload_resident`` and ``replay_resident``, on a mesh the columns stream
+    through ``replay_columnar``; then **the write-back**, an aggregate at a
+    time: :func:`~surge_tpu.codec.tensor.decode_states` builds one state
+    object a row of the pulled columns, and each gets its id back
+    (:func:`_with_aggregate_id` for a field named ``aggregate_id``, then
+    ``decode_state(aggregate_id, state)``: the model's own hook, which
+    ``SurgeCommandBusinessLogic.decode_state`` hands the engine, for whatever
+    else the tensor schema cannot carry, a cart's ``cart_id`` or a
+    vocabulary's strings), is turned to bytes by ``serialize_state(aggregate_id,
+    state)`` and stored under its id by ``store.put``.
+
     ``partitions`` restores only chunks/snapshot sections recorded for those
     source partitions (per-assigned-task restore, SURVEY.md §3.3): a multi-node
     cold start reads 1/N of the segment and never writes unowned aggregates.
+
+    ``engine`` is the caller's :class:`~surge_tpu.replay.ReplayEngine` of
+    ``replay_spec`` (and of ``config`` and ``mesh``), for a process that
+    restores more than once: the compiled programs live on the engine, so a
+    second restore through it compiles nothing. None builds one for this call.
+
+    One restore is one trace, through the engine's profiler: the root
+    ``replay.restore`` and, a chunk, ``replay.restore.read``,
+    ``replay.restore.wire``, the fold's own ``replay.h2d`` / ``replay.resident``
+    (or ``replay.encode`` / ``replay.fetch`` ...), ``replay.restore.decode`` and
+    ``replay.restore.writeback``; then ``replay.restore.snapshots``
+    (docs/observability.md, "Replay profiler", lists every attribute).
     """
     from surge_tpu.codec.tensor import decode_states
     from surge_tpu.log.columnar import (
@@ -468,12 +494,14 @@ def restore_from_segment(
         read_segment_snapshots,
         segment_info,
     )
-    from surge_tpu.replay.engine import ReplayEngine
+    from surge_tpu.replay.engine import ReplayEngine, _wire_nbytes
 
     import numpy as np
 
     cfg = config or default_config()
-    engine = ReplayEngine(replay_spec, config=cfg, mesh=mesh)
+    if engine is None:
+        engine = ReplayEngine(replay_spec, config=cfg, mesh=mesh)
+    stage = engine.profiler.stage
     info = segment_info(path)
     schema = info["schema"]
     extra = schema.get("extra", {})
@@ -495,52 +523,77 @@ def restore_from_segment(
     chunk_states: list = []
     where: Dict[str, tuple] = {}
     restored: set = set()
-    num_events = 0
-    for chunk in read_segment(path, partitions=part_filter):
-        if chunk.aggregate_ids is None:
-            raise ValueError(
-                f"{path}: segment chunks carry no aggregate ids; rebuild the "
-                "segment with build_segment_from_topic to restore through it")
-        init = None
-        if track:
-            hits = [(i, a) for i, a in enumerate(chunk.aggregate_ids)
-                    if a in where]
-            if hits:
-                init = engine.init_carry_np(chunk.num_aggregates)
-                for name, col in init.items():
-                    for i, a in hits:
-                        ci, row = where[a]
-                        col[i] = chunk_states[ci][name][row]
-        if use_resident:
-            wire = (_chunk_wire(engine, path, chunk,
-                                build_id=extra.get("build_id")) if wire_cache
-                    else engine.pack_resident(chunk))
-            resident = engine.upload_resident(wire)
-            res = engine.replay_resident(resident, init_carry=init)
-        else:
-            res = engine.replay_columnar(chunk, init_carry=init)
-        if track:
-            chunk_states.append({k: np.asarray(v)
-                                 for k, v in res.states.items()})
-            ci = len(chunk_states) - 1
-            for i, agg_id in enumerate(chunk.aggregate_ids):
-                where[agg_id] = (ci, i)
-        states = decode_states(replay_spec.registry.state, res.states)
-        for agg_id, state in zip(chunk.aggregate_ids, states):
-            if state is None:
-                continue
-            state = _with_aggregate_id(state, agg_id)
-            if decode_state is not None:
-                state = decode_state(agg_id, state)
-            store.put(agg_id, serialize_state(agg_id, state))
-            restored.add(agg_id)
-        num_events += res.num_events
-    # snapshot sections apply in file order AFTER chunks: a delta snapshot for
-    # an aggregate supersedes its (older) chunk-folded state, latest-wins
-    for key, value in read_segment_snapshots(path, partitions=part_filter):
-        store.put(key, value)
-        restored.add(key)
-    num_aggregates = len(restored)
+    num_events = num_chunks = wire_hits = wire_misses = 0
+    with stage("restore", segment_bytes=os.path.getsize(path),
+               backend="resident" if use_resident else "streaming") as root:
+        chunks = read_segment(path, partitions=part_filter)
+        while True:
+            # one step of the reader: the chunk's payloads read and decoded
+            # (the last step finds the end of the file and no chunk)
+            with stage("restore.read") as read:
+                chunk = next(chunks, None)
+                if chunk is not None and chunk.source_stored is not None:
+                    read.attributes.update(chunk.source_stored)
+            if chunk is None:
+                break
+            if chunk.aggregate_ids is None:
+                raise ValueError(
+                    f"{path}: segment chunks carry no aggregate ids; rebuild the "
+                    "segment with build_segment_from_topic to restore through it")
+            num_chunks += 1
+            init = None
+            if track:
+                hits = [(i, a) for i, a in enumerate(chunk.aggregate_ids)
+                        if a in where]
+                if hits:
+                    init = engine.init_carry_np(chunk.num_aggregates)
+                    for name, col in init.items():
+                        for i, a in hits:
+                            ci, row = where[a]
+                            col[i] = chunk_states[ci][name][row]
+            if use_resident:
+                if wire_cache:
+                    with stage("restore.wire") as cached:
+                        wire, hit = _chunk_wire(engine, path, chunk,
+                                                build_id=extra.get("build_id"))
+                        cached.set_attribute("hit", hit)
+                        cached.set_attribute("bytes", _wire_nbytes(wire))
+                    wire_hits += hit
+                    wire_misses += not hit
+                else:
+                    wire = engine.pack_resident(chunk)
+                resident = engine.upload_resident(wire)
+                res = engine.replay_resident(resident, init_carry=init)
+            else:
+                res = engine.replay_columnar(chunk, init_carry=init)
+            if track:
+                chunk_states.append({k: np.asarray(v)
+                                     for k, v in res.states.items()})
+                ci = len(chunk_states) - 1
+                for i, agg_id in enumerate(chunk.aggregate_ids):
+                    where[agg_id] = (ci, i)
+            with stage("restore.decode", aggregates=chunk.num_aggregates):
+                states = decode_states(replay_spec.registry.state, res.states)
+            with stage("restore.writeback",
+                       aggregates=chunk.num_aggregates) as back:
+                back.set_attribute("bytes", _write_back(
+                    store, chunk.aggregate_ids, states, serialize_state,
+                    decode_state, restored))
+            num_events += res.num_events
+        # snapshot sections apply in file order AFTER chunks: a delta snapshot for
+        # an aggregate supersedes its (older) chunk-folded state, latest-wins
+        with stage("restore.snapshots") as snaps:
+            count = 0
+            for key, value in read_segment_snapshots(path,
+                                                     partitions=part_filter):
+                store.put(key, value)
+                restored.add(key)
+                count += 1
+            snaps.set_attribute("snapshots", count)
+        num_aggregates = len(restored)
+        root.attributes.update(
+            chunks=num_chunks, aggregates=num_aggregates, events=num_events,
+            wire_hits=wire_hits, wire_misses=wire_misses)
 
     # indexer priming: the segment covers the state topic up to its build-time
     # state watermarks. Empty when the segment was built without a state topic —
@@ -550,6 +603,25 @@ def restore_from_segment(
                   if part_filter is None or int(p) in part_filter}
     return RestoreResult(num_aggregates=num_aggregates, num_events=num_events,
                          watermarks=watermarks, backend="segment")
+
+
+def _write_back(store: KeyValueStore, aggregate_ids, states, serialize_state,
+                decode_state, restored: set) -> int:
+    """A chunk's decoded states into the store, an aggregate at a time: the id
+    put back, the caller's hooks, ``store.put``; ``restored`` gains the ids
+    stored. Returns the bytes stored."""
+    written = 0
+    for agg_id, state in zip(aggregate_ids, states):
+        if state is None:
+            continue
+        state = _with_aggregate_id(state, agg_id)
+        if decode_state is not None:
+            state = decode_state(agg_id, state)
+        value = serialize_state(agg_id, state)
+        store.put(agg_id, value)
+        written += len(value)
+        restored.add(agg_id)
+    return written
 
 
 def _with_aggregate_id(state: Any, aggregate_id: str) -> Any:

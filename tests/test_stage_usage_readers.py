@@ -211,9 +211,15 @@ def test_every_reader_has_its_entry_in_the_manifest():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         man = json.load(f)
-    cells = [w["name"] for w in man["workloads"]]
+    rebuilds = [w["name"] for w in man["workloads"]
+                if w["name"].startswith("rebuild-")]
+    # a restore's chunks are loaded packed and fold under one root stage, so
+    # the readers of the pack, of the upload's process figures and of the
+    # rebuilds' trace ids find nothing to read in the restore cell
+    in_a_restore = {"h2d_put_gbps", "replay_host_pct", "fetch_ratio"}
     entries = {m["name"]: m for m in man["per_layer"]}
     for name in READERS:
-        assert entries[name]["workloads"] == cells, name
+        assert entries[name]["workloads"] == rebuilds + (
+            ["restore-cart-segment"] if name in in_a_restore else []), name
         assert entries[name]["layer"] == "Cold fold programs"
         assert entries[name]["moves"] == "rebuild_events_per_s"
