@@ -15,6 +15,7 @@ transposes to time-major itself.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Mapping, Sequence
 
@@ -213,20 +214,26 @@ def decode_events(registry: SchemaRegistry, enc: EncodedEvents) -> list[list[Any
 _EXCLUDED_DEFAULTS = {str: "", int: 0, float: 0.0, bool: False}
 
 
-def _construct(cls: type, kwargs: dict[str, Any]) -> Any:
-    """Build a dataclass instance, filling fields excluded from the tensor schema
-    (e.g. aggregate-id strings) with neutral defaults."""
-    import dataclasses
-
+def _excluded_defaults(cls: type, given) -> dict[str, Any]:
+    """Neutral values for the dataclass fields of ``cls`` that ``given`` (the
+    names the tensor schema carries) leaves out, e.g. aggregate-id strings. A
+    field with a default or a factory of its own is left to the class."""
+    out: dict[str, Any] = {}
     for f in dataclasses.fields(cls):
-        if f.name in kwargs:
+        if f.name in given:
             continue
         if f.default is not dataclasses.MISSING or f.default_factory is not dataclasses.MISSING:  # type: ignore[misc]
             continue
         ann = f.type if isinstance(f.type, type) else {"str": str, "int": int,
                                                        "float": float, "bool": bool}.get(str(f.type))
-        kwargs[f.name] = _EXCLUDED_DEFAULTS.get(ann, None)
-    return cls(**kwargs)
+        out[f.name] = _EXCLUDED_DEFAULTS.get(ann, None)
+    return out
+
+
+def _construct(cls: type, kwargs: dict[str, Any]) -> Any:
+    """Build a dataclass instance, filling fields excluded from the tensor schema
+    (e.g. aggregate-id strings) with neutral defaults."""
+    return cls(**kwargs, **_excluded_defaults(cls, kwargs))
 
 
 def encode_states(schema: StateSchema, states: Sequence[Any]) -> dict[str, np.ndarray]:
@@ -237,11 +244,77 @@ def encode_states(schema: StateSchema, states: Sequence[Any]) -> dict[str, np.nd
     return out
 
 
+_KIND_TYPES = {"b": bool, "i": int, "u": int, "f": float}
+
+
+def state_columns(schema: StateSchema, tree: Mapping[str, np.ndarray],
+                  count: int | None = None) -> list[list]:
+    """The first ``count`` rows (all by default) of a state tree as plain
+    Python values, a list a schema field in field order: what
+    :func:`state_materializer`'s constructor indexes. One C-speed ``tolist()``
+    a column gives every cell the type ``StateSchema.from_record`` converts it
+    to (bool / int / float by the field's dtype kind); only a column that
+    arrives in another kind than its field's is converted a cell."""
+    cols = []
+    for f in schema.fields:
+        a = np.asarray(tree[f.name])
+        col = (a if count is None else a[:count]).tolist()
+        want = _KIND_TYPES.get(f.dtype.kind)
+        if want is not None and _KIND_TYPES.get(a.dtype.kind) is not want:
+            col = list(map(want, col))
+        cols.append(col)
+    return cols
+
+
+def state_materializer(schema: StateSchema, decode_state=None, *,
+                       with_ids: bool = False):
+    """``make(aggregate_id, cols, j)``: row ``j`` of :func:`state_columns`'
+    ``cols`` as one state object of ``schema.cls``. The row-to-state step of
+    every bulk path (:func:`decode_states` for the restores of
+    ``store/restore.py``, the resident plane's gather lane), worked out once a
+    schema and not once a row: the field names in order, the excluded fields'
+    neutral values (:func:`_excluded_defaults`) and where the aggregate id
+    goes are compiled into one keyword call of the class.
+
+    Without ``with_ids`` the state is ``schema.from_record``'s of the row and
+    ``aggregate_id`` is not read. With it, the state is what
+    ``store.restore._with_aggregate_id`` makes of that: a dataclass field
+    named ``aggregate_id`` which the class gives no default, or an empty one,
+    takes the id at construction (a non-empty default or a factory is left
+    to the class). ``decode_state(aggregate_id, state)``, the model's hook,
+    is then called on every state when given."""
+    cls = schema.cls
+    names = schema.field_names
+    fixed = _excluded_defaults(cls, names)
+    parts = [f"{n}=c[{i}][j]" for i, n in enumerate(names)]
+    if with_ids:
+        own = next((f for f in dataclasses.fields(cls)
+                    if f.name == "aggregate_id" and f.name not in names), None)
+        if own is not None and own.default_factory is dataclasses.MISSING and (  # type: ignore[misc]
+                own.default is dataclasses.MISSING or not own.default):
+            fixed.pop("aggregate_id", None)
+            parts.append("aggregate_id=a")
+    # codegen the constructor call (field names are dataclass identifiers):
+    # one keyword call a row indexing straight into the tolist'd columns, no
+    # kwargs dict, no per-row tuple
+    consts = {f"_fixed{i}": v for i, v in enumerate(fixed.values())}
+    parts += [f"{n}={k}" for n, k in zip(fixed, consts)]
+    base = eval(  # noqa: S307 — names come from dataclass fields
+        f"lambda a, c, j: _cls({', '.join(parts)})", {"_cls": cls, **consts})
+    if decode_state is None:
+        return base
+    return lambda agg_id, c, j: decode_state(agg_id, base(agg_id, c, j))
+
+
 def decode_states(schema: StateSchema, tree: Mapping[str, np.ndarray]) -> list[Any]:
-    """Inverse of :func:`encode_states`."""
-    arrays = {f.name: np.asarray(tree[f.name]) for f in schema.fields}
-    b = len(next(iter(arrays.values()))) if arrays else 0
-    return [schema.from_record({n: a[i] for n, a in arrays.items()}) for i in range(b)]
+    """Inverse of :func:`encode_states`: one state object a row, equal to
+    ``schema.from_record`` of that row (types included), built by
+    :func:`state_materializer` from :func:`state_columns`."""
+    cols = state_columns(schema, tree)
+    if not cols:
+        return []
+    make = state_materializer(schema)
+    return [make(None, cols, j) for j in range(len(cols[0]))]
 
 
 def bucket_lengths(lengths: Sequence[int], buckets: Sequence[int]) -> dict[int, list[int]]:

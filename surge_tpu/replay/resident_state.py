@@ -61,7 +61,8 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from surge_tpu.codec.tensor import encode_events, encode_events_columnar
+from surge_tpu.codec.tensor import (encode_events, encode_events_columnar,
+                                    state_columns, state_materializer)
 from surge_tpu.codec.wire import WireFormat
 from surge_tpu.common import (Ack, BackgroundTask, Controllable, logger,
                               spawn_reaped)
@@ -225,7 +226,8 @@ class ResidentStatePlane(Controllable):
         self._wire = WireFormat(spec.registry, self.derived)
         self._fields = spec.registry.state.fields
         self._dtypes = {f.name: np.dtype(f.dtype) for f in self._fields}
-        self._make_state = self._build_state_materializer()
+        self._make_state = state_materializer(
+            spec.registry.state, decode_state, with_ids=True)
         # a remote (broker) log turns end_offset into a blocking RPC — the
         # read path's freshness check must ride the executor there, never
         # the event loop it shares with the command path
@@ -294,57 +296,15 @@ class ResidentStatePlane(Controllable):
             w *= 2
         return frozenset((lb, wb) for lb in lanes for wb in widths)
 
-    def _build_state_materializer(self):
-        """Precompiled row → domain-state constructor, the batch read path's
-        per-row cost. Semantically identical to ``StateSchema.from_record`` +
-        ``restore._with_aggregate_id`` + ``decode_state``, but with the
-        per-field dispatch (np-scalar coercion, excluded-field defaults,
-        dataclasses.replace for the id) hoisted out of the per-row loop: the
-        gather lane hands it plain Python scalars off one C-speed
-        ``ndarray.tolist()`` per column."""
-        import dataclasses
-
-        from surge_tpu.codec.tensor import _EXCLUDED_DEFAULTS
-
-        cls = self.spec.registry.state.cls
-        names = [f.name for f in self._fields]
-        extras: Dict[str, Any] = {}
-        has_agg_id = False
-        if dataclasses.is_dataclass(cls):
-            for f in dataclasses.fields(cls):
-                if f.name == "aggregate_id":
-                    has_agg_id = True
-                    continue
-                if (f.name in names
-                        or f.default is not dataclasses.MISSING
-                        or f.default_factory is not dataclasses.MISSING):  # type: ignore[misc]
-                    continue
-                ann = (f.type if isinstance(f.type, type)
-                       else {"str": str, "int": int, "float": float,
-                             "bool": bool}.get(str(f.type)))
-                extras[f.name] = _EXCLUDED_DEFAULTS.get(ann, None)
-        decode = self.decode_state
-        # codegen the constructor call (field names are dataclass
-        # identifiers): one keyword call per row indexing straight into the
-        # tolist'd columns — no kwargs dict, no per-row tuple. This runs once
-        # per gathered row on the read hot path.
-        parts = (["aggregate_id=a"] if has_agg_id else [])
-        parts += [f"{n}=c[{i}][j]" for i, n in enumerate(names)]
-        parts += [f"{n}=_extras[{n!r}]" for n in extras]
-        base = eval(  # noqa: S307 — names come from dataclass fields
-            f"lambda a, c, j: _cls({', '.join(parts)})",
-            {"_cls": cls, "_extras": extras})
-        if decode is None:
-            return base
-        return lambda agg_id, c, j: decode(agg_id, base(agg_id, c, j))
-
     def _states_of_batch(self, ids: Sequence[str],
                          rows: Mapping[str, np.ndarray], k: int) -> list:
-        """Materialize ``k`` gathered rows into domain states. One
-        ``tolist()`` per column converts every cell to the exact Python type
-        ``from_record`` would produce (bool/int/float by dtype kind), then
-        the precompiled constructor runs per row."""
-        cols = [rows[f.name][:k].tolist() for f in self._fields]
+        """Materialize ``k`` gathered rows into domain states, the batch read
+        path's per-row cost, through the bulk restores' own materializer
+        (``codec.tensor.state_materializer``: ``StateSchema.from_record`` +
+        ``restore._with_aggregate_id`` + ``decode_state`` with the per-field
+        dispatch worked out once a schema), fed plain Python scalars off one
+        C-speed ``ndarray.tolist()`` a column."""
+        cols = state_columns(self.spec.registry.state, rows, k)
         make = self._make_state
         return [make(agg, cols, j) for j, agg in enumerate(ids)]
 

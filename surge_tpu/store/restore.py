@@ -170,15 +170,10 @@ def restore_from_events(
     else:
         raise ValueError(f"unknown replay backend {backend!r}")
 
-    for agg_id, state in zip(agg_ids, states):
-        if state is None:
-            continue
-        state = _with_aggregate_id(state, agg_id)
-        if decode_state is not None and backend == "tpu":
-            # decode_state maps tensor-schema records back to domain states (e.g.
-            # Vocab-decoded strings); cpu-path states are already domain objects
-            state = decode_state(agg_id, state)
-        store.put(agg_id, serialize_state(agg_id, state))
+    # decode_state maps tensor-schema records back to domain states (e.g.
+    # Vocab-decoded strings); cpu-path states are already domain objects
+    _write_back(store, agg_ids, states, serialize_state,
+                decode_state if backend == "tpu" else None, set())
     return RestoreResult(num_aggregates=len(agg_ids), num_events=num_events,
                          watermarks=watermarks, backend=backend)
 
@@ -255,13 +250,8 @@ def _restore_events_checkpointed(log, events_topic: str, store, parts, *,
     else:
         raise ValueError(f"unknown replay backend {backend!r}")
 
-    for agg_id, state in zip(agg_ids, states):
-        if state is None:
-            continue
-        state = _with_aggregate_id(state, agg_id)
-        if decode_state is not None and backend == "tpu":
-            state = decode_state(agg_id, state)
-        store.put(agg_id, serialize_state(agg_id, state))
+    _write_back(store, agg_ids, states, serialize_state,
+                decode_state if backend == "tpu" else None, set())
     # untouched aggregates restore their checkpointed bytes verbatim (the
     # writer serialized them with this same serialize_state, so bytes match
     # the full fold exactly); folded-to-None snapshots stay unwritten, like
@@ -350,14 +340,12 @@ def _restore_events_cpu_ranges(log, events_topic: str, store, parts, *,
                 logs.setdefault(rec.key, []).append(
                     deserialize_event(rec.value))
                 num_events += 1
-        for agg_id, events in logs.items():
-            init = (model.initial_state(agg_id)
-                    if hasattr(model, "initial_state") else None)
-            state = fold_events(model, init, events)
-            if state is None:
-                continue
-            state = _with_aggregate_id(state, agg_id)
-            store.put(agg_id, serialize_state(agg_id, state))
+        # folded as the write-back asks for them: one state alive at a time
+        states = (fold_events(model, model.initial_state(agg_id)
+                              if hasattr(model, "initial_state") else None,
+                              events)
+                  for agg_id, events in logs.items())
+        _write_back(store, logs, states, serialize_state, None, set())
         num_aggregates += len(logs)
     return RestoreResult(
         num_aggregates=num_aggregates, num_events=num_events,
@@ -462,15 +450,19 @@ def restore_from_segment(
     packed wire comes from the cache beside the segment (:func:`_chunk_wire`,
     unless ``surge.replay.segment-wire-cache`` is off) and goes through
     ``upload_resident`` and ``replay_resident``, on a mesh the columns stream
-    through ``replay_columnar``; then **the write-back**, an aggregate at a
-    time: :func:`~surge_tpu.codec.tensor.decode_states` builds one state
-    object a row of the pulled columns, and each gets its id back
-    (:func:`_with_aggregate_id` for a field named ``aggregate_id``, then
-    ``decode_state(aggregate_id, state)``: the model's own hook, which
-    ``SurgeCommandBusinessLogic.decode_state`` hands the engine, for whatever
-    else the tensor schema cannot carry, a cart's ``cart_id`` or a
-    vocabulary's strings), is turned to bytes by ``serialize_state(aggregate_id,
-    state)`` and stored under its id by ``store.put``.
+    through ``replay_columnar``; then the pulled columns become stored bytes in
+    two steps. :func:`~surge_tpu.codec.tensor.decode_states` takes one
+    ``tolist()`` a column and calls the state class once a row, through the
+    constructor :func:`~surge_tpu.codec.tensor.state_materializer` compiles
+    once a chunk from the schema (field names, the excluded fields' neutral
+    values). **The write-back** (:func:`_write_back`) then goes through the
+    chunk in id order: a state whose class has a field named ``aggregate_id``
+    gets its id back (the class is asked once, not every row), then the
+    caller's two hooks run once a row, ``decode_state(aggregate_id, state)``
+    (the model's own, which ``SurgeCommandBusinessLogic.decode_state`` hands
+    the engine, for whatever else the tensor schema cannot carry: a cart's
+    ``cart_id``, a vocabulary's strings) and ``serialize_state(aggregate_id,
+    state)``, whose bytes ``store.put`` stores under the id as they are.
 
     ``partitions`` restores only chunks/snapshot sections recorded for those
     source partitions (per-assigned-task restore, SURVEY.md §3.3): a multi-node
@@ -607,29 +599,45 @@ def restore_from_segment(
 
 def _write_back(store: KeyValueStore, aggregate_ids, states, serialize_state,
                 decode_state, restored: set) -> int:
-    """A chunk's decoded states into the store, an aggregate at a time: the id
-    put back, the caller's hooks, ``store.put``; ``restored`` gains the ids
-    stored. Returns the bytes stored."""
+    """A chunk's states into the store, in the chunk's id order: a ``None``
+    state is skipped before any hook; every other gets its id back
+    (:func:`_with_aggregate_id`'s rule, its question of the class asked once a
+    class and not once a row), then ``decode_state(aggregate_id, state)`` once
+    where the caller gave one, ``serialize_state(aggregate_id, state)`` once
+    and ``store.put(aggregate_id, value)``: the stored bytes are whatever
+    ``serialize_state`` returned. ``restored`` gains the ids stored, in one
+    update. Returns the bytes stored."""
+    put = store.put
+    stored = []
     written = 0
+    cls, takes_id = None, False
     for agg_id, state in zip(aggregate_ids, states):
         if state is None:
             continue
-        state = _with_aggregate_id(state, agg_id)
+        if type(state) is not cls:
+            cls = type(state)
+            takes_id = _has_aggregate_id(cls)
+        if takes_id and not state.aggregate_id:
+            state = dataclasses.replace(state, aggregate_id=agg_id)
         if decode_state is not None:
             state = decode_state(agg_id, state)
         value = serialize_state(agg_id, state)
-        store.put(agg_id, value)
+        put(agg_id, value)
         written += len(value)
-        restored.add(agg_id)
+        stored.append(agg_id)
+    restored.update(stored)
     return written
+
+
+def _has_aggregate_id(cls: type) -> bool:
+    """Whether states of ``cls`` carry a dataclass field ``aggregate_id``."""
+    return dataclasses.is_dataclass(cls) and any(
+        f.name == "aggregate_id" for f in dataclasses.fields(cls))
 
 
 def _with_aggregate_id(state: Any, aggregate_id: str) -> Any:
     """Re-attach the aggregate id to states reconstructed from tensor columns (string
     fields are excluded from the tensor schema, surge_tpu.codec.schema)."""
-    if dataclasses.is_dataclass(state) and any(
-            f.name == "aggregate_id" for f in dataclasses.fields(state)):
-        current = getattr(state, "aggregate_id", None)
-        if not current:
-            return dataclasses.replace(state, aggregate_id=aggregate_id)
+    if _has_aggregate_id(type(state)) and not state.aggregate_id:
+        return dataclasses.replace(state, aggregate_id=aggregate_id)
     return state
