@@ -80,8 +80,9 @@ class ColumnarEvents:
     source_ordinal: int | None = None
     # how the chunk lay in its segment file (set by read_segment):
     # ``stored_bytes`` read for it, the ``raw_bytes`` they decode to (columns
-    # and ids), and its ``codec``: "slz", "raw", or "mixed" where the writer
-    # compressed some of its payloads only
+    # and ids), its ``codec``: "slz", "raw", or "mixed" where the writer
+    # compressed some of its payloads only, and the column payloads decoded
+    # and seeked past under a projection (``columns_read``, ``columns_skipped``)
     source_stored: dict | None = None
 
     @property

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import json
 import struct
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, Optional
 
 import numpy as np
@@ -62,10 +63,24 @@ def _encode_array(arr: np.ndarray):
     return seg.CODEC_RAW, raw, len(raw)
 
 
+#: threads that decompress one chunk's column payloads side by side
+#: (:func:`read_segment`): the codec is native code that releases the
+#: interpreter lock, and a chunk has a handful of payloads
+_DECODE_THREADS = 4
+
+
 def _decode_array(data: bytes, codec: int, raw_len: int, dtype: np.dtype) -> np.ndarray:
-    if codec == seg.CODEC_SLZ:
-        data = seg.slz_decompress(data, raw_len)
-    return np.frombuffer(data, dtype=dtype)
+    """A stored column payload as its array: a raw one as a view of the bytes
+    read, a compressed one decompressed straight into the array's own buffer
+    (a column is tens of megabytes: a staging buffer and a copy out of it cost
+    as much as the decompression)."""
+    if codec != seg.CODEC_SLZ:
+        return np.frombuffer(data, dtype=dtype)
+    if raw_len % dtype.itemsize:
+        raise ValueError(f"a payload of {raw_len} bytes is no whole {dtype} column")
+    out = np.empty(raw_len // dtype.itemsize, dtype=dtype)
+    seg.slz_decompress_into(data, out)
+    return out
 
 
 class ColumnarSegmentWriter:
@@ -264,8 +279,11 @@ def read_segment(path: str,
                  partitions: Optional[set] = None,
                  columns: Optional[Iterable[str]] = None
                  ) -> Iterator[ColumnarEvents]:
-    """Stream the segment's chunks back as ColumnarEvents (zero-copy frombuffer
-    views over the decompressed column bytes). ``partitions`` keeps only chunks
+    """Stream the segment's chunks back as ColumnarEvents (a raw payload as a
+    zero-copy view of the bytes read, a compressed one decompressed straight
+    into its own array; a chunk's payloads are read in file order and
+    decompressed side by side on ``_DECODE_THREADS`` threads, which last as
+    long as the iteration). ``partitions`` keeps only chunks
     whose recorded source partition is in the set — chunks without partition
     metadata (pre-scoping segments) always pass, and their payloads are seeked
     past, not decompressed, when filtered out.
@@ -280,7 +298,10 @@ def read_segment(path: str,
     if partitions is not None:
         partitions = {int(p) for p in partitions}
     wanted = None if columns is None else set(columns)
-    with open(path, "rb") as f:
+    with ThreadPoolExecutor(
+            max_workers=_DECODE_THREADS,
+            thread_name_prefix="surge-segment-decode") as decoders, \
+            open(path, "rb") as f:
         size = _os.fstat(f.fileno()).st_size
         head = f.read(8)
         if head[:4] != MAGIC:
@@ -327,18 +348,22 @@ def read_segment(path: str,
             c_derived = (dict(meta["chunk_derived"]) if "chunk_derived" in meta
                          else dict(derived))
             arrays = {}
-            stored_bytes = raw_bytes = 0
+            stored_bytes = raw_bytes = columns_skipped = 0
             codecs = set()
             for name, codec, stored_len, raw_len in meta["cols"]:
                 if (wanted is not None
                         and name not in ("agg_idx", "type_ids")
                         and name not in wanted):
                     f.seek(stored_len, 1)  # projected out: never decompressed
+                    columns_skipped += 1
                     continue
                 dtype = (c_agg if name == "agg_idx"
                          else c_type if name == "type_ids"
                          else c_cols[name])
-                arrays[name] = _decode_array(f.read(stored_len), codec, raw_len, dtype)
+                # the payloads are read in file order and decompressed side
+                # by side, while the ids below are read and split
+                arrays[name] = decoders.submit(
+                    _decode_array, f.read(stored_len), codec, raw_len, dtype)
                 stored_bytes += stored_len
                 raw_bytes += raw_len
                 codecs.add(codec)
@@ -356,6 +381,8 @@ def read_segment(path: str,
                     raise ValueError(
                         f"{path}: id count {len(ids)} != aggregates "
                         f"{meta['num_aggregates']} — corrupt chunk")
+            arrays = {name: decoded.result()
+                      for name, decoded in arrays.items()}
             yield ColumnarEvents(
                 num_aggregates=meta["num_aggregates"],
                 agg_idx=arrays.pop("agg_idx"),
@@ -367,7 +394,11 @@ def read_segment(path: str,
                 source_stored={
                     "stored_bytes": stored_bytes, "raw_bytes": raw_bytes,
                     "codec": ("mixed" if len(codecs) > 1 else
-                              "slz" if codecs == {seg.CODEC_SLZ} else "raw")})
+                              "slz" if codecs == {seg.CODEC_SLZ} else "raw"),
+                    # column payloads decoded (agg_idx and type_ids among
+                    # them) and seeked past under a projection
+                    "columns_read": len(meta["cols"]) - columns_skipped,
+                    "columns_skipped": columns_skipped})
 
 
 def segment_info(path: str) -> dict:

@@ -87,6 +87,24 @@ def slz_decompress(data: bytes, uncompressed_len: int) -> bytes:
     return out.raw[:uncompressed_len]
 
 
+def slz_decompress_into(data: bytes, out) -> None:
+    """Decompress ``data`` straight into ``out``, a writable C-contiguous
+    numpy array of exactly the uncompressed size: no zeroed staging buffer and
+    no copy out of it, which :func:`slz_decompress` pays to hand back
+    ``bytes``."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native segment codec not built (csrc/build.sh) but a "
+                           "compressed block was encountered")
+    if not (out.flags.c_contiguous and out.flags.writeable):
+        raise ValueError("decompression needs a writable contiguous array")
+    n = lib.surge_lz_decompress(
+        data, len(data), ctypes.cast(out.ctypes.data, ctypes.c_char_p),
+        out.nbytes)
+    if n != out.nbytes:
+        raise ValueError(f"block decompression failed ({n} != {out.nbytes})")
+
+
 # -- record framing ---------------------------------------------------------------------
 
 

@@ -31,6 +31,13 @@ Design:
 - **Bucketed shapes.** Event and aggregate axes pad to power-of-two buckets
   (events at least ``surge.query.chunk-events``), so a steady stream of
   different-sized chunks reuses a handful of compiled programs.
+- **Chunks in a pipeline.** A segment's next chunk is read on a reader
+  thread, and a chunk's outputs are awaited only once the next chunk's
+  program is dispatched, so the host's read, grouping and upload of chunk
+  ``k + 1`` run beside the device's reduce of chunk ``k``; the group column
+  is factorised without a sort where its range allows
+  (:func:`_factorize_group`). Every stage is a ``replay.scan*`` span
+  (docs/observability.md, "Replay profiler").
 - **Exactness contract.** Arithmetic happens in the DEVICE dtype of each
   column (with x64 off an int64 column reduces in int32); the numpy host
   reference (:func:`scan_reference`) mirrors that bit for bit, and the
@@ -45,20 +52,36 @@ Served through ``SurgeEngine.query()`` / ``query_states()`` and the admin
 from __future__ import annotations
 
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
-from typing import Any, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+from typing import (Any, Dict, Iterable, Iterator, List, Mapping, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
 from surge_tpu.codec.tensor import ColumnarEvents
 from surge_tpu.config import Config, default_config
+from surge_tpu.replay.profiler import ReplayProfiler
+from surge_tpu.tracing import default_tracer
 
 __all__ = ["Predicate", "Aggregate", "ScanQuery", "StateQuery", "QueryResult",
            "QueryEngine", "scan_reference", "state_query_reference",
-           "predicate_mask_np"]
+           "predicate_mask_np", "SCAN_JIT_NAMES"]
 
 #: comparison ops a predicate may use (conjunctive; applied on device)
 _OPS = ("==", "!=", "<", "<=", ">", ">=")
+
+#: the functions the scan's jitted programs are made from, single-device and
+#: sharded alike: XLA names a program ``jit_<function>``, and the benchmark's
+#: trace reduction maps those names to a layer (benchmarks/programs/scan.json;
+#: tests/test_cart_projection.py holds the two to each other, as
+#: ``engine.COLD_PATH_JIT_NAMES`` is held to programs/cold-fold.json)
+SCAN_JIT_NAMES = ("scan",)
+
+#: a presence table over a group column's value range costs one pass over the
+#: range beside two over the events: taken where the range is at most this many
+#: times the events (:func:`_factorize_group`)
+_TABLE_SPAN_PER_EVENT = 4
 
 
 @dataclass(frozen=True)
@@ -308,22 +331,49 @@ def predicate_mask_np(cols: Mapping[str, np.ndarray], type_ids: np.ndarray,
     return mask
 
 
-def _group_key_str(v, dt: np.dtype) -> str:
-    """Stable string key for one group-by column value (views and changefeeds
-    key rows by these across processes, so the format is part of the wire
-    contract)."""
-    if dt.kind in "iub":
-        return str(int(v))
-    return repr(float(v))
+def _group_keys(vals: np.ndarray) -> List[str]:
+    """Stable string keys for a group-by column's values (views and
+    changefeeds key rows by these across processes, so the format is part of
+    the wire contract): an integer or a bool as ``str(int(v))``, a float as
+    ``repr(float(v))``."""
+    if vals.dtype.kind == "b":
+        vals = vals.view(np.uint8)
+    return [repr(v) for v in vals.tolist()]  # an int's repr is its str
 
 
-def _factorize_group(col: np.ndarray) -> Tuple[List[str], np.ndarray]:
+def _factorize_group(col: np.ndarray) -> Tuple[List[str], np.ndarray, str]:
     """Distinct values of a DEVICE-dtype group column → (string keys in
-    ascending value order, int32 group index per event)."""
+    ascending value order, int32 group index per event, how).
+
+    The result is ``np.unique(col, return_inverse=True)``'s, value for value
+    and index for index; ``how`` says which way it was reached, chosen from
+    the column itself. ``"table"``: an integer (or bool) column whose values
+    span at most ``_TABLE_SPAN_PER_EVENT`` times its length takes a presence
+    table over ``[min, max]``, whose running count is every value's rank: two
+    linear passes over the events and one over the range, no sort. ``"sort"``:
+    any other column (a float, a wide or sparse range, an empty chunk) takes
+    ``np.unique``'s argsort."""
+    if col.dtype.kind in "iub" and col.size:
+        values = col.view(np.uint8) if col.dtype.kind == "b" else col
+        lo, hi = int(values.min()), int(values.max())
+        span = hi - lo + 1
+        if span <= _TABLE_SPAN_PER_EVENT * col.size:
+            # offsets from ``lo`` in the unsigned kin of the column's dtype:
+            # modulo its width the difference is the true one, in [0, span)
+            if lo == 0:
+                off = values
+            else:
+                unsigned = values.view(f"u{values.dtype.itemsize}")
+                off = unsigned - unsigned.dtype.type(
+                    lo % (1 << 8 * values.dtype.itemsize))
+            present = np.zeros((span,), dtype=bool)
+            present[off] = True
+            rank = np.cumsum(present, dtype=np.int32)
+            rank -= 1
+            vals = (np.flatnonzero(present) + lo).astype(col.dtype)
+            return _group_keys(vals), rank[off], "table"
     vals, inv = np.unique(col, return_inverse=True)
-    dt = np.dtype(col.dtype)
-    return ([_group_key_str(v, dt) for v in vals],
-            inv.astype(np.int32).reshape(-1))
+    return _group_keys(vals), inv.astype(np.int32).reshape(-1), "sort"
 
 
 def _sentinel(op: str, dt: np.dtype):
@@ -346,15 +396,28 @@ def _normalize_zero_match(out: Dict[str, np.ndarray], query: ScanQuery
     return out
 
 
-def _merge_scan_outputs(collected, query: ScanQuery, saw_ids: bool,
-                        has_dup: bool, seen: Dict[str, int]):
-    """Combine per-chunk RAW scan outputs into the final grouped columns.
+def _merge_scan_outputs(collected, query: ScanQuery):
+    """Combine per-chunk RAW scan outputs ``[(keys | None, outputs)]`` into
+    the final grouped columns.
 
-    Disjoint chunks (the common case, detected while streaming) concatenate;
-    chunks repeating an aggregate id — auto-extended segments append delta
-    chunks continuing base-chunk aggregates — MERGE into one row per id
-    (count/sum add, min/max combine over the sentinel-carrying partials).
-    Returns ``(aggregate_ids | None, columns)`` post-normalization."""
+    Disjoint chunks (the common case) concatenate; chunks repeating a key —
+    auto-extended segments append delta chunks continuing base-chunk
+    aggregates, and under ``group_by`` every chunk repeats the group values —
+    MERGE into one row per key (count/sum add, min/max combine over the
+    sentinel-carrying partials). Chunks without keys cannot be matched across
+    chunks and keep the disjointness contract. Returns ``(keys | None,
+    columns, repeated)`` post-normalization; ``repeated`` counts the chunk
+    rows whose key an earlier chunk had shown."""
+    saw_ids = all(ids_c is not None for ids_c, _out in collected)
+    seen: Dict[str, int] = {}
+    repeated = 0
+    if saw_ids:
+        for ids_c, _out in collected:
+            before = len(seen)
+            for a in ids_c:
+                seen.setdefault(a, len(seen))
+            repeated += len(ids_c) - (len(seen) - before)
+    has_dup = repeated > 0
     agg_specs = [(a.op, a.name) for a in query.aggregates if a.op != "count"]
     if not (saw_ids and has_dup):
         parts: Dict[str, List[np.ndarray]] = {}
@@ -370,7 +433,7 @@ def _merge_scan_outputs(collected, query: ScanQuery, saw_ids: bool,
         if not columns:
             columns = {"count": np.zeros((0,), np.int32)}
         return (ids if saw_ids else None,
-                _normalize_zero_match(columns, query))
+                _normalize_zero_match(columns, query), 0)
     b = len(seen)
     columns = {"count": np.zeros((b,), np.int32)}
     for ids_c, out in collected:
@@ -391,10 +454,24 @@ def _merge_scan_outputs(collected, query: ScanQuery, saw_ids: bool,
                 np.minimum.at(columns[name], idxs, col)
             else:
                 np.maximum.at(columns[name], idxs, col)
-    order = [None] * b
-    for a, i in seen.items():
-        order[i] = a
-    return order, _normalize_zero_match(columns, query)
+    return list(seen), _normalize_zero_match(columns, query), repeated
+
+
+def _read_ahead(chunks: Iterable[ColumnarEvents]) -> Iterator[ColumnarEvents]:
+    """``chunks``, each step taken one ahead of the consumer on a thread of
+    its own: a segment's next chunk is read and decompressed (native code,
+    which releases the interpreter lock) while the scan groups, puts and
+    reduces this one, so the scan waits for the device and not for the disk.
+    One step is under way at a time, in order; what it raises is raised to
+    the consumer at that chunk; at most one chunk waits beside the one being
+    scanned."""
+    stream = iter(chunks)
+    with ThreadPoolExecutor(max_workers=1,
+                            thread_name_prefix="surge-scan-read") as reader:
+        ahead = reader.submit(next, stream, None)
+        while (chunk := ahead.result()) is not None:
+            ahead = reader.submit(next, stream, None)
+            yield chunk
 
 
 class QueryEngine:
@@ -428,6 +505,9 @@ class QueryEngine:
                           for s in self.registry.event_schemas}
         self.stats = {"scans": 0, "chunks": 0, "scanned_events": 0,
                       "matched_events": 0}
+        #: every stage of a scan is a span of this profiler, in the
+        #: process-wide ring as the replay engine's are (one scan, one trace)
+        self.profiler = ReplayProfiler.counters(tracer=default_tracer())
 
     # -- helpers ------------------------------------------------------------------------
 
@@ -500,7 +580,15 @@ class QueryEngine:
         aggs = tuple((a.op, a.column, a.name) for a in query.aggregates)
         has_types = query.event_types is not None
 
-        def local_scan(agg_idx, type_ids, valid, pred_vals, type_allow, cols):
+        def partials(agg_idx, type_ids, first, n, pred_vals, type_allow,
+                     cols):
+            # rows past the chunk's ``n`` events hold whatever the host's
+            # buffer held: masked here, by position (``first`` is this
+            # shard's offset on the event axis), and their group index held
+            # in range
+            valid = first + jnp.arange(agg_idx.shape[0], dtype=jnp.int32) < n
+            agg_idx = jnp.where(valid, agg_idx, 0)
+
             def compare(cname, op, integral, j):
                 # one predicate leg, indexed into the FLAT pred_vals vector
                 # (conjunctive predicates first, then OR-group members)
@@ -571,8 +659,11 @@ class QueryEngine:
             return out
 
         if self.mesh is None or self._n_dev() <= 1:
-            prog = jax.jit(lambda ai, ti, va, pv, ta, cs:
-                           local_scan(ai, ti, va, pv, ta, cs))
+            def scan(agg_idx, type_ids, n, pred_vals, type_allow, cols):
+                return partials(agg_idx, type_ids, 0, n, pred_vals,
+                                type_allow, cols)
+
+            prog = jax.jit(scan)
         else:
             from jax.sharding import PartitionSpec as P
 
@@ -580,9 +671,10 @@ class QueryEngine:
             pe = P(axis)  # event axis, sharded
             pr = P()      # replicated (predicate values, type filter, output)
 
-            def sharded(agg_idx, type_ids, valid, pred_vals, type_allow, cols):
-                part = local_scan(agg_idx, type_ids, valid, pred_vals,
-                                  type_allow, cols)
+            def scan(agg_idx, type_ids, n, pred_vals, type_allow, cols):
+                first = jax.lax.axis_index(axis) * agg_idx.shape[0]
+                part = partials(agg_idx, type_ids, first, n, pred_vals,
+                                type_allow, cols)
                 # ONE collective per output column: partial per-aggregate
                 # reduces combine across the event shards
                 out: dict = {}
@@ -596,14 +688,14 @@ class QueryEngine:
                         out[name] = jax.lax.psum(v, axis)
                 return out
 
-            mapped = jax.shard_map(
-                sharded, mesh=self.mesh,
-                in_specs=(pe, pe, pe, pr, pr, {n: pe for n in col_names}),
+            scan = jax.shard_map(
+                scan, mesh=self.mesh,
+                in_specs=(pe, pe, pr, pr, pr, {n: pe for n in col_names}),
                 out_specs={name: pr for name in
                            ["count"] + [a[2] for a in aggs
                                         if a[0] != "count"]},
                 check_vma=False)
-            prog = jax.jit(mapped)
+            prog = jax.jit(scan)
         self._programs[key] = prog
         return prog
 
@@ -622,17 +714,41 @@ class QueryEngine:
         repeated group (delta chunks, per-refresh-round view folds) stay
         combinable. Returns ``(group keys, raw outputs)`` — keys are the
         chunk's aggregate ids, or under ``group_by`` the distinct group-column
-        values of THIS chunk as stable strings."""
+        values of THIS chunk as stable strings. The chunk's program is
+        dispatched and its outputs awaited at once; :meth:`scan_chunks` takes
+        the two halves apart."""
+        return self._collect_scan(self._dispatch_scan(colev, query, {}))
+
+    def _dispatch_scan(self, colev: ColumnarEvents, query: ScanQuery,
+                       buffers: dict) -> tuple:
+        """The first half of a chunk's scan, up to its program under way on
+        the device: ``(group keys, groups, outputs on the device, the reduce
+        stage's counts)`` for :meth:`_collect_scan`. ``buffers`` holds the
+        event-bucket host buffers the chunk's arrays are copied into, one an
+        array and bucket, made on first use and never cleared (the program
+        masks the rows past the chunk's events): the caller may hand the same
+        dict to a later chunk once this one's outputs are collected.
+
+        Three stages, each a span (docs/observability.md, "Replay profiler"):
+        ``replay.scan.group`` (under ``group_by``: the group column's distinct
+        values and every event's group index), ``replay.scan.h2d`` (the
+        arrays copied into the buffers and put on the device) and
+        ``replay.scan.dispatch`` (the program's asynchronous dispatch, with
+        its compilation on a first signature)."""
         import jax
 
+        stage = self.profiler.stage
         n = colev.num_events
         needed = tuple(query.columns_needed())
         cols_np = self._materialize_columns(colev, needed)
         if query.group_by is not None:
-            gcol = (colev.type_ids if query.group_by == "type_id"
-                    else cols_np[query.group_by])
-            gcol = gcol.astype(self._device_dtype(np.dtype(gcol.dtype)))
-            ids, grp_idx = _factorize_group(gcol)
+            with stage("scan.group") as grouped:
+                gcol = (colev.type_ids if query.group_by == "type_id"
+                        else cols_np[query.group_by])
+                gcol = gcol.astype(self._device_dtype(np.dtype(gcol.dtype)),
+                                   copy=False)
+                ids, grp_idx, how = _factorize_group(gcol)
+                grouped.attributes.update(distinct=len(ids), how=how)
             b = len(ids)
         else:
             ids, grp_idx = colev.aggregate_ids, colev.agg_idx
@@ -641,38 +757,57 @@ class QueryEngine:
         n_bucket = _pow2(max(n, 1), max(self._event_bucket, n_dev))
         b_bucket = _pow2(max(b, 1), 8)
 
-        agg_p = np.zeros((n_bucket,), dtype=np.int32)
-        agg_p[:n] = grp_idx
-        type_p = np.full((n_bucket,), -1, dtype=np.int32)
-        type_p[:n] = colev.type_ids
-        valid = np.zeros((n_bucket,), dtype=bool)
-        valid[:n] = True
-        cols_p: Dict[str, np.ndarray] = {}
-        for name in needed:
-            dt = self._device_dtype(cols_np[name].dtype)
-            cp = np.zeros((n_bucket,), dtype=dt)
-            cp[:n] = cols_np[name].astype(dt)
-            cols_p[name] = cp
-        pred_vals = np.asarray([p.value for p in query.all_predicates()],
-                               dtype=np.float64)
-        type_allow = (self.resolve_type_ids(query.event_types)
-                      if query.event_types is not None
-                      else np.zeros((0,), dtype=np.int32))
+        with stage("scan.h2d", padded_events=n_bucket) as h2d:
+            def padded(name: str, col: np.ndarray, dtype) -> np.ndarray:
+                # rows past ``n`` are never cleared: the program masks them
+                key = (name, n_bucket, np.dtype(dtype))
+                buf = buffers.get(key)
+                if buf is None:
+                    buf = buffers[key] = np.zeros((n_bucket,), dtype=dtype)
+                buf[:n] = col
+                return buf
 
-        if self.mesh is not None and n_dev > 1:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            events = (padded("", grp_idx, np.int32),
+                      padded("type_id", colev.type_ids, np.int32),
+                      {name: padded(name, cols_np[name], self._device_dtype(
+                          cols_np[name].dtype)) for name in needed})
+            pred_vals = np.asarray([p.value for p in query.all_predicates()],
+                                   dtype=np.float64)
+            type_allow = (self.resolve_type_ids(query.event_types)
+                          if query.event_types is not None
+                          else np.zeros((0,), dtype=np.int32))
+            scalars = (np.int32(n), pred_vals, type_allow)
 
-            sh = NamedSharding(self.mesh, P(self.mesh_axis))
-            rep = NamedSharding(self.mesh, P())
-            put_e = lambda a: jax.device_put(a, sh)  # noqa: E731
-            put_r = lambda a: jax.device_put(a, rep)  # noqa: E731
-        else:
-            put_e = put_r = lambda a: a  # noqa: E731
-        prog = self._program(query, n_bucket, b_bucket, needed)
-        out_dev = prog(put_e(agg_p), put_e(type_p), put_e(valid),
-                       put_r(pred_vals), put_r(type_allow),
-                       {k: put_e(v) for k, v in cols_p.items()})
-        return ids, {k: np.asarray(v)[:b] for k, v in out_dev.items()}
+            if self.mesh is not None and n_dev > 1:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                on_events = NamedSharding(self.mesh, P(self.mesh_axis))
+                replicated = NamedSharding(self.mesh, P())
+            else:
+                on_events = replicated = None  # the default device
+            agg_d, type_d, cols_d, n_d, pred_d, allow_d = jax.block_until_ready(
+                (*jax.device_put(events, on_events),
+                 *jax.device_put(scalars, replicated)))
+            leaves = jax.tree_util.tree_leaves(events)
+            h2d.attributes.update(
+                copied_bytes=n * sum(a.itemsize for a in leaves),
+                put_bytes=sum(a.nbytes for a in leaves) + sum(
+                    a.nbytes for a in scalars))
+        with stage("scan.dispatch"):
+            prog = self._program(query, n_bucket, b_bucket, needed)
+            out_dev = prog(agg_d, type_d, n_d, pred_d, allow_d, cols_d)
+        reduces = 1 + sum(1 for a in query.aggregates if a.op != "count")
+        return ids, b, out_dev, dict(bucket=n_bucket, group_bucket=b_bucket,
+                                     updates=n * reduces)
+
+    def _collect_scan(self, dispatched: tuple
+                      ) -> Tuple[Optional[List[str]], Dict[str, np.ndarray]]:
+        """The second half: ``replay.scan.reduce``, the wait for the program
+        and its outputs' way to the host, cut to the chunk's groups."""
+        ids, b, out_dev, counts = dispatched
+        with self.profiler.stage("scan.reduce", **counts):
+            out = {k: np.asarray(v)[:b] for k, v in out_dev.items()}
+        return ids, out
 
     def scan_chunks(self, chunks: Iterable[ColumnarEvents], query: ScanQuery
                     ) -> QueryResult:
@@ -684,48 +819,73 @@ class QueryEngine:
         the merge. Chunks without aggregate ids cannot be matched across
         chunks and keep the disjointness contract. Under ``group_by`` rows
         key by group value (the same value recurring across chunks merges
-        exactly like a repeated aggregate id)."""
+        exactly like a repeated aggregate id).
+
+        One scan is one trace: the root span ``replay.scan``, a chunk
+        ``replay.scan.read`` (one step of ``chunks``: of a segment, the wait
+        for its reader, which reads and decodes a chunk ahead; the last step
+        finds the end), :meth:`_dispatch_scan`'s stages and the
+        ``replay.scan.reduce`` of the chunk before (:meth:`_collect_scan`),
+        then ``replay.scan.merge``."""
         t0 = time.perf_counter()
+        stage = self.profiler.stage
         collected: List[Tuple[Optional[List[str]], Dict[str, np.ndarray]]] = []
-        saw_ids = True
-        has_dup = False
-        seen: Dict[str, int] = {}
-        scanned = matched = n_chunks = 0
-        for colev in chunks:
-            ids_c, out = self._raw_scan(colev, query)
-            collected.append((ids_c, out))
-            scanned += colev.num_events
-            matched += int(out["count"].sum())
-            n_chunks += 1
-            if ids_c is None:
-                saw_ids = False
-            elif saw_ids:
-                for a in ids_c:
-                    if a in seen:
-                        has_dup = True
-                    else:
-                        seen[a] = len(seen)
-        ids, columns = _merge_scan_outputs(collected, query, saw_ids,
-                                           has_dup, seen)
+        scanned = n_chunks = 0
+        # a chunk's program runs while the next chunk is read, grouped and
+        # put: its outputs are collected once the next one is under way, so
+        # the device always has a program waiting. Two sets of host buffers
+        # take turns, since a backend may read a put array where it lies
+        # until the program that takes it has ended
+        buffers: Tuple[dict, dict] = ({}, {})
+        under_way = None
+        with stage("scan", group_by=query.group_by or "",
+                   columns=len(query.columns_needed())) as root:
+            stream = iter(chunks)
+            while True:
+                with stage("scan.read") as read:
+                    colev = next(stream, None)
+                    if colev is not None and colev.source_stored is not None:
+                        read.attributes.update(
+                            {k: colev.source_stored[k] for k in (
+                                "stored_bytes", "raw_bytes", "columns_read",
+                                "columns_skipped")})
+                following = None
+                if colev is not None:
+                    following = self._dispatch_scan(colev, query,
+                                                    buffers[n_chunks % 2])
+                    n_chunks += 1
+                    scanned += colev.num_events
+                if under_way is not None:
+                    collected.append(self._collect_scan(under_way))
+                under_way = following
+                if colev is None:
+                    break
+            matched = sum(int(out["count"].sum()) for _ids, out in collected)
+            with stage("scan.merge") as merge:
+                ids, columns, repeated = _merge_scan_outputs(collected, query)
+                groups = len(next(iter(columns.values())))
+                merge.attributes.update(groups=groups, repeated=repeated)
+            root.attributes.update(chunks=len(collected), events=scanned,
+                                   matched=matched, groups=groups)
         self.stats["scans"] += 1
-        self.stats["chunks"] += n_chunks
+        self.stats["chunks"] += len(collected)
         self.stats["scanned_events"] += scanned
         self.stats["matched_events"] += matched
         return QueryResult(
-            aggregate_ids=ids, columns=columns,
-            num_aggregates=len(next(iter(columns.values()))),
-            scanned_events=scanned, matched_events=matched, chunks=n_chunks,
-            elapsed_s=time.perf_counter() - t0)
+            aggregate_ids=ids, columns=columns, num_aggregates=groups,
+            scanned_events=scanned, matched_events=matched,
+            chunks=len(collected), elapsed_s=time.perf_counter() - t0)
 
     def scan_segment(self, path: str, query: ScanQuery,
                      partitions: Optional[set] = None) -> QueryResult:
         """Scan a committed columnar segment file. Only the columns the query
-        touches are decompressed (projection pushdown into the reader)."""
+        touches are decompressed (projection pushdown into the reader), a
+        chunk ahead of the scan (:func:`_read_ahead`)."""
         from surge_tpu.log.columnar import read_segment
 
         return self.scan_chunks(
-            read_segment(path, partitions=partitions,
-                         columns=query.columns_needed()),
+            _read_ahead(read_segment(path, partitions=partitions,
+                                     columns=query.columns_needed())),
             query)
 
     # -- state queries (fold + filter + project) ----------------------------------------
@@ -855,10 +1015,7 @@ def scan_reference(chunks: Iterable[ColumnarEvents], query: ScanQuery,
     type_ids_of = {s.cls.__name__: s.type_id for s in registry.event_schemas}
     union_dts = {f.name: np.dtype(f.dtype) for f in registry.union_columns()}
     collected: List[Tuple[Optional[List[str]], Dict[str, np.ndarray]]] = []
-    saw_ids = True
-    has_dup = False
-    seen: Dict[str, int] = {}
-    total_b = scanned = matched = n_chunks = 0
+    scanned = matched = 0
     for colev in chunks:
         n = colev.num_events
         cols: Dict[str, np.ndarray] = {}
@@ -876,7 +1033,7 @@ def scan_reference(chunks: Iterable[ColumnarEvents], query: ScanQuery,
         if query.group_by is not None:
             gcol = (colev.type_ids if query.group_by == "type_id"
                     else cols[query.group_by])
-            ids_c, grp_idx = _factorize_group(gcol)
+            ids_c, grp_idx, _how = _factorize_group(gcol)
             b = len(ids_c)
         else:
             ids_c, grp_idx = colev.aggregate_ids, colev.agg_idx
@@ -912,24 +1069,13 @@ def scan_reference(chunks: Iterable[ColumnarEvents], query: ScanQuery,
                               np.where(mask, col, np.asarray(small, dt)))
             out[a.name] = acc  # raw: sentinels normalize after the merge
         collected.append((ids_c, out))
-        total_b += b
         scanned += n
         matched += int(count.sum())
-        n_chunks += 1
-        if ids_c is None:
-            saw_ids = False
-        elif saw_ids:
-            for a_id in ids_c:
-                if a_id in seen:
-                    has_dup = True
-                else:
-                    seen[a_id] = len(seen)
-    ids, columns = _merge_scan_outputs(collected, query, saw_ids, has_dup,
-                                       seen)
+    ids, columns, _repeated = _merge_scan_outputs(collected, query)
     return QueryResult(aggregate_ids=ids, columns=columns,
                        num_aggregates=len(next(iter(columns.values()))),
                        scanned_events=scanned, matched_events=matched,
-                       chunks=n_chunks)
+                       chunks=len(collected))
 
 
 def state_query_reference(states: Mapping[str, np.ndarray],

@@ -525,7 +525,9 @@ def restore_from_segment(
             with stage("restore.read") as read:
                 chunk = next(chunks, None)
                 if chunk is not None and chunk.source_stored is not None:
-                    read.attributes.update(chunk.source_stored)
+                    read.attributes.update(
+                        {k: chunk.source_stored[k]
+                         for k in ("stored_bytes", "raw_bytes", "codec")})
             if chunk is None:
                 break
             if chunk.aggregate_ids is None:

@@ -392,3 +392,34 @@ def test_rebuilt_segment_never_serves_stale_wires(tmp_path):
     for agg, st in exp2.items():
         got = sfmt.read_state(store2.get(agg))
         assert (got.count, got.version) == (st.count, st.version), agg
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int8, np.float32, np.int64])
+def test_a_compressed_column_is_decompressed_into_its_own_array(dtype):
+    """``_decode_array`` hands a compressed payload's bytes to the codec with
+    the array's own buffer as the destination: the same values
+    ``slz_decompress`` gives, in a writable array that owns them; a
+    destination of another size, or one that is not contiguous, is refused."""
+    from surge_tpu.log import columnar
+
+    if not seg.native_codec_available():
+        pytest.skip("native segment codec not built")
+    want = (np.arange(50_000) % 97).astype(dtype)
+    stored = seg.slz_compress(want.tobytes())
+    assert stored is not None and len(stored) < want.nbytes
+    got = columnar._decode_array(stored, seg.CODEC_SLZ, want.nbytes,
+                                 np.dtype(dtype))
+    assert got.dtype == want.dtype and got.tolist() == want.tolist()
+    assert got.flags.writeable and got.flags.owndata
+    assert got.tobytes() == seg.slz_decompress(stored, want.nbytes)
+    raw = columnar._decode_array(want.tobytes(), seg.CODEC_RAW, want.nbytes,
+                                 np.dtype(dtype))
+    assert raw.tolist() == want.tolist()
+    with pytest.raises(ValueError, match="decompression failed"):
+        seg.slz_decompress_into(stored, np.empty(want.size - 1, dtype=dtype))
+    with pytest.raises(ValueError, match="contiguous"):
+        seg.slz_decompress_into(stored, np.empty(2 * want.size, dtype=dtype)[::2])
+    if np.dtype(dtype).itemsize > 1:
+        with pytest.raises(ValueError, match="no whole"):
+            columnar._decode_array(stored, seg.CODEC_SLZ, want.nbytes - 1,
+                                   np.dtype(dtype))

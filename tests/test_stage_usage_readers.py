@@ -211,8 +211,11 @@ def test_every_reader_has_its_entry_in_the_manifest():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         man = json.load(f)
+    # the cold fold's rebuild cells; the projection rebuild scans, and opens
+    # none of the fold's stages (PERF.md section 4)
     rebuilds = [w["name"] for w in man["workloads"]
-                if w["name"].startswith("rebuild-")]
+                if w["name"].startswith("rebuild-")
+                and w["config"] != "cart-projection-rebuild"]
     # a restore's chunks are loaded packed and fold under one root stage, so
     # the readers of the pack, of the upload's process figures and of the
     # rebuilds' trace ids find nothing to read in the restore cell
