@@ -19,8 +19,12 @@ Design:
   program the predicate mask is fused into the segment reduce, so filtered
   events cost a compare, not a branch.
 - **Grouped aggregates keyed by aggregate id.** ``count | sum | min | max``
-  per aggregate via one segment-reduce (``.at[agg_idx].add/min/max``) over the
-  flat event axis — no per-aggregate padding, no [B, T] batch materialization.
+  per aggregate via one segment-reduce over the flat event axis — no
+  per-aggregate padding, no [B, T] batch materialization. A large chunk is
+  reduced over SORTED RUNS (one device sort by group, then reads at the runs'
+  boundaries: :func:`_reduce_runs`), a small one or a float ``sum`` by
+  scatters (``.at[agg_idx].add/min/max``: :func:`_reduce_scatter`); the
+  program chooses by its bucket and dtypes (:data:`_RUNS_FROM_UPDATES`).
   Chunks cover disjoint aggregate ranges (the columnar-segment contract), so
   chunk results concatenate.
 - **Mesh-sharded scans.** With a mesh, the EVENT axis shards across devices
@@ -82,6 +86,18 @@ SCAN_JIT_NAMES = ("scan",)
 #: range beside two over the events: taken where the range is at most this many
 #: times the events (:func:`_factorize_group`)
 _TABLE_SPAN_PER_EVENT = 4
+#: a chunk is reduced over sorted runs where its event bucket's rows (a shard's,
+#: on a mesh) times the query's reduces reach this, else by scatters. The
+#: scatters cost 7.3 ns a padded row and reduce; the sorted program one sort of
+#: the rows and 8-10 ms for the searches and gathers at 65,536 groups' run
+#: boundaries, whatever the reduces. On a v5e (PERF.md section 6, PR 40), ms by
+#: scatters / over runs, 65,536 groups: three reduces 6.9 / 11.2 at 2^18 rows,
+#: 12.6 / 12.8 at 2^19, 23.9 / 14.5 at 2^20, 46.7 / 16.8 at 2^21, 182.9 / 32.6
+#: at 2^23; ``count`` alone 8.3 / 10.4 at 2^20, 15.9 / 11.3 at 2^21. With few
+#: groups the sorted program is ahead from 65,536 rows (2.8 / 1.0 ms) but
+#: compiles in 10-20 s where the scatters take 0.2-1.0, which a view's round
+#: (65,536 rows) would pay on the served path on every new bucket pair
+_RUNS_FROM_UPDATES = 1 << 21
 
 
 @dataclass(frozen=True)
@@ -185,6 +201,12 @@ class ScanQuery:
         predicates first, then each OR-group's members in declaration
         order."""
         return self.predicates + tuple(p for g in self.or_groups for p in g)
+
+    @property
+    def reduces(self) -> int:
+        """The reduces a chunk's program runs: ``count``, always, and one for
+        each ``sum`` / ``min`` / ``max``."""
+        return 1 + sum(1 for a in self.aggregates if a.op != "count")
 
     def columns_needed(self) -> List[str]:
         """Every stored union column this query touches — the projection the
@@ -383,6 +405,97 @@ def _sentinel(op: str, dt: np.dtype):
     return np.finfo(dt).min if dt.kind == "f" else np.iinfo(dt).min
 
 
+def _run_extreme(skey, col, op: str):
+    """The running ``min`` / ``max`` of ``col`` within each run of equal,
+    sorted ``skey``, by doubling: after the step of distance ``d`` a row holds
+    the extreme of the (at most) ``2 d`` rows of its run that end at it, so
+    after log2(rows) steps a run's LAST row holds the run's. Exact in any
+    dtype; log2(rows) fused elementwise passes, no scan primitive
+    (``lax.associative_scan`` over ``(key, value)`` at 2^23 rows did not
+    finish compiling in 45 minutes: PERF.md section 6, PR 40)."""
+    import jax.numpy as jnp
+
+    pick = jnp.minimum if op == "min" else jnp.maximum
+    d = 1
+    while d < skey.shape[0]:
+        # a row's predecessor at distance d lies in its run iff the keys are
+        # equal (the run is contiguous); the first d rows have none
+        same = jnp.concatenate([jnp.zeros((d,), bool), skey[d:] == skey[:-d]])
+        before = jnp.concatenate([col[:d], col[:-d]])
+        col = jnp.where(same, pick(col, before), col)
+        d *= 2
+    return col
+
+
+def _reduce_runs(mask, group, reduced: dict, aggs, b_bucket: int) -> dict:
+    """A chunk's outputs read from its SORTED RUNS. An event the mask rejects
+    takes the sentinel key ``b_bucket`` and sorts past every group (the
+    garbage rows past a chunk's events in a reused buffer among them: nothing
+    else holds them); ONE sort a chunk carries the reduced columns; every
+    output is then read at the ``b_bucket + 1`` run boundaries: ``count`` the
+    distance between two runs' starts, an integer ``sum`` the difference of a
+    prefix sum at a run's two ends, ``min`` / ``max`` the run's running
+    extreme at its last row. A group the chunk does not show is an empty run:
+    ``count`` 0, ``sum`` 0, ``min`` / ``max`` the sentinel."""
+    import jax
+    import jax.numpy as jnp
+
+    names = sorted(reduced)
+    skey, *scols = jax.lax.sort(
+        (jnp.where(mask, group, b_bucket), *(reduced[c] for c in names)),
+        num_keys=1, is_stable=False)
+    scol = dict(zip(names, scols))
+    starts = jnp.searchsorted(
+        skey, jnp.arange(b_bucket + 1, dtype=jnp.int32)).astype(jnp.int32)
+    lo, hi = starts[:-1], starts[1:]
+    last = jnp.maximum(hi - 1, 0)
+    out = {"count": hi - lo}
+    for op, cname, oname in aggs:
+        if op == "count":
+            continue
+        col = scol[cname]
+        dt = col.dtype
+        if op == "sum":
+            # the prefix sum is taken in the output's own dtype and wraps as
+            # the scatter's adds wrap: modulo 2^w its difference at a run's
+            # two ends is the run's sum in the same ring, bit for bit
+            upto = jnp.cumsum(col, dtype=dt)
+            ends = jnp.where(starts > 0, upto[jnp.maximum(starts - 1, 0)],
+                             jnp.zeros((), dt))
+            out[oname] = ends[1:] - ends[:-1]
+        else:
+            out[oname] = jnp.where(
+                hi > lo, _run_extreme(skey, col, op)[last],
+                jnp.array(_sentinel(op, np.dtype(dt)), dt))
+    return out
+
+
+def _reduce_scatter(mask, group, reduced: dict, aggs, b_bucket: int) -> dict:
+    """A chunk's outputs by one scatter reduce an output, the mask fused in
+    (``group`` in range on every row). XLA's TPU scatter takes unsorted
+    updates one after the other, about 9 ns each (PERF.md section 6, PR 39):
+    the small chunk's program (:data:`_RUNS_FROM_UPDATES`), and any float
+    ``sum``'s, whose answer is the order of its additions."""
+    import jax.numpy as jnp
+
+    out = {"count": jnp.zeros((b_bucket,), jnp.int32).at[group].add(
+        mask.astype(jnp.int32))}
+    for op, cname, oname in aggs:
+        if op == "count":
+            continue
+        col = reduced[cname]
+        dt = col.dtype
+        if op == "sum":
+            out[oname] = jnp.zeros((b_bucket,), dt).at[group].add(
+                jnp.where(mask, col, jnp.zeros((), dt)))
+            continue
+        idle = jnp.array(_sentinel(op, np.dtype(dt)), dt)
+        rows = jnp.full((b_bucket,), idle, dt).at[group]
+        masked = jnp.where(mask, col, idle)
+        out[oname] = rows.min(masked) if op == "min" else rows.max(masked)
+    return out
+
+
 def _normalize_zero_match(out: Dict[str, np.ndarray], query: ScanQuery
                           ) -> Dict[str, np.ndarray]:
     """Zero-match aggregates report 0 everywhere: min/max sentinels flip to 0
@@ -563,31 +676,40 @@ class QueryEngine:
     # -- the device program -------------------------------------------------------------
 
     def _program(self, query: ScanQuery, n_bucket: int, b_bucket: int,
-                 col_names: Tuple[str, ...]):
-        key = (query.signature(), n_bucket, b_bucket, col_names)
+                 col_dts: Tuple[Tuple[str, np.dtype], ...]) -> tuple:
+        """The chunk's jitted program for the query at these buckets and the
+        put columns' ``(name, dtype)``, and how it reduces, by what it sees:
+        ``"runs"`` (:func:`_reduce_runs`) where the event bucket's rows, a
+        shard's on a mesh, times the reduces reach :data:`_RUNS_FROM_UPDATES`
+        and every ``sum`` is an integer's, else ``"scatter"``
+        (:func:`_reduce_scatter`: a float's sum IS the order of its
+        additions, and a prefix difference would cancel)."""
+        aggs = tuple((a.op, a.column, a.name) for a in query.aggregates)
+        dts = dict(col_dts, type_id=np.dtype(np.int32))
+        how = "runs" if (
+            n_bucket // self._n_dev() * query.reduces >= _RUNS_FROM_UPDATES
+            and all(dts[cname].kind in "iu"
+                    for op, cname, _ in aggs if op == "sum")) else "scatter"
+        key = (query.signature(), n_bucket, b_bucket, col_dts)
         hit = self._programs.get(key)
         if hit is not None:
-            return hit
+            return hit, how
         import jax
         import jax.numpy as jnp
 
-        dev_dts = {n: self._device_dtype(self._col_dtypes.get(
-            n, np.dtype(np.int32))) for n in col_names}
+        col_names = tuple(name for name, _ in col_dts)
         preds = tuple((p.column, p.op, _is_integral(p.value))
                       for p in query.predicates)
         groups = tuple(tuple((p.column, p.op, _is_integral(p.value))
                              for p in g) for g in query.or_groups)
-        aggs = tuple((a.op, a.column, a.name) for a in query.aggregates)
         has_types = query.event_types is not None
 
         def partials(agg_idx, type_ids, first, n, pred_vals, type_allow,
                      cols):
             # rows past the chunk's ``n`` events hold whatever the host's
             # buffer held: masked here, by position (``first`` is this
-            # shard's offset on the event axis), and their group index held
-            # in range
+            # shard's offset on the event axis)
             valid = first + jnp.arange(agg_idx.shape[0], dtype=jnp.int32) < n
-            agg_idx = jnp.where(valid, agg_idx, 0)
 
             def compare(cname, op, integral, j):
                 # one predicate leg, indexed into the FLAT pred_vals vector
@@ -633,30 +755,12 @@ class QueryEngine:
                     hit = leg if hit is None else hit | leg
                     j += 1
                 mask = mask & hit
-            out: dict = {}
-            out["count"] = jnp.zeros((b_bucket,), jnp.int32).at[agg_idx].add(
-                mask.astype(jnp.int32))
-            for op, cname, oname in aggs:
-                if op == "count":
-                    continue
-                col = (type_ids if cname == "type_id" else cols[cname])
-                dt = col.dtype
-                if op == "sum":
-                    out[oname] = jnp.zeros((b_bucket,), dt).at[agg_idx].add(
-                        jnp.where(mask, col, jnp.zeros((), dt)))
-                elif op == "min":
-                    big = (jnp.array(jnp.finfo(dt).max, dt)
-                           if jnp.issubdtype(dt, jnp.floating)
-                           else jnp.array(jnp.iinfo(dt).max, dt))
-                    out[oname] = jnp.full((b_bucket,), big, dt).at[
-                        agg_idx].min(jnp.where(mask, col, big))
-                else:
-                    small = (jnp.array(jnp.finfo(dt).min, dt)
-                             if jnp.issubdtype(dt, jnp.floating)
-                             else jnp.array(jnp.iinfo(dt).min, dt))
-                    out[oname] = jnp.full((b_bucket,), small, dt).at[
-                        agg_idx].max(jnp.where(mask, col, small))
-            return out
+            reduced = {cname: type_ids if cname == "type_id" else cols[cname]
+                       for op, cname, _ in aggs if op != "count"}
+            if how == "runs":
+                return _reduce_runs(mask, agg_idx, reduced, aggs, b_bucket)
+            return _reduce_scatter(mask, jnp.where(valid, agg_idx, 0),
+                                   reduced, aggs, b_bucket)
 
         if self.mesh is None or self._n_dev() <= 1:
             def scan(agg_idx, type_ids, n, pred_vals, type_allow, cols):
@@ -697,7 +801,7 @@ class QueryEngine:
                 check_vma=False)
             prog = jax.jit(scan)
         self._programs[key] = prog
-        return prog
+        return prog, how
 
     # -- chunk / segment scans ----------------------------------------------------------
 
@@ -794,11 +898,11 @@ class QueryEngine:
                 put_bytes=sum(a.nbytes for a in leaves) + sum(
                     a.nbytes for a in scalars))
         with stage("scan.dispatch"):
-            prog = self._program(query, n_bucket, b_bucket, needed)
+            prog, how = self._program(query, n_bucket, b_bucket, tuple(
+                (name, cols_d[name].dtype) for name in needed))
             out_dev = prog(agg_d, type_d, n_d, pred_d, allow_d, cols_d)
-        reduces = 1 + sum(1 for a in query.aggregates if a.op != "count")
         return ids, b, out_dev, dict(bucket=n_bucket, group_bucket=b_bucket,
-                                     updates=n * reduces)
+                                     how=how, updates=n * query.reduces)
 
     def _collect_scan(self, dispatched: tuple
                       ) -> Tuple[Optional[List[str]], Dict[str, np.ndarray]]:

@@ -176,7 +176,10 @@ def test_the_table_is_taken_by_the_columns_range_not_by_a_key():
 
 # --- one scan is one trace ------------------------------------------------------------
 
-def test_one_scan_is_one_trace_with_the_whole_tree(tmp_path):
+def test_one_scan_is_one_trace_with_the_whole_tree(tmp_path, monkeypatch):
+    # the cell's reduce (its chunks are past ``_RUNS_FROM_UPDATES``), at this
+    # size: a bucket of 65,536 rows x three reduces
+    monkeypatch.setattr(query_module, "_RUNS_FROM_UPDATES", 3 * 65536)
     corpus, path = make_segment(tmp_path, 2**31 + 37)
     engine = make_engine()
     engine.scan_segment(path, QUERY)  # compiles
@@ -228,9 +231,11 @@ def test_one_scan_is_one_trace_with_the_whole_tree(tmp_path):
     assert [s.attributes for s in named(spans, "replay.scan.h2d")] == [
         {"padded_events": bucket, "copied_bytes": 20 * n,
          "put_bytes": 20 * bucket + 8} for n in events]
+    # every output read from the chunk's sorted runs; ``updates`` is still
+    # the work the query asked for: events x its three reduces
     assert [s.attributes for s in named(spans, "replay.scan.reduce")] == [
-        {"bucket": bucket, "group_bucket": 128, "updates": 3 * n}
-        for n in events]
+        {"bucket": bucket, "group_bucket": 128, "how": "runs",
+         "updates": 3 * n} for n in events]
     assert [s.attributes for s in named(spans, "replay.scan.dispatch")] == [
         {}] * CHUNKS
     (merge,) = named(spans, "replay.scan.merge")

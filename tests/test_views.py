@@ -315,7 +315,10 @@ def test_view_golden_after_mid_round_failure():
             plane._fold_group = real
             await wait_views_current(log, plane, views, ["totals"])
             assert_view_golden(views, "totals", TOTALS_Q, log)
-            # the re-anchor reached the changefeed as reset entries
+            # the re-anchor reached the changefeed as reset entries (a fold
+            # publishes through call_soon_threadsafe: the entries of a round
+            # that ended since this task last yielded are not queued yet)
+            await asyncio.sleep(0.05)
             entries = []
             while not sub.queue.empty():
                 entries.append(sub.queue.get_nowait())
