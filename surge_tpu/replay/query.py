@@ -38,10 +38,14 @@ Design:
 - **Chunks in a pipeline.** A segment's next chunk is read on a reader
   thread, and a chunk's outputs are awaited only once the next chunk's
   program is dispatched, so the host's read, grouping and upload of chunk
-  ``k + 1`` run beside the device's reduce of chunk ``k``; the group column
-  is factorised without a sort where its range allows
-  (:func:`_factorize_group`). Every stage is a ``replay.scan*`` span
-  (docs/observability.md, "Replay profiler").
+  ``k + 1`` run beside the device's reduce of chunk ``k``. Under
+  ``group_by`` a chunk reduced over sorted runs is keyed by its group
+  column itself, on the device, where the column is an integer's of a
+  narrow range (:func:`_key_offsets`: the host reads its least and greatest
+  values alone); any other chunk's group column is factorised on the host,
+  without a sort where its range allows (:func:`_factorize_group`). Every
+  stage is a ``replay.scan*`` span (docs/observability.md, "Replay
+  profiler").
 - **Exactness contract.** Arithmetic happens in the DEVICE dtype of each
   column (with x64 off an int64 column reduces in int32); the numpy host
   reference (:func:`scan_reference`) mirrors that bit for bit, and the
@@ -363,39 +367,71 @@ def _group_keys(vals: np.ndarray) -> List[str]:
     return [repr(v) for v in vals.tolist()]  # an int's repr is its str
 
 
+def _value_range(col: np.ndarray) -> Optional[Tuple[int, int]]:
+    """``(lo, span)`` of an integer (or bool) column whose values span at
+    most ``_TABLE_SPAN_PER_EVENT`` times its length: its least value and
+    ``max - lo + 1``, two linear passes. None for any other column (a float,
+    a wider or sparser range, an empty chunk)."""
+    if col.dtype.kind not in "iub" or not col.size:
+        return None
+    values = col.view(np.uint8) if col.dtype.kind == "b" else col
+    lo = int(values.min())
+    span = int(values.max()) - lo + 1
+    return (lo, span) if span <= _TABLE_SPAN_PER_EVENT * col.size else None
+
+
+def _unsigned_low(dtype: np.dtype, lo: int) -> np.ndarray:
+    """``lo`` in the unsigned kin of ``dtype`` (a bool's: ``uint8``): modulo
+    that kin's width, a value less it is the true difference, in ``[0,
+    span)`` for every value of the range."""
+    width = np.dtype(dtype).itemsize
+    return np.asarray(lo % (1 << 8 * width), dtype=f"u{width}")
+
+
 def _factorize_group(col: np.ndarray) -> Tuple[List[str], np.ndarray, str]:
     """Distinct values of a DEVICE-dtype group column → (string keys in
     ascending value order, int32 group index per event, how).
 
     The result is ``np.unique(col, return_inverse=True)``'s, value for value
     and index for index; ``how`` says which way it was reached, chosen from
-    the column itself. ``"table"``: an integer (or bool) column whose values
-    span at most ``_TABLE_SPAN_PER_EVENT`` times its length takes a presence
-    table over ``[min, max]``, whose running count is every value's rank: two
-    linear passes over the events and one over the range, no sort. ``"sort"``:
-    any other column (a float, a wide or sparse range, an empty chunk) takes
-    ``np.unique``'s argsort."""
-    if col.dtype.kind in "iub" and col.size:
+    the column itself. ``"table"``: a column :func:`_value_range` finds
+    narrow takes a presence table over ``[min, max]``, whose running count
+    is every value's rank: two linear passes over the events and one over
+    the range, no sort. ``"sort"``: any other column takes ``np.unique``'s
+    argsort. (A chunk the scan reduces over sorted runs needs no rank: its
+    program keys by the column itself, :func:`_key_offsets`.)"""
+    found = _value_range(col)
+    if found is not None:
+        lo, span = found
         values = col.view(np.uint8) if col.dtype.kind == "b" else col
-        lo, hi = int(values.min()), int(values.max())
-        span = hi - lo + 1
-        if span <= _TABLE_SPAN_PER_EVENT * col.size:
-            # offsets from ``lo`` in the unsigned kin of the column's dtype:
-            # modulo its width the difference is the true one, in [0, span)
-            if lo == 0:
-                off = values
-            else:
-                unsigned = values.view(f"u{values.dtype.itemsize}")
-                off = unsigned - unsigned.dtype.type(
-                    lo % (1 << 8 * values.dtype.itemsize))
-            present = np.zeros((span,), dtype=bool)
-            present[off] = True
-            rank = np.cumsum(present, dtype=np.int32)
-            rank -= 1
-            vals = (np.flatnonzero(present) + lo).astype(col.dtype)
-            return _group_keys(vals), rank[off], "table"
+        if lo == 0:
+            off = values
+        else:
+            off = (values.view(f"u{values.dtype.itemsize}")
+                   - _unsigned_low(values.dtype, lo))
+        present = np.zeros((span,), dtype=bool)
+        present[off] = True
+        rank = np.cumsum(present, dtype=np.int32)
+        rank -= 1
+        vals = (np.flatnonzero(present) + lo).astype(col.dtype)
+        return _group_keys(vals), rank[off], "table"
     vals, inv = np.unique(col, return_inverse=True)
     return _group_keys(vals), inv.astype(np.int32).reshape(-1), "sort"
+
+
+def _key_offsets(col, lo):
+    """The device's group key: ``col - lo`` as int32, taken in the unsigned
+    kin of the column's dtype (``lo`` is :func:`_unsigned_low`'s scalar,
+    traced, so a new least value does not recompile); in ``[0, span)`` on
+    every row of the chunk, garbage on the rows past it."""
+    import jax
+    import jax.numpy as jnp
+
+    if col.dtype == jnp.bool_:
+        col = col.astype(jnp.uint8)
+    if col.dtype != lo.dtype:
+        col = jax.lax.bitcast_convert_type(col, lo.dtype)
+    return (col - lo).astype(jnp.int32)
 
 
 def _sentinel(op: str, dt: np.dtype):
@@ -427,7 +463,8 @@ def _run_extreme(skey, col, op: str):
     return col
 
 
-def _reduce_runs(mask, group, reduced: dict, aggs, b_bucket: int) -> dict:
+def _reduce_runs(mask, group, reduced: dict, aggs, b_bucket: int,
+                 valid=None) -> dict:
     """A chunk's outputs read from its SORTED RUNS. An event the mask rejects
     takes the sentinel key ``b_bucket`` and sorts past every group (the
     garbage rows past a chunk's events in a reused buffer among them: nothing
@@ -436,20 +473,50 @@ def _reduce_runs(mask, group, reduced: dict, aggs, b_bucket: int) -> dict:
     distance between two runs' starts, an integer ``sum`` the difference of a
     prefix sum at a run's two ends, ``min`` / ``max`` the run's running
     extreme at its last row. A group the chunk does not show is an empty run:
-    ``count`` 0, ``sum`` 0, ``min`` / ``max`` the sentinel."""
+    ``count`` 0, ``sum`` 0, ``min`` / ``max`` the sentinel.
+
+    With ``valid`` (the rows of the chunk's events) ``group`` is the device's
+    own key (:func:`_key_offsets`) and a group is every key a valid row
+    shows, whether the mask takes the row or not, as ``scan_reference`` forms
+    groups. A valid row's sort key is then ``2 x key``, plus one where the
+    mask rejects it, and the rest take ``2 x b_bucket``: the runs' starts are
+    searched at the even keys alone, so a key's run holds its taken rows and
+    then its rejected ones, and the outputs read the taken rows by the sort
+    key's low bit (a rejected row adds 0 and carries the sentinel). One more
+    output, ``_shown``, is a key's valid rows: the chunk's groups are the
+    keys where it is above 0."""
     import jax
     import jax.numpy as jnp
 
     names = sorted(reduced)
+    if valid is None:
+        key, halves = jnp.where(mask, group, b_bucket), 0
+    else:
+        key, halves = jnp.where(valid, 2 * group + (~mask).astype(jnp.int32),
+                                2 * b_bucket), 1
     skey, *scols = jax.lax.sort(
-        (jnp.where(mask, group, b_bucket), *(reduced[c] for c in names)),
-        num_keys=1, is_stable=False)
+        (key, *(reduced[c] for c in names)), num_keys=1, is_stable=False)
     scol = dict(zip(names, scols))
     starts = jnp.searchsorted(
-        skey, jnp.arange(b_bucket + 1, dtype=jnp.int32)).astype(jnp.int32)
+        skey, jnp.arange(b_bucket + 1, dtype=jnp.int32) << halves
+    ).astype(jnp.int32)
     lo, hi = starts[:-1], starts[1:]
     last = jnp.maximum(hi - 1, 0)
-    out = {"count": hi - lo}
+
+    def across(upto):
+        # a prefix sum's difference at each run's two ends
+        ends = jnp.where(starts > 0, upto[jnp.maximum(starts - 1, 0)],
+                         jnp.zeros((), upto.dtype))
+        return ends[1:] - ends[:-1]
+
+    if valid is None:
+        run, taken = skey, None
+        out = {"count": hi - lo}
+    else:
+        # the sentinel's rows read as taken too, past every run read here
+        run, taken = skey >> 1, (skey & 1) == 0
+        out = {"count": across(jnp.cumsum(taken, dtype=jnp.int32)),
+               "_shown": hi - lo}
     for op, cname, oname in aggs:
         if op == "count":
             continue
@@ -459,14 +526,15 @@ def _reduce_runs(mask, group, reduced: dict, aggs, b_bucket: int) -> dict:
             # the prefix sum is taken in the output's own dtype and wraps as
             # the scatter's adds wrap: modulo 2^w its difference at a run's
             # two ends is the run's sum in the same ring, bit for bit
-            upto = jnp.cumsum(col, dtype=dt)
-            ends = jnp.where(starts > 0, upto[jnp.maximum(starts - 1, 0)],
-                             jnp.zeros((), dt))
-            out[oname] = ends[1:] - ends[:-1]
+            if taken is not None:
+                col = jnp.where(taken, col, jnp.zeros((), dt))
+            out[oname] = across(jnp.cumsum(col, dtype=dt))
         else:
-            out[oname] = jnp.where(
-                hi > lo, _run_extreme(skey, col, op)[last],
-                jnp.array(_sentinel(op, np.dtype(dt)), dt))
+            idle = jnp.array(_sentinel(op, np.dtype(dt)), dt)
+            if taken is not None:
+                col = jnp.where(taken, col, idle)
+            out[oname] = jnp.where(hi > lo, _run_extreme(run, col, op)[last],
+                                   idle)
     return out
 
 
@@ -675,22 +743,32 @@ class QueryEngine:
 
     # -- the device program -------------------------------------------------------------
 
-    def _program(self, query: ScanQuery, n_bucket: int, b_bucket: int,
-                 col_dts: Tuple[Tuple[str, np.dtype], ...]) -> tuple:
-        """The chunk's jitted program for the query at these buckets and the
-        put columns' ``(name, dtype)``, and how it reduces, by what it sees:
-        ``"runs"`` (:func:`_reduce_runs`) where the event bucket's rows, a
-        shard's on a mesh, times the reduces reach :data:`_RUNS_FROM_UPDATES`
-        and every ``sum`` is an integer's, else ``"scatter"``
-        (:func:`_reduce_scatter`: a float's sum IS the order of its
-        additions, and a prefix difference would cancel)."""
-        aggs = tuple((a.op, a.column, a.name) for a in query.aggregates)
+    def _regime(self, query: ScanQuery, n_bucket: int,
+                col_dts: Tuple[Tuple[str, np.dtype], ...]) -> str:
+        """How a chunk's program reduces, by what it sees: ``"runs"``
+        (:func:`_reduce_runs`) where the event bucket's rows, a shard's on a
+        mesh, times the reduces reach :data:`_RUNS_FROM_UPDATES` and every
+        ``sum`` is an integer's, else ``"scatter"`` (:func:`_reduce_scatter`:
+        a float's sum IS the order of its additions, and a prefix difference
+        would cancel)."""
         dts = dict(col_dts, type_id=np.dtype(np.int32))
-        how = "runs" if (
+        return "runs" if (
             n_bucket // self._n_dev() * query.reduces >= _RUNS_FROM_UPDATES
-            and all(dts[cname].kind in "iu"
-                    for op, cname, _ in aggs if op == "sum")) else "scatter"
-        key = (query.signature(), n_bucket, b_bucket, col_dts)
+            and all(dts[a.column].kind in "iu"
+                    for a in query.aggregates if a.op == "sum")) else "scatter"
+
+    def _program(self, query: ScanQuery, n_bucket: int, b_bucket: int,
+                 col_dts: Tuple[Tuple[str, np.dtype], ...],
+                 keyed_by: Optional[str] = None) -> tuple:
+        """The chunk's jitted program for the query at these buckets and the
+        put columns' ``(name, dtype)``, and how it reduces
+        (:meth:`_regime`). ``keyed_by`` names the group column the program
+        keys its sorted runs by itself (its first argument is then the
+        chunk's least value, :func:`_key_offsets`); without it the first
+        argument is every event's group index, put."""
+        aggs = tuple((a.op, a.column, a.name) for a in query.aggregates)
+        how = self._regime(query, n_bucket, col_dts)
+        key = (query.signature(), n_bucket, b_bucket, col_dts, keyed_by)
         hit = self._programs.get(key)
         if hit is not None:
             return hit, how
@@ -704,12 +782,11 @@ class QueryEngine:
                              for p in g) for g in query.or_groups)
         has_types = query.event_types is not None
 
-        def partials(agg_idx, type_ids, first, n, pred_vals, type_allow,
-                     cols):
+        def partials(group, type_ids, first, n, pred_vals, type_allow, cols):
             # rows past the chunk's ``n`` events hold whatever the host's
             # buffer held: masked here, by position (``first`` is this
             # shard's offset on the event axis)
-            valid = first + jnp.arange(agg_idx.shape[0], dtype=jnp.int32) < n
+            valid = first + jnp.arange(type_ids.shape[0], dtype=jnp.int32) < n
 
             def compare(cname, op, integral, j):
                 # one predicate leg, indexed into the FLAT pred_vals vector
@@ -757,14 +834,18 @@ class QueryEngine:
                 mask = mask & hit
             reduced = {cname: type_ids if cname == "type_id" else cols[cname]
                        for op, cname, _ in aggs if op != "count"}
+            if keyed_by is not None:
+                gcol = type_ids if keyed_by == "type_id" else cols[keyed_by]
+                return _reduce_runs(mask, _key_offsets(gcol, group), reduced,
+                                    aggs, b_bucket, valid=valid)
             if how == "runs":
-                return _reduce_runs(mask, agg_idx, reduced, aggs, b_bucket)
-            return _reduce_scatter(mask, jnp.where(valid, agg_idx, 0),
+                return _reduce_runs(mask, group, reduced, aggs, b_bucket)
+            return _reduce_scatter(mask, jnp.where(valid, group, 0),
                                    reduced, aggs, b_bucket)
 
         if self.mesh is None or self._n_dev() <= 1:
-            def scan(agg_idx, type_ids, n, pred_vals, type_allow, cols):
-                return partials(agg_idx, type_ids, 0, n, pred_vals,
+            def scan(group, type_ids, n, pred_vals, type_allow, cols):
+                return partials(group, type_ids, 0, n, pred_vals,
                                 type_allow, cols)
 
             prog = jax.jit(scan)
@@ -775,12 +856,13 @@ class QueryEngine:
             pe = P(axis)  # event axis, sharded
             pr = P()      # replicated (predicate values, type filter, output)
 
-            def scan(agg_idx, type_ids, n, pred_vals, type_allow, cols):
-                first = jax.lax.axis_index(axis) * agg_idx.shape[0]
-                part = partials(agg_idx, type_ids, first, n, pred_vals,
+            def scan(group, type_ids, n, pred_vals, type_allow, cols):
+                first = jax.lax.axis_index(axis) * type_ids.shape[0]
+                part = partials(group, type_ids, first, n, pred_vals,
                                 type_allow, cols)
                 # ONE collective per output column: partial per-aggregate
-                # reduces combine across the event shards
+                # reduces combine across the event shards (``_shown`` as
+                # ``count``)
                 out: dict = {}
                 for name, v in part.items():
                     op = next((a[0] for a in aggs if a[2] == name), "count")
@@ -794,10 +876,12 @@ class QueryEngine:
 
             scan = jax.shard_map(
                 scan, mesh=self.mesh,
-                in_specs=(pe, pe, pr, pr, pr, {n: pe for n in col_names}),
+                in_specs=(pe if keyed_by is None else pr, pe, pr, pr, pr,
+                          {n: pe for n in col_names}),
                 out_specs={name: pr for name in
                            ["count"] + [a[2] for a in aggs
-                                        if a[0] != "count"]},
+                                        if a[0] != "count"]
+                           + ([] if keyed_by is None else ["_shown"])},
                 check_vma=False)
             prog = jax.jit(scan)
         self._programs[key] = prog
@@ -827,38 +911,54 @@ class QueryEngine:
                        buffers: dict) -> tuple:
         """The first half of a chunk's scan, up to its program under way on
         the device: ``(group keys, groups, outputs on the device, the reduce
-        stage's counts)`` for :meth:`_collect_scan`. ``buffers`` holds the
-        event-bucket host buffers the chunk's arrays are copied into, one an
-        array and bucket, made on first use and never cleared (the program
-        masks the rows past the chunk's events): the caller may hand the same
-        dict to a later chunk once this one's outputs are collected.
+        stage's counts, the device key's least value and dtype)`` for
+        :meth:`_collect_scan`. ``buffers`` holds the event-bucket host
+        buffers the chunk's arrays are copied into, one an array and bucket,
+        made on first use and never cleared (the program masks the rows past
+        the chunk's events): the caller may hand the same dict to a later
+        chunk once this one's outputs are collected.
 
         Three stages, each a span (docs/observability.md, "Replay profiler"):
-        ``replay.scan.group`` (under ``group_by``: the group column's distinct
-        values and every event's group index), ``replay.scan.h2d`` (the
-        arrays copied into the buffers and put on the device) and
-        ``replay.scan.dispatch`` (the program's asynchronous dispatch, with
-        its compilation on a first signature)."""
+        ``replay.scan.group`` (under ``group_by``: where the chunk is reduced
+        over sorted runs and the group column is an integer's of a narrow
+        range (:func:`_value_range`), its least value and range alone, the
+        program keying its runs by the column itself; else the column's
+        distinct values and every event's group index,
+        :func:`_factorize_group`),
+        ``replay.scan.h2d`` (the arrays copied into the buffers and put on
+        the device) and ``replay.scan.dispatch`` (the program's asynchronous
+        dispatch, with its compilation on a first signature)."""
         import jax
 
         stage = self.profiler.stage
         n = colev.num_events
         needed = tuple(query.columns_needed())
         cols_np = self._materialize_columns(colev, needed)
+        col_dts = tuple((name, self._device_dtype(cols_np[name].dtype))
+                        for name in needed)
+        n_dev = self._n_dev()
+        n_bucket = _pow2(max(n, 1), max(self._event_bucket, n_dev))
+        keyed = None  # the device key's (least value, dtype)
         if query.group_by is not None:
             with stage("scan.group") as grouped:
                 gcol = (colev.type_ids if query.group_by == "type_id"
                         else cols_np[query.group_by])
                 gcol = gcol.astype(self._device_dtype(np.dtype(gcol.dtype)),
                                    copy=False)
-                ids, grp_idx, how = _factorize_group(gcol)
-                grouped.attributes.update(distinct=len(ids), how=how)
-            b = len(ids)
+                found = (_value_range(gcol) if self._regime(
+                    query, n_bucket, col_dts) == "runs" else None)
+                # the sort key 2 x key + 1 and its sentinel fit an int32
+                if found is not None and _pow2(found[1], 8) <= 1 << 30:
+                    lo, b = found
+                    keyed, ids = (lo, gcol.dtype), None
+                    grouped.attributes.update(how="device", span=b)
+                else:
+                    ids, grp_idx, how = _factorize_group(gcol)
+                    grouped.attributes.update(distinct=len(ids), how=how)
+                    b = len(ids)
         else:
             ids, grp_idx = colev.aggregate_ids, colev.agg_idx
             b = colev.num_aggregates
-        n_dev = self._n_dev()
-        n_bucket = _pow2(max(n, 1), max(self._event_bucket, n_dev))
         b_bucket = _pow2(max(b, 1), 8)
 
         with stage("scan.h2d", padded_events=n_bucket) as h2d:
@@ -871,16 +971,19 @@ class QueryEngine:
                 buf[:n] = col
                 return buf
 
-            events = (padded("", grp_idx, np.int32),
+            # the program's first argument: every event's group index, or
+            # the device key's least value
+            events = (None if keyed else padded("", grp_idx, np.int32),
                       padded("type_id", colev.type_ids, np.int32),
-                      {name: padded(name, cols_np[name], self._device_dtype(
-                          cols_np[name].dtype)) for name in needed})
+                      {name: padded(name, cols_np[name], dt)
+                       for name, dt in col_dts})
             pred_vals = np.asarray([p.value for p in query.all_predicates()],
                                    dtype=np.float64)
             type_allow = (self.resolve_type_ids(query.event_types)
                           if query.event_types is not None
                           else np.zeros((0,), dtype=np.int32))
-            scalars = (np.int32(n), pred_vals, type_allow)
+            scalars = (np.int32(n), pred_vals, type_allow,
+                       _unsigned_low(keyed[1], keyed[0]) if keyed else None)
 
             if self.mesh is not None and n_dev > 1:
                 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -889,28 +992,41 @@ class QueryEngine:
                 replicated = NamedSharding(self.mesh, P())
             else:
                 on_events = replicated = None  # the default device
-            agg_d, type_d, cols_d, n_d, pred_d, allow_d = jax.block_until_ready(
+            (index_d, type_d, cols_d, n_d, pred_d, allow_d,
+             low_d) = jax.block_until_ready(
                 (*jax.device_put(events, on_events),
                  *jax.device_put(scalars, replicated)))
             leaves = jax.tree_util.tree_leaves(events)
             h2d.attributes.update(
                 copied_bytes=n * sum(a.itemsize for a in leaves),
                 put_bytes=sum(a.nbytes for a in leaves) + sum(
-                    a.nbytes for a in scalars))
+                    a.nbytes for a in jax.tree_util.tree_leaves(scalars)))
         with stage("scan.dispatch"):
-            prog, how = self._program(query, n_bucket, b_bucket, tuple(
-                (name, cols_d[name].dtype) for name in needed))
-            out_dev = prog(agg_d, type_d, n_d, pred_d, allow_d, cols_d)
+            prog, how = self._program(query, n_bucket, b_bucket, col_dts,
+                                      query.group_by if keyed else None)
+            out_dev = prog(low_d if keyed else index_d, type_d, n_d, pred_d,
+                           allow_d, cols_d)
         return ids, b, out_dev, dict(bucket=n_bucket, group_bucket=b_bucket,
-                                     how=how, updates=n * query.reduces)
+                                     how=how, updates=n * query.reduces), keyed
 
     def _collect_scan(self, dispatched: tuple
                       ) -> Tuple[Optional[List[str]], Dict[str, np.ndarray]]:
         """The second half: ``replay.scan.reduce``, the wait for the program
-        and its outputs' way to the host, cut to the chunk's groups."""
-        ids, b, out_dev, counts = dispatched
-        with self.profiler.stage("scan.reduce", **counts):
+        and its outputs' way to the host, cut to the chunk's groups. Where
+        the program keyed its runs by the group column, the groups are the
+        keys its valid rows showed (``_shown``): their values, the least one
+        added back in the column's dtype, make the row keys, ascending, as
+        :func:`_factorize_group` makes them, and ``distinct`` is counted
+        here."""
+        ids, b, out_dev, counts, keyed = dispatched
+        with self.profiler.stage("scan.reduce", **counts) as reduce:
             out = {k: np.asarray(v)[:b] for k, v in out_dev.items()}
+            if keyed:
+                lo, dtype = keyed
+                slots = np.flatnonzero(out.pop("_shown"))
+                ids = _group_keys((slots + lo).astype(dtype))
+                out = {k: v[slots] for k, v in out.items()}
+                reduce.attributes.update(distinct=len(ids))
         return ids, out
 
     def scan_chunks(self, chunks: Iterable[ColumnarEvents], query: ScanQuery

@@ -223,19 +223,23 @@ def test_one_scan_is_one_trace_with_the_whole_tree(tmp_path, monkeypatch):
         assert 0 < read.attributes["stored_bytes"] <= 20 * n + id_bytes
         assert (read.attributes["columns_read"],
                 read.attributes["columns_skipped"]) == (5, 0)
+    # the runs are keyed by ``item_code`` itself on the device: the host
+    # reads its least value and range (0 to 96), and no group index
     assert [s.attributes for s in named(spans, "replay.scan.group")] == [
-        {"distinct": CODES, "how": "table"}] * CHUNKS
+        {"how": "device", "span": CODES}] * CHUNKS
     bucket = 65536  # surge.query.chunk-events, the least event bucket
-    # five int32 buffers of a bucket go up, the chunk's own rows copied into
-    # them; beside them the event count and the one allowed type id
+    # four int32 buffers of a bucket go up (type ids and the three columns),
+    # the chunk's own rows copied into them; beside them the event count,
+    # the one allowed type id and the key's least value
     assert [s.attributes for s in named(spans, "replay.scan.h2d")] == [
-        {"padded_events": bucket, "copied_bytes": 20 * n,
-         "put_bytes": 20 * bucket + 8} for n in events]
+        {"padded_events": bucket, "copied_bytes": 16 * n,
+         "put_bytes": 16 * bucket + 12} for n in events]
     # every output read from the chunk's sorted runs; ``updates`` is still
-    # the work the query asked for: events x its three reduces
+    # the work the query asked for: events x its three reduces; the groups
+    # are counted where the keys are read off the program's outputs
     assert [s.attributes for s in named(spans, "replay.scan.reduce")] == [
         {"bucket": bucket, "group_bucket": 128, "how": "runs",
-         "updates": 3 * n} for n in events]
+         "updates": 3 * n, "distinct": CODES} for n in events]
     assert [s.attributes for s in named(spans, "replay.scan.dispatch")] == [
         {}] * CHUNKS
     (merge,) = named(spans, "replay.scan.merge")

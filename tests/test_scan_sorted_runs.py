@@ -39,7 +39,8 @@ class Dropped:
 
 FIELDS = (FieldSpec("a", np.int32), FieldSpec("b", np.int32),
           FieldSpec("code", np.int32), FieldSpec("narrow", np.int16),
-          FieldSpec("tiny", np.int8), FieldSpec("ratio", np.float32))
+          FieldSpec("tiny", np.int8), FieldSpec("ratio", np.float32),
+          FieldSpec("serial", np.uint32), FieldSpec("flag", np.bool_))
 
 
 def make_spec():
@@ -72,7 +73,10 @@ def chunk(n, groups, *, agg_idx=None, type_ids=None, seed=0, **cols):
         "a": rng.integers(-1000, 1000, n), "b": rng.integers(-1000, 1000, n),
         "code": rng.integers(0, 7, n), "narrow": rng.integers(-300, 300, n),
         "tiny": rng.integers(-100, 100, n),
-        "ratio": rng.integers(-8, 8, n) / 4}
+        "ratio": rng.integers(-8, 8, n) / 4,
+        # near the top of its range: a least value past int32's
+        "serial": rng.integers(2**32 - 3 * n, 2**32, n),
+        "flag": rng.integers(0, 2, n)}
     given.update(cols)
     return ColumnarEvents(
         num_aggregates=groups,
@@ -187,7 +191,63 @@ def case_the_type_id_pseudo_column():
         aggregates=(Aggregate("sum", "type_id"), Aggregate("max", "type_id")))
 
 
+def case_keyed_by_a_narrow_column_of_negative_values():
+    # int16 over -300..299: the least value is negative, the key's offsets
+    # taken in uint16; the second chunk's least value is another one
+    return [chunk(900, 12, seed=15), chunk(400, 5, seed=16,
+                                           narrow=np.arange(400) - 700)
+            ], ScanQuery(aggregates=EVERY_OP, group_by="narrow")
+
+
+def case_keyed_by_an_int8_astride_zero():
+    return [chunk(800, 9, seed=17)], ScanQuery(
+        aggregates=(Aggregate("sum", "narrow"), Aggregate("max", "tiny"),
+                    Aggregate("min", "b")),
+        predicates=(Predicate("a", "<", 500),), group_by="tiny")
+
+
+def case_keyed_by_an_unsigned_column_near_its_top():
+    return [chunk(700, 7, seed=18)], ScanQuery(
+        aggregates=EVERY_OP, group_by="serial")
+
+
+def case_keyed_by_a_bool():
+    return [chunk(600, 7, seed=19), chunk(50, 3, seed=20,
+                                          flag=np.ones(50))], ScanQuery(
+        aggregates=(Aggregate("count"), Aggregate("sum", "tiny"),
+                    Aggregate("min", "tiny"), Aggregate("max", "a")),
+        group_by="flag")
+
+
+def case_keyed_by_type_id_where_the_filter_rejects_a_type():
+    # every event of type 1 is rejected: its group is there, count 0
+    return [chunk(500, 10, seed=21, type_ids=np.arange(500) % 2)], ScanQuery(
+        aggregates=EVERY_OP, event_types=("Kept",), group_by="type_id")
+
+
+def case_a_value_only_rejected_events_show():
+    # code 3's events all fail the predicate, and code 6 is shown by no
+    # event at all: 3 is a group with zeros (sentinels before the
+    # normalisation), 6 no group
+    n = 900
+    rng = np.random.default_rng(22)
+    code = rng.integers(0, 6, n)
+    a = np.where(code == 3, -rng.integers(0, 900, n),
+                 rng.integers(-900, 900, n))
+    return [chunk(n, 20, seed=22, code=code, a=a)], ScanQuery(
+        aggregates=EVERY_OP, predicates=(Predicate("a", ">", 0),),
+        group_by="code")
+
+
 CASES = [v for k, v in sorted(globals().items()) if k.startswith("case_")]
+#: the cases that group by an integer event column of a narrow range: their
+#: chunks' runs are keyed by the column itself, on the device
+KEYED = [case_group_by_an_event_column,
+         case_keyed_by_a_narrow_column_of_negative_values,
+         case_keyed_by_an_int8_astride_zero,
+         case_keyed_by_an_unsigned_column_near_its_top, case_keyed_by_a_bool,
+         case_keyed_by_type_id_where_the_filter_rejects_a_type,
+         case_a_value_only_rejected_events_show]
 
 
 def assert_equal(got, want):
@@ -226,12 +286,68 @@ def test_an_empty_run_carries_the_sentinel_until_the_merge(case):
         assert (empty == want).all(), a.name
 
 
-def test_rows_past_the_events_of_a_reused_buffer_are_garbage():
+def group_spans(since):
+    return sorted((s for s in default_tracer().spans(since_mono=since)
+                   if s.name == "replay.scan.group"),
+                  key=lambda s: s.start_mono)
+
+
+def assert_raw_equal(got, want):
+    (got_ids, got_out), (want_ids, want_out) = got, want
+    assert got_ids == want_ids
+    assert set(got_out) == set(want_out)
+    for name, column in want_out.items():
+        assert got_out[name].dtype == column.dtype, name
+        assert np.array_equal(got_out[name], column), name
+
+
+@pytest.mark.parametrize("case", KEYED, ids=lambda c: c.__name__[5:])
+def test_the_device_key_gives_the_factorisations_rows(case, monkeypatch):
+    """Where the chunk is reduced over sorted runs and its group column is an
+    integer's of a narrow range, the program keys the runs by the column
+    itself (``how`` = ``device``): every chunk's raw outputs, the sentinels
+    of a group no event matched among them, and its keys are the factorising
+    path's bit for bit, and normalised they are ``scan_reference``'s."""
+    chunks, query = case()
+    engine = make_engine()
+    since = time.monotonic()
+    raws = [engine._raw_scan(c, query) for c in chunks]
+    assert [s.attributes["how"] for s in group_spans(since)] == [
+        "device"] * len(chunks)
+    # no range is narrow enough for a table: the factorising path, np.unique
+    monkeypatch.setattr(query_module, "_TABLE_SPAN_PER_EVENT", 0)
+    factorising = make_engine()
+    since = time.monotonic()
+    for c, raw in zip(chunks, raws):
+        assert_raw_equal(raw, factorising._raw_scan(c, query))
+        want = scan_reference([c], query, SPEC.registry)
+        ids, out = raw
+        assert ids == want.aggregate_ids
+        for name, column in want.columns.items():
+            assert np.array_equal(np.where(out["count"] > 0, out[name], 0),
+                                  column), name
+    assert {s.attributes["how"] for s in group_spans(since)} == {"sort"}
+
+
+def test_a_group_only_rejected_events_show_carries_the_sentinels():
+    (only,), query = case_a_value_only_rejected_events_show()
+    ids, raw = make_engine()._raw_scan(only, query)
+    assert ids == ["0", "1", "2", "3", "4", "5"]
+    assert (raw["count"][3], raw["sum_a"][3], raw["min_a"][3],
+            raw["max_b"][3]) == (0, 0, I32.max, I32.min)
+    assert (raw["count"][[0, 1, 2, 4, 5]] > 0).all()
+
+
+@pytest.mark.parametrize("query", [
+    ScanQuery(aggregates=EVERY_OP),
+    ScanQuery(aggregates=EVERY_OP, group_by="narrow")],
+    ids=["per_aggregate", "keyed_on_the_device"])
+def test_rows_past_the_events_of_a_reused_buffer_are_garbage(query):
     """A scan's host buffers are never cleared: the second chunk, shorter,
     leaves the first chunk's rows past its own events, and a poisoned buffer
-    leaves out-of-range garbage there. The sentinel key takes them all."""
+    leaves out-of-range garbage there (under ``group_by`` in the group
+    column the program keys by). The sentinel key takes them all."""
     engine = make_engine()
-    query = ScanQuery(aggregates=EVERY_OP)
     long, short = chunk(1000, 30, seed=20), chunk(10, 4, seed=21)
     buffers: dict = {}
     engine._collect_scan(engine._dispatch_scan(long, query, buffers))
@@ -239,18 +355,23 @@ def test_rows_past_the_events_of_a_reused_buffer_are_garbage():
         # the group index far out of range on both sides, the columns extreme
         buf[:] = np.where(np.arange(buf.shape[0]) % 2, I32.min, I32.max
                           ).astype(buf.dtype)
-    _ids, raw = engine._collect_scan(
+    ids, raw = engine._collect_scan(
         engine._dispatch_scan(short, query, buffers))
     want = scan_reference([short], query, SPEC.registry)
+    assert ids == want.aggregate_ids
     for name, column in want.columns.items():
         assert np.array_equal(
             np.where(raw["count"] > 0, raw[name], 0), column), name
 
 
-@pytest.mark.parametrize("case", [case_groups_the_chunk_does_not_show,
-                                  case_per_aggregate_with_agg_idx_unsorted,
-                                  case_a_float_column],
-                         ids=lambda c: c.__name__[5:])
+@pytest.mark.parametrize("case", [
+    case_groups_the_chunk_does_not_show,
+    case_per_aggregate_with_agg_idx_unsorted, case_a_float_column,
+    # keyed on the device, a shard at a time: presence adds across as count
+    case_keyed_by_a_narrow_column_of_negative_values,
+    case_keyed_by_type_id_where_the_filter_rejects_a_type,
+    case_a_value_only_rejected_events_show],
+    ids=lambda c: c.__name__[5:])
 def test_the_mesh_twin_sorts_a_shard_and_reduces_across(case):
     import jax
 
@@ -310,3 +431,46 @@ def test_the_regime_is_chosen_by_a_shards_rows_times_the_reduces(
     _prog, said = make_engine(mesh)._program(
         query, rows, 65536, tuple((c, np.dtype(np.int32)) for c in columns))
     assert said == how
+
+
+@pytest.mark.parametrize("runs_from,values,aggregates,group,reduce", [
+    # the chunk over sorted runs, the range at most four times its events
+    (1, [0, 399], EVERY_OP, {"how": "device", "span": 400}, "runs"),
+    (1, [-1, 398], EVERY_OP, {"how": "device", "span": 400}, "runs"),
+    # one value wider: the factorisation, whose table takes the same rule
+    (1, [0, 400], EVERY_OP, {"distinct": 2, "how": "sort"}, "runs"),
+    # a float group column
+    (1, "ratio", EVERY_OP, {"distinct": 16, "how": "sort"}, "runs"),
+    # the scatters (a view's round: rows x reduces under the line; a float
+    # sum) keep the host's factorisation, and the programs they had
+    (1 << 40, [0, 399], EVERY_OP, {"distinct": 2, "how": "table"},
+     "scatter"),
+    (1, [0, 399], (Aggregate("sum", "ratio"),),
+     {"distinct": 2, "how": "table"}, "scatter")],
+    ids=["device", "device_below_zero", "one_past_the_range", "a_float",
+         "scatter_regime", "a_float_sum"])
+def test_the_group_span_says_how_the_chunk_was_grouped(
+        monkeypatch, runs_from, values, aggregates, group, reduce):
+    """``replay.scan.group`` says ``device`` where the program keys the runs
+    by the column (with the range, ``span``; ``distinct`` is then counted by
+    ``replay.scan.reduce``, where the keys are read off), else ``table`` /
+    ``sort`` with ``distinct``; the result is the reference's either way."""
+    monkeypatch.setattr(query_module, "_RUNS_FROM_UPDATES", runs_from)
+    n = 100
+    if values == "ratio":
+        c, column = chunk(n, 5, seed=23), "ratio"
+    else:
+        c = chunk(n, 5, seed=23, a=np.resize(values, n))
+        column = "a"
+    query = ScanQuery(aggregates=aggregates, group_by=column)
+    since = time.monotonic()
+    got = make_engine().scan_chunks([c], query)
+    spans = default_tracer().spans(since_mono=since)
+    assert [s.attributes for s in spans if s.name == "replay.scan.group"] == [
+        group]
+    (reduced,) = [s.attributes for s in spans
+                  if s.name == "replay.scan.reduce"]
+    assert reduced["how"] == reduce
+    assert reduced.get("distinct") == (
+        got.num_aggregates if group["how"] == "device" else None)
+    assert_equal(got, scan_reference([c], query, SPEC.registry))
