@@ -30,9 +30,12 @@ from benchmarks import gen, gen_cart, reference_cart_restore, spans
 
 def build_inputs(run):
     """The corpus from the seed and its carts' ids. A cart with no event has
-    no key in an events topic, so the segment of a topic could not hold it."""
+    no key in an events topic, so the segment of a topic could not hold it.
+    The law's ``lengths_seed`` keeps a chunk's lengths together: every seed's
+    segment has chunks of the same events, in its own order."""
     corpus = gen_cart.cart_corpus(run.sizes["aggregates"], run.sizes["events"],
-                                  run.seed, run.config["corpus"])
+                                  run.seed, run.config["corpus"],
+                                  block=run.sizes["chunk_aggregates"])
     if int(corpus.lengths.min(initial=1)) < 1:
         raise ValueError("the corpus holds a cart with no event: a topic's "
                          "segment cannot (choose sizes with longer logs)")
@@ -43,7 +46,12 @@ def write_segment(path: str, corpus, ids: list, chunk_aggregates: int) -> dict:
     """The corpus as the segment ``build_segment_from_topic`` writes for a
     one-partition events topic of these carts: chunks of ``chunk_aggregates``
     carts in key order, aggregate-sorted, ``sequence_number`` derived, every
-    chunk with its ids, partition 0. Returns ``segment_info``."""
+    chunk with its ids, partition 0. Returns ``segment_info``.
+
+    A committed segment is durable before anyone reads it: the writer flushes
+    a fresh file but does not sync it, so the file and its directory are
+    synced here, before the warm-up, and the kernel's write-back of the
+    gigabyte cannot fall inside a timed window. The pages stay cached."""
     from surge_tpu.codec.tensor import ColumnarEvents
     from surge_tpu.log.columnar import ColumnarSegmentWriter, segment_info
 
@@ -61,6 +69,12 @@ def write_segment(path: str, corpus, ids: list, chunk_aggregates: int) -> dict:
                       "unit_price_cents": corpus.unit_price_cents[a:b]},
                 derived_cols={"sequence_number": "ordinal"},
                 aggregate_ids=ids[lo:hi]), partition=0)
+    for synced in (path, os.path.dirname(os.path.abspath(path))):
+        fd = os.open(synced, os.O_RDONLY)
+        try:
+            os.fsync(fd)
+        finally:
+            os.close(fd)
     return segment_info(path)
 
 

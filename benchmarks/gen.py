@@ -38,22 +38,33 @@ class Corpus:
 
 
 def log_lengths(law: dict, num_aggregates: int, num_events: int,
-                rng: np.random.Generator) -> np.ndarray:
+                rng: np.random.Generator, block: int | None = None) -> np.ndarray:
     """Events per aggregate under a configuration's ``corpus`` law, summing
     exactly to ``num_events``. ``fixed``: every log as long as the next (the
     remainder, where there is one, goes to the first logs). ``lognormal``:
-    lognormal lengths of sigma ``length_sigma`` around the same mean."""
+    lognormal lengths of sigma ``length_sigma`` around the same mean.
+
+    Where the law names a ``lengths_seed``, the lengths are drawn from that
+    seed alone, and ``rng`` only orders them within each run of ``block``
+    aggregates (the whole log where ``block`` is None): every seed then gets
+    the same lengths, and the same events in each block, in its own order."""
     kind = law["length_law"]
+    own = "lengths_seed" in law
+    draw = np.random.default_rng(int(law["lengths_seed"])) if own else rng
     if kind == "fixed":
         lengths = np.full(num_aggregates, num_events // num_aggregates,
                           dtype=np.int64)
     elif kind == "lognormal":
-        w = rng.lognormal(mean=0.0, sigma=float(law["length_sigma"]),
-                          size=num_aggregates)
+        w = draw.lognormal(mean=0.0, sigma=float(law["length_sigma"]),
+                           size=num_aggregates)
         lengths = np.floor(w * (num_events / w.sum())).astype(np.int64)
     else:
         raise ValueError(f"unknown length_law {kind!r}")
     lengths[: num_events - int(lengths.sum())] += 1
+    if own:
+        step = block or num_aggregates
+        for lo in range(0, num_aggregates, step):
+            rng.shuffle(lengths[lo:lo + step])
     return lengths
 
 

@@ -69,16 +69,17 @@ def running_total_extremes(corpus: CartCorpus) -> tuple:
 
 
 def cart_corpus(num_aggregates: int, num_events: int, seed: int,
-                law: dict) -> CartCorpus:
+                law: dict, block: int | None = None) -> CartCorpus:
     """The cart's log under ``law`` (a configuration's ``corpus`` group): its
     ``length_law``; ``body_mix``, the shares of ``ItemAdded`` and
     ``ItemRemoved`` among the events of a log's body; ``added_quantity`` and
     ``removed_quantity``, the ends of their uniform quantities; ``item_codes``,
     how many codes an item is drawn from; ``price_cents``, the ends of the
     prices; ``checkout_share``, the share of carts whose last event is their
-    one ``CheckedOut``."""
+    one ``CheckedOut``. ``block``: the aggregates a law's ``lengths_seed``
+    keeps the lengths of together (``gen.log_lengths``)."""
     rng = np.random.default_rng(seed)
-    lengths = log_lengths(law, num_aggregates, num_events, rng)
+    lengths = log_lengths(law, num_aggregates, num_events, rng, block)
     n = int(lengths.sum())
     agg_idx = np.repeat(np.arange(num_aggregates, dtype=np.int32), lengths)
     cut = int(round(law["body_mix"][0] * 10_000))

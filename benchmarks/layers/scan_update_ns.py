@@ -1,7 +1,9 @@
-"""Device nanoseconds a scatter update: the scan programs' device time within
-the traced window (one whole rebuild, the window's first) over the ``updates``
-its ``replay.scan.reduce`` spans carry (a chunk: events x reduces, counted by
-the program). What XLA's scatter costs an update, whatever the host does."""
+"""Device nanoseconds an update: the scan programs' device time within the
+traced window (one whole rebuild, the window's first) over the ``updates`` its
+``replay.scan.reduce`` spans carry (a chunk: events x reduces, counted by the
+program, whatever implements them: one sort a chunk and the reduces over its
+sorted runs, or the scatters on a view's small rounds). What the device costs
+an event and reduce, whatever the host does."""
 
 from benchmarks import spans
 

@@ -231,14 +231,36 @@ def test_cart_fault_an_answer_altered_where_it_is_produced(capsys, monkeypatch,
 
 # --- the manifest ---------------------------------------------------------------------
 
+def assert_listed(man, cell, config_name, chips, traffic):
+    """The manifest lists ``cell`` once, with its configuration, chips and
+    traffic, found by name and not by place (later cells are appended), and
+    asks for four chips in no more cells than the contract's cap."""
+    entries = [w for w in man["workloads"] if w["name"] == cell]
+    assert len(entries) == 1
+    assert (entries[0]["config"], entries[0]["chips"], entries[0]["traffic"]) \
+        == (config_name, chips, traffic)
+    assert config_name in [c["name"] for c in man["configs"]]
+    four = sum(w["chips"] == 4 for w in man["workloads"])
+    assert four <= max(1, len(man["workloads"]) // 2)
+
+
 def test_the_manifest_is_clean_and_lists_the_cell():
     assert harness.main(["--check"]) == 0
-    man, cell, config, traffic = harness.load_cell(CELL)
-    assert cell["chips"] == 1 and traffic["name"] == "rebuild-loop"
+    man, _cell, config, _traffic = harness.load_cell(CELL)
+    assert_listed(man, CELL, "cart-rebuild", 1, "rebuild-loop")
     assert config["sizes"] == {"aggregates": 1_000_000, "events": 100_000_000}
-    reported = [m["name"] for m in man["per_layer"]
-                if harness.reports(m, CELL, man)]
-    assert len(reported) == 14
-    assert reported[-2:] == ["pull_bytes_ratio", "small_tile_slots_pct"]
-    for m in man["per_layer"][-2:]:
-        assert m["workloads"] == ["rebuild-1m-100m", CELL]
+    reported = {m["name"] for m in man["per_layer"]
+                if harness.reports(m, CELL, man)}
+    layers = {m["name"]: m for m in man["per_layer"]}
+    # the cold fold's readers, the two this cell brought among them
+    assert reported >= {"device_idle_pct.rebuild", "fold_roofline",
+                        "pack_share_pct", "pad_ratio", "h2d_share_pct",
+                        "fetch_wait_pct", "pull_bytes_ratio",
+                        "small_tile_slots_pct"}
+    for name in ("pull_bytes_ratio", "small_tile_slots_pct"):
+        assert {"rebuild-1m-100m", CELL} <= set(layers[name]["workloads"])
+    # none of another layer's: the mixed fold's, the mesh's, the restore's,
+    # the scan's
+    assert not reported & {"scan_step_us", "union_live_pct",
+                           "mesh_fold_roofline", "shard_share_pct",
+                           "restore_read_pct", "scan_read_pct"}

@@ -10,13 +10,17 @@ import pytest
 
 from benchmarks import control, gen_cart, reference_cart, reference_cart_restore
 from benchmarks import run as harness
-from benchmarks.tests.test_cart import law, reader, rec, run_over
+from benchmarks.tests.test_cart import (assert_listed, law, reader, rec,
+                                        run_over)
 
 CELL = "restore-cart-segment"
 LIMITS = {"states_wrong", "store_missing", "store_extra", "events_unaccounted",
           "scalar_sample_wrong"}
 NEW = ["restore_read_pct", "restore_wire_pct", "restore_decode_pct",
        "restore_writeback_pct", "restore_host_us_per_aggregate"]
+# the cells of the cold fold that were there before this one
+FOLD_CELLS = ["rebuild-1m-100m", "rebuild-cart-ragged", "rebuild-mixed-opaque",
+              "rebuild-mixed-mesh4"]
 
 
 # --- the reference agrees with itself: the dictionary == the scalar fold's bytes --
@@ -83,6 +87,63 @@ def test_the_readers_give_nothing_on_a_program_without_the_spans(monkeypatch):
                 run_over(monkeypatch, [], rebuilds=1)):  # and no ring at all
         for name in NEW:
             assert reader(name)(run) is None, name
+
+
+# --- the committed segment is on disk before the set-up reads it -------------------
+
+def test_the_segment_is_synced_before_write_segment_returns(tmp_path,
+                                                            monkeypatch):
+    """A committed segment is durable before anyone reads it: the writer
+    flushes a fresh file but does not sync it, so ``write_segment`` syncs the
+    whole closed file and then its directory before it returns."""
+    from benchmarks.drivers import cart_restore as driver
+
+    synced = []
+    real_fsync = os.fsync
+
+    def fsync(fd):
+        name = os.path.realpath(os.readlink(f"/proc/self/fd/{fd}"))
+        synced.append((name, os.fstat(fd).st_size))
+        real_fsync(fd)
+
+    monkeypatch.setattr(os, "fsync", fsync)
+    corpus = gen_cart.cart_corpus(300, 6000, 5, law())
+    path = os.path.join(os.path.realpath(tmp_path), "cart-events.scol")
+    info = driver.write_segment(path, corpus,
+                                reference_cart_restore.cart_ids(300), 128)
+    assert info["num_chunks"] == 3
+    assert [name for name, _size in synced] == [path, os.path.dirname(path)]
+    assert synced[0][1] == os.path.getsize(path) > 0  # the whole file
+
+
+@pytest.mark.parametrize("seeds", [(3, 2**31 + 11), (3_300_000_033,
+                                                  3_300_000_035)])
+def test_every_seed_gets_a_chunk_of_the_same_events(seeds):
+    """Under the segment cells' law the seed orders a chunk's lengths and does
+    not draw them: every chunk holds the same lengths, so the same events,
+    whatever the seed; the events themselves are the seed's."""
+    from benchmarks.drivers import cart_restore as driver
+
+    _man, cell, config, traffic = harness.load_cell(CELL)
+    made = []
+    for seed in seeds:
+        run = harness.Run(cell, config, traffic, seed, 1.0, False, True)
+        made.append(driver.build_inputs(run)[0])
+    block = run.sizes["chunk_aggregates"]
+    assert 0 < block < run.sizes["aggregates"] // 4  # several chunks
+    one, other = made
+    assert one.num_events == other.num_events == run.sizes["events"]
+    for lo in range(0, run.sizes["aggregates"], block):
+        a, b = one.lengths[lo:lo + block], other.lengths[lo:lo + block]
+        assert np.array_equal(np.sort(a), np.sort(b))
+        assert not np.array_equal(a, b)  # in the seed's own order
+    assert not np.array_equal(one.item_code, other.item_code)
+    # without the key the seed draws the lengths, as in rebuild-cart-ragged
+    drawn = [gen_cart.cart_corpus(run.sizes["aggregates"], run.sizes["events"],
+                                  seed, law(), block=block) for seed in seeds]
+    assert "lengths_seed" not in law()
+    assert not np.array_equal(np.sort(drawn[0].lengths),
+                              np.sort(drawn[1].lengths))
 
 
 # --- a sound run, the control, a fault ---------------------------------------------
@@ -171,15 +232,18 @@ def test_restore_fault_a_put_altered_where_it_is_made(capsys, monkeypatch,
 
 def test_the_manifest_is_clean_and_lists_the_cell():
     assert harness.main(["--check"]) == 0
-    man, cell, config, traffic = harness.load_cell(CELL)
+    man, cell, config, _traffic = harness.load_cell(CELL)
     # four chips for steadiness alone: the restore itself folds on one
-    assert cell["chips"] == 4 and config["chips"] == 1
-    assert traffic["name"] == "rebuild-loop"
+    assert_listed(man, CELL, "cart-segment-restore", 4, "rebuild-loop")
+    assert config["chips"] == 1
     assert config["sizes"] == {"aggregates": 1_000_000, "events": 100_000_000,
                                "chunk_aggregates": 65536}
     assert config["reduced"] == ["chips"] and config["driver"] == "cart_restore"
     cart = harness.load_cell("rebuild-cart-ragged")[2]
-    assert config["corpus"] == cart["corpus"] and config["work"] == cart["work"]
+    # cart-rebuild's laws, and one key more: the lengths drawn from a seed of
+    # their own, the run's seed ordering them within a chunk
+    assert config["corpus"] == dict(cart["corpus"], lengths_seed=0)
+    assert config["work"] == cart["work"]
     assert len(config["source"]) <= 200
     # found by name, not by place: a later PR appends after these
     assert cell["config"] in [c["name"] for c in man["configs"]]
@@ -201,7 +265,8 @@ def test_the_manifest_is_clean_and_lists_the_cell():
     assert not set(reported) & {
         "pack_share_pct", "encode_words_pct", "pack_sys_pct", "h2d_put_cores",
         "rebuild_slowest_ratio", "host_preempts_per_rebuild"}
-    # additions only: the cell joined each list after the rebuild cells
+    # additions only: the cell joined each list after the fold's cells
     for name in reported:
-        older = [c for c in layers[name]["workloads"] if c.startswith("rebuild-")]
-        assert layers[name]["workloads"][:len(older)] == older
+        listed = layers[name]["workloads"]
+        older = [c for c in listed if c in FOLD_CELLS]
+        assert listed[:len(older)] == older

@@ -14,6 +14,7 @@ import pytest
 
 from benchmarks import control, gen_mixed, reference_mixed
 from benchmarks import run as harness
+from benchmarks.tests.test_cart import assert_listed
 
 CELL = "rebuild-mixed-mesh4"
 CONFIG = "mixed-rebuild-mesh4"
@@ -42,10 +43,8 @@ def reader(metric):
 
 def test_the_manifest_is_clean_and_names_the_cell():
     assert harness.main(["--check"]) == 0
-    man, cell, cfg, traffic = harness.load_cell(CELL)
-    assert cell["chips"] == 4 and cell["config"] == CONFIG
-    assert traffic["name"] == "rebuild-loop"
-    assert sum(w["chips"] == 4 for w in man["workloads"]) == 1
+    man, _cell, cfg, _traffic = harness.load_cell(CELL)
+    assert_listed(man, CELL, CONFIG, 4, "rebuild-loop")
     assert cfg["driver"] == "mixed_rebuild_mesh" and cfg["chips"] == 4
     assert cfg["reduced"] == ["chips"]
     entry = next(c for c in man["configs"] if c["name"] == CONFIG)
@@ -63,7 +62,7 @@ def test_the_manifest_is_clean_and_names_the_cell():
             assert m["moves"] == "rebuild_events_per_s"
     moved = next(m for m in man["end_to_end"]
                  if m["name"] == "rebuild_events_per_s")
-    assert CELL in moved["workloads"] and moved["bound"] == 0.045
+    assert CELL in moved["workloads"] and moved["bound"] == 0.085
 
 
 def test_the_configuration_is_the_mixed_cells_but_for_the_chips():
