@@ -1,37 +1,30 @@
 #!/usr/bin/env python
-"""North-star benchmark: cold replay of a ragged event log (BASELINE.md targets).
+"""Command-path and served-plane benchmarks of one host.
 
-1. The parent process pins itself to the host CPU platform — a chip belongs to one
-   process at a time, and the parent must not be the one holding it — and builds the
-   1M-aggregate / 100M-event counter corpus columnar-side, saving it to disk for the
-   replay children.
-2. The scalar CPU fold baseline (the reference's Kafka Streams restore is exactly this
-   per-aggregate scalar fold, SURVEY.md §3.3) and the phase-2 steady-state command
-   latency (p50/p99/commands-per-sec through the full engine with the reference's
-   50 ms flush tick and fsync-on-commit FileLog) are measured first — neither needs
-   any accelerator.
-3. A CPU-JAX replay child measures the batched fold on the host platform; its rate is
-   recorded as ``cpu_jax_events_per_sec``, never as the headline.
-4. ONE device child runs with the original environment. It must come up on a TPU: a
-   child that fails, or that comes up on any other platform, fails the run. The
-   headline is that child's number.
+The cold fold and the projection rebuild are measured by ``python3 -m
+benchmarks.run`` (BENCHMARK.json); this script measures what no cell holds yet.
+Every phase runs on the host CPU platform.
+
+The default run is the steady-state command latency (p50/p99/commands-per-sec
+through the full engine with the reference's 50 ms flush tick and
+fsync-on-commit FileLog) and the producer sweep, then, with
+SURGE_BENCH_RESTORE=1, full vs checkpointed cold start. One of the SURGE_BENCH_*
+mode switches below runs that mode alone instead: LADDER (with LANE or NATIVE),
+FAILOVER, ANATOMY, SOAK, SAGA, HANDOFF, MESH, RAGGED, RESIDENT, RESIDENT_FEED,
+VIEWS.
 
 Prints one JSON line to stdout (a failed run prints one with ``error`` and exits 1):
-    {"metric": "cold_replay_events_per_sec", "value": N, "unit": "events/s",
-     "vs_baseline": <speedup over the scalar CPU fold>, "platform": ...,
-     "pad_ratio": ..., "pack_s": ..., "command_p50_ms": ..., ...}
+    {"metric": "commands_per_sec", "value": N, "unit": "commands/s",
+     "command_p50_ms": ..., "command_p99_ms": ..., ...}
 
-Env knobs: SURGE_BENCH_AGGREGATES (1_000_000), SURGE_BENCH_EVENTS (100_000_000),
-SURGE_BENCH_CPU_SAMPLE (200_000 events), SURGE_BENCH_TIME_CHUNK, SURGE_BENCH_BATCH,
-SURGE_BENCH_LATENCY_SECONDS (5; 0 skips phase 2), SURGE_BENCH_LATENCY_WORKERS (64),
-SURGE_BENCH_SKIP_CPU_REPLAY (0).
+Env knobs: SURGE_BENCH_LATENCY_SECONDS (5; 0 skips the latency phase),
+SURGE_BENCH_LATENCY_WORKERS (64), SURGE_BENCH_SWEEP (1), SURGE_BENCH_RESTORE (0).
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -54,323 +47,8 @@ def emit(payload: dict) -> None:
     print(json.dumps(payload), flush=True)
 
 
-def _cpu_env(env: dict) -> dict:
-    """A copy of ``env`` pinned to the host CPU platform."""
-    return {**env, "JAX_PLATFORMS": "cpu"}
-
-
 # --------------------------------------------------------------------------------------
-# corpus on disk (parent writes once; replay children mmap)
-# --------------------------------------------------------------------------------------
-
-_CORPUS_FILES = ("agg_idx", "type_ids", "increment_by", "decrement_by",
-                 "lengths", "expected_count", "expected_version")
-
-
-def save_corpus(corpus, root: str) -> None:
-    ev = corpus.events
-    arrays = {
-        "agg_idx": ev.agg_idx, "type_ids": ev.type_ids,
-        "increment_by": ev.cols["increment_by"],
-        "decrement_by": ev.cols["decrement_by"],
-        "lengths": corpus.lengths, "expected_count": corpus.expected_count,
-        "expected_version": corpus.expected_version,
-    }
-    for name in _CORPUS_FILES:
-        np.save(os.path.join(root, f"{name}.npy"), arrays[name])
-
-
-def load_corpus(root: str):
-    from surge_tpu.codec.tensor import ColumnarEvents
-    from surge_tpu.replay.corpus import CounterCorpus
-
-    a = {name: np.load(os.path.join(root, f"{name}.npy"), mmap_mode="r")
-         for name in _CORPUS_FILES}
-    events = ColumnarEvents(
-        num_aggregates=int(a["lengths"].shape[0]), agg_idx=a["agg_idx"],
-        type_ids=a["type_ids"],
-        cols={"increment_by": a["increment_by"], "decrement_by": a["decrement_by"]},
-        derived_cols={"sequence_number": "ordinal"})
-    return CounterCorpus(events=events, lengths=a["lengths"],
-                         expected_count=a["expected_count"],
-                         expected_version=a["expected_version"])
-
-
-# --------------------------------------------------------------------------------------
-# replay child: one backend, one measured replay, one JSON line on stdout
-# --------------------------------------------------------------------------------------
-
-def make_engine():
-    """The bench replay engine (shared by parent pack and replay children so
-    the wire form and tile plan agree).
-
-    SURGE_BENCH_PROFILE=1 attaches the per-stage replay profiler (a DEBUG
-    registry + surge_tpu.replay.profiler): the child payload then carries a
-    per-stage encode/h2d/compile/dispatch/fetch breakdown. Off by default —
-    the headline numbers always come from the unprofiled hot path."""
-    from surge_tpu.config import default_config
-    from surge_tpu.models.counter import make_replay_spec
-    from surge_tpu.replay.engine import ReplayEngine
-
-    cfg = default_config().with_overrides({
-        "surge.replay.batch-size": int(os.environ.get("SURGE_BENCH_BATCH", 8192)),
-        # 64 over the old 128: narrower tiles cut time-axis tail padding (pad
-        # 1.80 -> 1.47, +8% fold rate at 10M on CPU); the TPU child's smoke
-        # sweep overrides with whatever measures best on chip
-        "surge.replay.time-chunk": int(os.environ.get("SURGE_BENCH_TIME_CHUNK", 64)),
-        "surge.replay.dispatch": os.environ.get("SURGE_BENCH_DISPATCH", "switch"),
-        # auto: assoc tree fold for models with an AssociativeFold on
-        # accelerators (the r5 on-chip redesign)
-        "surge.replay.tile-backend": os.environ.get("SURGE_BENCH_TILE", "auto"),
-        # single corpus, explicit warm: exact buffer length, no bucket padding
-        # on the (timed) upload
-        "surge.replay.resident-len-bucket": "exact",
-    })
-    profiler = None
-    if os.environ.get("SURGE_BENCH_PROFILE", "0") == "1":
-        from surge_tpu.metrics import Metrics, RecordingLevel, engine_metrics
-        from surge_tpu.replay.profiler import ReplayProfiler
-
-        registry = Metrics(recording_level=RecordingLevel.DEBUG)
-        profiler = ReplayProfiler.if_enabled(registry, engine_metrics(registry))
-    return ReplayEngine(make_replay_spec(),
-                        config=cfg,
-                        unroll=int(os.environ.get("SURGE_BENCH_UNROLL", 1)),
-                        profiler=profiler)
-
-
-def replay_child(corpus_dir: str) -> None:
-    import jax
-
-    from surge_tpu.replay.engine import ensure_compile_cache
-
-    ensure_compile_cache()
-    devices = jax.devices()  # ONE attempt; parent decides platform via env
-    platform = devices[0].platform
-    log(f"child backend up: platform={platform} devices={devices}")
-
-    from surge_tpu.models.counter import make_replay_spec
-
-    corpus = load_corpus(corpus_dir)
-    engine = make_engine()
-
-    # The resident path (default) ships the corpus ONCE (1 byte/event, zero
-    # padding on the link) and every fold gathers on-device — the measured
-    # time is the flat pack + upload + all folds. Gather programs depend on
-    # the buffer's static length, so they are warmed on the REAL buffer with
-    # zero-length no-op folds (state untouched) before the timed fold pass.
-    # SURGE_BENCH_STREAMING=1 (or the legacy SURGE_BENCH_RESIDENT=0 spelling)
-    # falls back to the streaming window path, whose fixed-shape programs ARE
-    # warmable corpus-free: one all-padding [width, batch] window per ladder
-    # width + the full chunk. (SURGE_BENCH_RESIDENT=1 itself now selects the
-    # read-plane fast path in main() and never reaches a replay child.)
-    resident_mode = (os.environ.get("SURGE_BENCH_STREAMING", "0") != "1"
-                     and os.environ.get("SURGE_BENCH_RESIDENT", "1") == "1")
-    bs = engine.batch_size
-    if not resident_mode:
-        union_cols = {f.name: np.zeros((bs, 1), dtype=f.dtype)
-                      for f in make_replay_spec().registry.union_columns()}
-        for width in engine.ladder_widths() + [max(engine.time_chunk, 1)]:
-            carry = engine._carry_slice(None, 0, bs, bs)
-            pad_ids = np.full((bs, width), -1, dtype=np.int32)
-            cols = {name: np.zeros((bs, width), dtype=col.dtype)
-                    for name, col in union_cols.items()
-                    if name not in ("sequence_number",)}
-            engine._fold_window(carry, pad_ids, cols, bs,
-                                derived_cols={"sequence_number": "ordinal"})
-    engine.stats.update(pack_s=0.0, h2d_s=0.0, windows=0)
-    warm_compiles = engine.num_compiles()
-    log(f"child warmup done, compiled programs: {warm_compiles}")
-
-    extra_timing = {}
-    if resident_mode:
-        from surge_tpu.replay.engine import ResidentWire
-
-        wire_dir = os.path.join(corpus_dir, "wire")
-        stream_segments = int(os.environ.get("SURGE_BENCH_STREAM_SEGMENTS", 0))
-        if stream_segments > 1 and os.path.isdir(wire_dir):
-            # pipelined mode: upload itself is part of the timed pass (pieces
-            # upload while earlier pieces fold); warm with a throwaway pass
-            wire = ResidentWire.load(wire_dir)
-            engine.replay_resident_streamed(wire, segments=stream_segments)
-            # the warm pass uploaded and folded once; count only the timed
-            # pass's windows and transfer time
-            engine.stats.update(windows=0, h2d_s=0.0, pack_s=0.0)
-            warm_compiles = engine.num_compiles()
-            log(f"streamed mode ({stream_segments} segments): warmed")
-            t0 = time.perf_counter()
-            result = engine.replay_resident_streamed(wire,
-                                                     segments=stream_segments)
-            fold_s = time.perf_counter() - t0
-            if engine.num_compiles() != warm_compiles:
-                log(f"WARNING: {engine.num_compiles() - warm_compiles} "
-                    f"program(s) compiled INSIDE the timed window")
-            replay_s = fold_s
-            extra_timing = {"fold_s": round(fold_s, 2),
-                            "stream_segments": stream_segments}
-        else:
-            if stream_segments > 1:
-                log("streamed mode requested but no packed wire dir exists; "
-                    "running the plain resident path")
-            t0 = time.perf_counter()
-            if os.path.isdir(wire_dir):
-                # the parent packed the wire at corpus-build time (the
-                # log-segment build analog): cold replay = mmap + upload + fold
-                resident = engine.upload_resident(ResidentWire.load(wire_dir))
-            else:
-                resident = engine.prepare_resident(corpus.events)
-            prepare_s = time.perf_counter() - t0
-            # compile the single tile program against the real buffers, then
-            # run one full throwaway pass: the first real execution pays a
-            # one-time runtime/autotune cost (~0.7s measured) that is warmup,
-            # not replay — the timed pass still re-uploads its per-replay
-            # inputs and re-folds every event
-            engine.warm_resident(resident)
-            engine.replay_resident(resident)
-            engine.stats["windows"] = 0  # count only the timed pass's windows
-            warm_compiles = engine.num_compiles()
-            log(f"resident corpus: {resident.wire_bytes / 1e6:.0f} MB shipped "
-                f"in {resident.upload_s:.1f}s; programs warmed + throwaway "
-                "pass done")
-            t0 = time.perf_counter()
-            result = engine.replay_resident(resident)
-            fold_s = time.perf_counter() - t0
-            if engine.num_compiles() != warm_compiles:
-                log(f"WARNING: {engine.num_compiles() - warm_compiles} "
-                    f"program(s) compiled INSIDE the timed window (warmup gap)")
-            # steady regime: the corpus is resident (standby refresh,
-            # repeated rebuilds) — where the accelerator is transfer-free.
-            # snapshot the timed pass's window count first so the payload
-            # reports it un-inflated by these extra passes
-            timed_windows = engine.stats["windows"]
-            steady_s = fold_s
-            for _ in range(2):
-                t0 = time.perf_counter()
-                result = engine.replay_resident(resident)
-                steady_s = min(steady_s, time.perf_counter() - t0)
-            engine.stats["windows"] = timed_windows
-            replay_s = prepare_s + fold_s
-            extra_timing = {"upload_s": round(resident.upload_s, 2),
-                            "fold_s": round(fold_s, 2),
-                            "steady_replay_s": round(steady_s, 3),
-                            "steady_events_per_sec": round(
-                                corpus.num_events / steady_s),
-                            "wire_mb": round(resident.wire_bytes / 1e6, 1)}
-    else:
-        t0 = time.perf_counter()
-        result = engine.replay_columnar(corpus.events)
-        replay_s = time.perf_counter() - t0
-        if engine.num_compiles() != warm_compiles:
-            log(f"WARNING: {engine.num_compiles() - warm_compiles} program(s) "
-                f"compiled INSIDE the timed window (warmup gap)")
-
-    if not np.array_equal(result.states["count"], corpus.expected_count):
-        raise AssertionError("replay count mismatch vs closed-form fold")
-    if not np.array_equal(result.states["version"], corpus.expected_version):
-        raise AssertionError("replay version mismatch vs closed-form fold")
-    if result.num_events != corpus.num_events:
-        raise AssertionError("replay event accounting mismatch")
-
-    # Device-resident fold ceiling: re-fold one full window with inputs pinned
-    # on device — no host link involved — to separate the fold rate from the
-    # transfer bound that governs events_per_sec.
-    device_eps = _device_resident_fold_rate(engine, corpus)
-    log(f"device-resident fold rate: {device_eps:,.0f} event-slots/s "
-        f"(transfer-free)")
-
-    eps = corpus.num_events / replay_s
-    payload = {
-        "platform": platform,
-        "events_per_sec": round(eps),
-        "device_fold_events_per_sec": round(device_eps),
-        "aggregates_per_sec": round(corpus.num_aggregates / replay_s),
-        "replay_s": round(replay_s, 2),
-        "pad_ratio": round(result.padded_events / max(corpus.num_events, 1), 3),
-        "pack_s": round(engine.stats["pack_s"], 2),
-        "h2d_s": round(engine.stats["h2d_s"], 2),
-        "windows": engine.stats["windows"],
-        "compiles": engine.num_compiles(),
-        "num_events": corpus.num_events,
-        "num_aggregates": corpus.num_aggregates,
-        "knobs": {"dispatch": engine._dispatch, "unroll": engine._unroll,
-                  "time_chunk": engine.time_chunk, "batch": engine.batch_size,
-                  "tile": engine.tile_backend},
-        **extra_timing,
-    }
-    if engine.profiler is not None:
-        payload["profile"] = engine.profiler.summary()
-        log(f"profile breakdown: {payload['profile']}")
-    log(f"child replay: {corpus.num_events:,} events in {replay_s:.2f}s -> "
-        f"{eps:,.0f} events/s (pad {payload['pad_ratio']}, pack {payload['pack_s']}s, "
-        f"{payload['windows']} windows, {payload['compiles']} programs, verified)")
-    print(json.dumps(payload), flush=True)
-
-
-def _device_resident_fold_rate(engine, corpus) -> float:
-    """Slots/s of the compiled fold with every input already on device (carry
-    donated and chained): the compute ceiling the replay would reach on a host
-    whose link is not the bottleneck."""
-    import jax
-    import jax.numpy as jnp
-
-    bs = engine.batch_size
-    chunk = max(engine.time_chunk, 1)
-    key, wire, fold = engine._wire_fold({"sequence_number": "ordinal"})
-    ev = corpus.events
-    # one full window of real corpus data (padded batch-major [b, T])
-    from surge_tpu.codec.tensor import columnar_to_batch
-
-    sub = ev.sorted_by_aggregate().slice_aggregates(0, min(bs, ev.num_aggregates))
-    enc = columnar_to_batch(sub, pad_to=None)
-    t = min(enc.max_len, chunk)
-    packed, side = wire.pack_window(enc.type_ids, enc.cols, 0, t, chunk, bs)
-    packed_dev = jax.device_put(packed)
-    side_dev = {k: jax.device_put(v) for k, v in side.items()}
-    ord_dev = jax.device_put(np.zeros((bs,), dtype=np.int32))
-    def fetch_barrier(c):
-        # a real device→host fetch of one element: its data dependency
-        # forces the whole chained sequence to finish
-        next(iter(np.asarray(v)[:1] for v in c.values()))
-
-    carry = engine._carry_slice(None, 0, bs, bs)
-    carry = fold(carry, packed_dev, side_dev, ord_dev)  # warm/compile
-    fetch_barrier(carry)
-    # calibrate iterations to a ~2s measurement
-    t0 = time.perf_counter()
-    carry = fold(carry, packed_dev, side_dev, ord_dev)
-    fetch_barrier(carry)
-    per_iter = max(time.perf_counter() - t0, 1e-5)
-    iters = max(int(2.0 / per_iter), 3)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        carry = fold(carry, packed_dev, side_dev, ord_dev)
-    fetch_barrier(carry)
-    dt = time.perf_counter() - t0
-    return iters * chunk * bs / dt
-
-
-def run_replay_child(env: dict, corpus_dir: str, label: str) -> dict | None:
-    """Run one replay child to completion (no timeout here: the driver owns the
-    overall deadline). The parent is pinned to CPU, so the child is the only
-    process that may hold the chip."""
-    log(f"starting {label} replay child")
-    t0 = time.perf_counter()
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--replay-child", corpus_dir],
-        env=env, stdout=subprocess.PIPE, text=True)
-    elapsed = time.perf_counter() - t0
-    if proc.returncode != 0:
-        log(f"{label} replay child failed rc={proc.returncode} after {elapsed:.0f}s")
-        return None
-    line = proc.stdout.strip().splitlines()[-1]
-    out = json.loads(line)
-    log(f"{label} replay child done in {elapsed:.0f}s: "
-        f"{out['events_per_sec']:,} events/s on {out['platform']}")
-    return out
-
-
-# --------------------------------------------------------------------------------------
-# phase 2: steady-state command latency (no accelerator involved)
+# steady-state command latency (no accelerator involved)
 # --------------------------------------------------------------------------------------
 
 def steady_state_latency(seconds: float, overrides: dict | None = None,
@@ -1545,36 +1223,6 @@ def producer_sweep(seconds: float) -> list:
     return rows
 
 
-# --------------------------------------------------------------------------------------
-# parent orchestration
-# --------------------------------------------------------------------------------------
-
-def _merge_replay(payload: dict, child: dict, cpu_eps: float) -> None:
-    payload["value"] = child["events_per_sec"]
-    payload["vs_baseline"] = round(child["events_per_sec"] / cpu_eps, 2) if cpu_eps else 0
-    for k in ("platform", "aggregates_per_sec", "replay_s", "pad_ratio", "pack_s",
-              "h2d_s", "windows", "compiles", "device_fold_events_per_sec",
-              "upload_s", "fold_s", "steady_replay_s",
-              "steady_events_per_sec", "wire_mb", "stream_segments", "knobs"):
-        if k in child:
-            payload[k] = child[k]
-    # End-to-end cold-start accounting (VERDICT r4 missing #3), matching how
-    # the reference's restore is judged — wall clock of the whole restore
-    # (KafkaStreamsUpdatePartitionsOnStateChangeListener.scala:1-113):
-    # - mmap hit (every restart after the first): mmap the packed wire +
-    #   upload + fold = replay_s, so value/vs_baseline ARE end-to-end here
-    # - first build (one-time): + the wire pack at segment-build time
-    # corpus_build_s stays separate: it synthesizes the benchmark fixture the
-    # reference reads out of its pre-existing Kafka topics.
-    if "replay_s" in child:
-        payload["cold_start_mmap_hit_s"] = child["replay_s"]
-        first = round(payload.get("wire_pack_s", 0.0) + child["replay_s"], 2)
-        payload["cold_start_first_build_s"] = first
-        if cpu_eps and payload.get("num_events") and first > 0:
-            payload["vs_baseline_first_build"] = round(
-                payload["num_events"] / first / cpu_eps, 2)
-
-
 def restore_bench() -> dict:
     """SURGE_BENCH_RESTORE=1: full vs checkpointed cold start (docs/compaction.md).
 
@@ -2294,7 +1942,7 @@ def ragged_bench() -> dict:
 
     # the dense arm is the PRE-PR refresh of record — the single padded
     # rectangle per window AND the copying (undonated) scatter, exactly what
-    # shipped before ISSUE 18; bucketed/bucketed_pallas ride the new
+    # shipped before ISSUE 18; bucketed rides the new
     # defaults (bucketed dispatch + donated scatter). The decompositions
     # stay isolated: waste_ratio measures bucketing alone, the 1M-row probe
     # measures donation alone (both its arms bucketed).
@@ -2509,9 +2157,8 @@ def ragged_bench() -> dict:
 
 
 def main() -> None:
-    orig_env = dict(os.environ)
-    # the parent never holds the chip — pin it to the host CPU before any
-    # jax-importing module loads; the device child gets the original environment
+    # every phase runs on the host CPU — pin it before any jax-importing
+    # module loads
     os.environ["JAX_PLATFORMS"] = "cpu"
     if (os.environ.get("SURGE_BENCH_MESH", "0") == "1"
             or os.environ.get("SURGE_BENCH_RAGGED", "0") == "1"):
@@ -2524,35 +2171,17 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8").strip()
 
-    num_aggregates = int(os.environ.get("SURGE_BENCH_AGGREGATES", 1_000_000))
-    num_events = int(os.environ.get("SURGE_BENCH_EVENTS", 100_000_000))
-    cpu_sample_events = int(os.environ.get("SURGE_BENCH_CPU_SAMPLE", 200_000))
+    payload: dict = {"metric": "commands_per_sec", "value": 0,
+                     "unit": "commands/s"}
 
-    import shutil
-    import tempfile
-
-    from surge_tpu.engine.model import fold_events
-    from surge_tpu.models.counter import CounterModel
-    from surge_tpu.replay.corpus import decode_sample, sample_indices, synth_counter_corpus
-
-    payload: dict = {"metric": "cold_replay_events_per_sec", "value": 0,
-                     "unit": "events/s", "vs_baseline": 0}
-
-    # -- phase 2 first: steady-state latency (no accelerator, no corpus) ----------
-    # running it before the corpus build keeps the multi-GB build/save churn
-    # (page cache pressure, 1-core contention) out of the latency distribution
     try:
         latency_seconds = float(os.environ.get("SURGE_BENCH_LATENCY_SECONDS", 5))
     except ValueError:
         latency_seconds = 0.0
         payload["latency_error"] = "unparseable SURGE_BENCH_LATENCY_SECONDS"
 
-    # SURGE_BENCH_LADDER=1: command-path fast path — regenerate the
-    # throughput ladder + producer sweep WITHOUT the 100M-event corpus
-    # build/replay (the replay numbers are untouched by producer work, and
-    # the corpus build dominates a full run's wall clock)
     # SURGE_BENCH_FAILOVER=1: leader-kill chaos bench — unavailability
-    # window + zero-loss/zero-duplicate proof, no corpus build
+    # window + zero-loss/zero-duplicate proof
     if os.environ.get("SURGE_BENCH_FAILOVER", "0") == "1":
         payload = {"metric": "failover_unavailability_ms", "value": 0,
                    "unit": "ms"}
@@ -2638,10 +2267,7 @@ def main() -> None:
         return
 
     # SURGE_BENCH_RESIDENT=1: device-resident read-plane fast path — read
-    # ladder + refresh-loop folds + command guard, no corpus build. The full
-    # corpus run below still replays through the resident path by default;
-    # SURGE_BENCH_STREAMING=1 (or the legacy SURGE_BENCH_RESIDENT=0) selects
-    # the streaming window path there instead.
+    # ladder + refresh-loop folds + command guard.
     if os.environ.get("SURGE_BENCH_RESIDENT", "0") == "1":
         payload = {"metric": "resident_reads_per_sec", "value": 0,
                    "unit": "reads/s"}
@@ -2675,9 +2301,9 @@ def main() -> None:
         emit(payload)
         return
 
+    # SURGE_BENCH_LADDER=1: command-path fast path — the throughput ladder
+    # + producer sweep, no restore phase
     if os.environ.get("SURGE_BENCH_LADDER", "0") == "1":
-        payload = {"metric": "commands_per_sec", "value": 0,
-                   "unit": "commands/s"}
         secs = latency_seconds if latency_seconds > 0 else 5.0
         # SURGE_BENCH_LANE=1 (the r08 protocol): paired interleaved
         # direct-lane vs classic-lane medians, inproc AND grpc rungs
@@ -2737,7 +2363,7 @@ def main() -> None:
                 except Exception as exc:  # noqa: BLE001
                     log(f"producer sweep failed: {exc!r}")
                     payload["sweep_error"] = f"{type(exc).__name__}: {exc}"
-        except Exception as exc:  # noqa: BLE001 — phase 2 must not void phase 1
+        except Exception as exc:  # noqa: BLE001 — must not void the restore phase
             log(f"steady-state latency phase failed: {exc!r}")
             payload["latency_error"] = f"{type(exc).__name__}: {exc}"
 
@@ -2745,100 +2371,15 @@ def main() -> None:
     if os.environ.get("SURGE_BENCH_RESTORE", "0") == "1":
         try:
             payload.update(restore_bench())
-        except Exception as exc:  # noqa: BLE001 — must not void the headline
+        except Exception as exc:  # noqa: BLE001 — must not void the latency phase
             log(f"restore bench phase failed: {exc!r}")
             payload["restore_error"] = f"{type(exc).__name__}: {exc}"
 
-    t0 = time.perf_counter()
-    corpus = synth_counter_corpus(num_aggregates, num_events, seed=42,
-                                  sort_by_length=True)
-    build_s = time.perf_counter() - t0
-    log(f"corpus: {corpus.num_aggregates} aggregates, {corpus.num_events} events, "
-        f"{corpus.events.nbytes() / 1e9:.2f} GB columnar ({build_s:.1f}s)")
-    payload.update(num_events=corpus.num_events, num_aggregates=corpus.num_aggregates,
-                   corpus_build_s=round(build_s, 1))
-
-    corpus_dir = tempfile.mkdtemp(prefix="surge-bench-corpus-")
-    try:
-        t0 = time.perf_counter()
-        save_corpus(corpus, corpus_dir)
-        log(f"corpus saved to {corpus_dir} ({time.perf_counter() - t0:.1f}s)")
-
-        # one-time wire pack (the log-segment build analog, SURVEY §5.4): cold
-        # replays mmap this and stream it straight onto the device. Skipped
-        # when the streaming path is benched — no child would read it.
-        if (os.environ.get("SURGE_BENCH_STREAMING", "0") != "1"
-                and os.environ.get("SURGE_BENCH_RESIDENT", "1") == "1"):
-            t0 = time.perf_counter()
-            make_engine().pack_resident(corpus.events).save(
-                os.path.join(corpus_dir, "wire"))
-            wire_pack_s = time.perf_counter() - t0
-            log(f"wire packed+saved ({wire_pack_s:.1f}s, one-time build)")
-            payload["wire_pack_s"] = round(wire_pack_s, 1)
-
-        # -- scalar CPU fold baseline (the reference restore path) --------------------
-        idx = sample_indices(corpus, cpu_sample_events)
-        logs = decode_sample(corpus, idx)
-        n_sample = sum(len(l) for l in logs)
-        model = CounterModel()
-        t0 = time.perf_counter()
-        folded = [fold_events(model, None, events) for events in logs]
-        cpu_s = time.perf_counter() - t0
-        cpu_eps = n_sample / cpu_s
-        # golden cross-check: scalar fold must agree with the closed-form expectation
-        for j, state in zip(idx, folded):
-            expect = (int(corpus.expected_count[j]), int(corpus.expected_version[j]))
-            got = (state.count, state.version) if state is not None else (0, 0)
-            if got != expect:
-                raise AssertionError(
-                    f"scalar fold mismatch at aggregate {j}: {got} != {expect}")
-        log(f"cpu baseline: {n_sample} events over {len(logs)} aggregates in "
-            f"{cpu_s:.2f}s -> {cpu_eps:,.0f} events/s (verified)")
-        payload["cpu_baseline_events_per_sec"] = round(cpu_eps)
-
-        # the corpus lives on disk now; free the ~1.6 GB in-memory copy (and the
-        # decoded sample) before replay children map the same data
-        del corpus, logs, folded
-
-        # -- CPU-JAX batched replay: a host figure, never the headline ----------------
-        if os.environ.get("SURGE_BENCH_SKIP_CPU_REPLAY", "0") != "1":
-            cpu_child = run_replay_child(_cpu_env(orig_env), corpus_dir, "cpu")
-            if cpu_child is not None:
-                payload["cpu_jax_events_per_sec"] = cpu_child["events_per_sec"]
-            else:
-                payload["cpu_replay_error"] = "cpu replay child failed (see stderr)"
-
-        # -- the ONE device child: always attempted, must come up on a TPU ------------
-        tpu_child = run_replay_child(dict(orig_env), corpus_dir, "device")
-        if tpu_child is None:
-            raise RuntimeError("device replay child failed (see stderr)")
-        if tpu_child["platform"] != "tpu":
-            raise RuntimeError(
-                f"device replay child came up on {tpu_child['platform']!r}, not "
-                f"'tpu' (JAX_PLATFORMS={orig_env.get('JAX_PLATFORMS')!r}); a "
-                "host-platform replay is not a result")
-        _merge_replay(payload, tpu_child, cpu_eps)
-        if cpu_eps and tpu_child.get("steady_events_per_sec"):
-            payload["vs_baseline_steady"] = round(
-                tpu_child["steady_events_per_sec"] / cpu_eps, 2)
-        log(f"speedup vs scalar CPU fold: {payload['vs_baseline']}x cold, "
-            f"{payload.get('vs_baseline_steady', 0)}x steady on "
-            f"{payload['platform']} (target >=50x)")
-        emit(payload)
-    finally:
-        shutil.rmtree(corpus_dir, ignore_errors=True)
+    payload["value"] = payload.get("peak_commands_per_sec", 0)
+    emit(payload)
 
 
 if __name__ == "__main__":
-    if len(sys.argv) >= 3 and sys.argv[1] == "--replay-child":
-        try:
-            replay_child(sys.argv[2])
-        except BaseException:
-            import traceback
-
-            traceback.print_exc(file=sys.stderr)
-            sys.exit(1)
-        sys.exit(0)
     try:
         main()
     except BaseException as err:  # terminal failure must still emit one JSON line
@@ -2848,8 +2389,7 @@ if __name__ == "__main__":
         # never clobber an already-measured result with a value-0 line: re-emit the
         # last printed payload with the error attached (last line wins)
         final = dict(_last_printed) if _last_printed else {
-            "metric": "cold_replay_events_per_sec", "value": 0,
-            "unit": "events/s", "vs_baseline": 0}
+            "metric": "commands_per_sec", "value": 0, "unit": "commands/s"}
         final["error"] = f"{type(err).__name__}: {err}"
         print(json.dumps(final), flush=True)
         sys.exit(1)

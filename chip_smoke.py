@@ -22,9 +22,8 @@ donation on), every answer checked against a plain reference outside any timing:
 Exits non-zero, printing no result line, unless ``jax.devices()[0].platform`` is
 ``tpu``. ``--cpu-tiny`` runs the same code at a tiny size on the CPU backend, to
 debug before chip time is spent. ``--mesh`` runs the sharded paths over every
-visible device instead (a four-chip host); ``--kernels`` compiles the Pallas
-tile scan at the engine's shapes against the XLA tile. The last line of a passing
-run is ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+visible device instead (a four-chip host). The last line of a passing run is
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 """
 
 from __future__ import annotations
@@ -617,54 +616,6 @@ async def leg_mesh(sizes: dict, seed: int, workdir: str) -> dict:
 
 
 # --------------------------------------------------------------------------------------
-# --kernels: the Pallas tile scan through Mosaic, against the XLA tile
-# --------------------------------------------------------------------------------------
-
-def leg_kernels(sizes: dict, seed: int) -> dict:
-    import numpy as np
-
-    from surge_tpu.codec.tensor import encode_events_columnar
-    from surge_tpu.config import default_config
-    from surge_tpu.models import bank_account, counter, shopping_cart
-    from surge_tpu.replay import ReplayEngine
-    from surge_tpu.replay.corpus import synth_counter_corpus
-    from surge_tpu.testing import random_bank_log, random_cart_log
-
-    def both(spec, colev) -> dict:
-        out = {}
-        for tile in ("xla", "pallas"):
-            engine = ReplayEngine(spec, config=default_config().with_overrides(
-                {"surge.replay.tile-backend": tile}))
-            out[tile] = engine.replay_resident(
-                engine.prepare_resident(colev)).states
-        for name in out["xla"]:
-            check(np.array_equal(out["xla"][name], out["pallas"][name]),
-                  f"kernels: pallas tile differs from the xla tile in {name}")
-        return out["pallas"]
-
-    # cold tile scan at the engine's shapes: 8192- and 1024-lane tiles, width 512
-    corpus = synth_counter_corpus(max(sizes["mesh_aggregates"] // 4, 1_024),
-                                  max(sizes["mesh_events"] // 4, 40_000),
-                                  seed=seed)
-    states = both(counter.make_replay_spec(), corpus.events)
-    check(np.array_equal(states["count"], corpus.expected_count),
-          "kernels: pallas tile differs from the closed form")
-    rng = random.Random(seed)
-    vocab = bank_account.Vocab()
-    n = sizes["family_aggregates"]
-    spec = bank_account.make_replay_spec()
-    both(spec, encode_events_columnar(spec.registry, [
-        [bank_account.encode_event(vocab, e)
-         for e in random_bank_log(rng, f"b{i}")] for i in range(n)]))
-    spec = shopping_cart.make_replay_spec()
-    both(spec, encode_events_columnar(spec.registry, [
-        random_cart_log(rng, f"c{i}") for i in range(n)]))
-
-    return {"tile_scan": "equals the xla tile (counter, bank_account, "
-                         "shopping_cart)"}
-
-
-# --------------------------------------------------------------------------------------
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -673,8 +624,6 @@ def main() -> int:
                     help="debug run: tiny sizes on the CPU backend")
     ap.add_argument("--mesh", action="store_true",
                     help="run the sharded paths over every visible device")
-    ap.add_argument("--kernels", action="store_true",
-                    help="compile the Pallas tile scan against the XLA tile")
     for key in ("cold_aggregates", "cold_events", "served_aggregates"):
         ap.add_argument("--" + key.replace("_", "-"), type=int, default=None,
                         help=f"cut of scale (stated: {STATED[key]:,})")
@@ -728,9 +677,7 @@ def main() -> int:
     say("native", build_native())
 
     workdir = tempfile.mkdtemp(prefix="surge-chip-smoke-")
-    if args.kernels:
-        legs = [("kernels", lambda: leg_kernels(sizes, args.seed))]
-    elif args.mesh:
+    if args.mesh:
         legs = [("mesh", lambda: asyncio.run(leg_mesh(sizes, args.seed, workdir)))]
     else:
         legs = [("cold", lambda: leg_cold(sizes, args.seed)),

@@ -1,6 +1,6 @@
 """Root conftest: force a virtual 8-device CPU platform for all tests.
 
-The chip runs chip_smoke.py and bench.py's device child; tests exercise the
+The chip runs chip_smoke.py and benchmarks/run.py; tests exercise the
 multi-device sharding paths on the host (xla_force_host_platform_device_count), per the
 driver contract. Both variables must be in place before jax initialises a backend,
 which conftest import time guarantees; the jax.config update covers a jax that some

@@ -147,15 +147,9 @@ DEFAULTS: dict[str, Any] = {
     # deleted buffer either way — the plane republishes the handle per
     # window and the gather lane retries across a donation race)
     "surge.replay.donate-refresh": True,
-    # scan-step dispatch ("switch" = lax.switch over schema branches,
-    # "select" = compute-all-and-select) and the tile-loop backend ("auto"
-    # picks the scanless assoc tree fold for models shipping AssociativeFold)
-    "surge.replay.dispatch": "switch",  # switch | select
-    "surge.replay.tile-backend": "auto",  # auto | xla | pallas | assoc
-    # bucket the resident corpus's device buffers to powers of two rows
-    # ("pow2", padded on the device) so the jit cache sees few shapes, or keep
-    # exact lengths ("exact")
-    "surge.replay.resident-len-bucket": "pow2",  # pow2 | exact
+    # the tile-loop backend ("auto" picks the scanless assoc tree fold for
+    # models shipping AssociativeFold, off a CPU host)
+    "surge.replay.tile-backend": "auto",  # auto | xla | assoc
     # overlap segment-stream uploads with replay dispatches in N segments
     # (0/1 = plain upload+replay)
     "surge.replay.upload-stream-segments": 0,

@@ -384,8 +384,7 @@ class EngineMetrics:
             "was drained (backpressure indicator)"))
         self.replay_events_per_sec = m.gauge(MI(
             "surge.replay.rebuild-events-per-sec",
-            "events/s of the latest bulk rebuild, end to end (compare "
-            "bench.py's cold_replay_events_per_sec for the fold alone)"))
+            "events/s of the latest bulk rebuild, end to end"))
         self.live_entities = m.gauge(MI(
             "surge.engine.live-entities", "currently resident aggregate entities"))
         self.standby_lag = m.gauge(MI(

@@ -56,8 +56,8 @@ def ensure_compile_cache() -> Optional[str]:
     jax reads the variable itself and this changes no config (returns None).
     Otherwise the cache goes to :data:`COMPILE_CACHE_DIR`. jax decides once,
     at its first compilation, whether the cache is in use — so every root of
-    device programs (``ReplayEngine.__init__``, ``chip_smoke.py``, the bench
-    children) calls this before building one. Idempotent."""
+    device programs (``ReplayEngine.__init__``, ``chip_smoke.py``,
+    ``benchmarks/run.py``) calls this before building one. Idempotent."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return None
     if jax.config.jax_compilation_cache_dir != COMPILE_CACHE_DIR:
@@ -65,23 +65,15 @@ def ensure_compile_cache() -> Optional[str]:
     return COMPILE_CACHE_DIR
 
 
-def make_step_fn(spec: ReplaySpec, dispatch: str = "switch"
+def make_step_fn(spec: ReplaySpec
                  ) -> Callable[[StateTree, Mapping[str, Any]], StateTree]:
     """One-event step for a single aggregate: dispatch on type_id, mask padding.
 
     The returned function is scalar over the batch dim (engine vmaps it). Any type_id
     outside ``[0, num_types)`` — padding (-1) or corrupt positive ids — carries state
-    through unchanged rather than dispatching to an arbitrary handler.
-
-    ``dispatch`` picks the lowering:
-
-    - ``"switch"`` — ``lax.switch`` on the (clipped) type id; under ``vmap``
-      XLA turns this into predicated branches.
-    - ``"select"`` — branchless: EVERY handler runs on every slot and results
-      mask-combine with ``where``. More FLOPs but pure VPU data flow with no
-      per-branch control overhead; event handlers are a few scalar ops each,
-      so on TPU the extra arithmetic is usually cheaper than the branch
-      machinery (``surge.replay.dispatch`` selects it engine-wide).
+    through unchanged rather than dispatching to an arbitrary handler. The
+    dispatch is ``lax.switch`` on the (clipped) type id; under ``vmap`` XLA
+    turns this into predicated branches.
     """
     num_types = spec.registry.num_event_types
     handlers = spec.handlers.ordered(num_types)
@@ -95,28 +87,6 @@ def make_step_fn(spec: ReplaySpec, dispatch: str = "switch"
             v = new.get(name, old[name])
             out[name] = jnp.asarray(v, dtype=old[name].dtype)
         return out
-
-    if dispatch == "select":
-        def pick(hit, new, old):
-            # bool columns combine by mask logic: the Mosaic tile kernel
-            # (pallas_fold) cannot select between 1-bit vectors
-            if old.dtype == jnp.bool_:
-                return (hit & new) | (~hit & old)
-            return jnp.where(hit, new, old)
-
-        def step(state: StateTree, event: Mapping[str, Any]) -> StateTree:
-            tid = event["type_id"]
-            fields = {k: v for k, v in event.items() if k != "type_id"}
-            out = state
-            for t, h in enumerate(handlers):
-                new = normalize(h(state, fields), state)
-                hit = tid == t
-                out = {k: pick(hit, new[k], out[k]) for k in out}
-            return out
-
-        return step
-    if dispatch != "switch":
-        raise ValueError(f"unknown dispatch {dispatch!r} (switch|select)")
 
     def step(state: StateTree, event: Mapping[str, Any]) -> StateTree:
         tid = event["type_id"]
@@ -132,21 +102,20 @@ def make_step_fn(spec: ReplaySpec, dispatch: str = "switch"
     return step
 
 
-def make_batch_fold(spec: ReplaySpec, *, unroll: int = 1, dispatch: str = "switch"):
+def make_batch_fold(spec: ReplaySpec):
     """Batched fold: ``(carry {name:[B]}, events {col:[T,B]}) -> carry``.
 
     The per-aggregate fold of CommandModels.scala:20-21 / PersistentActor's applyEvents,
     vectorized: ``lax.scan`` over T of ``vmap``-over-B of the switch step. jit-compiled by
     the caller (ReplayEngine) with carry donation.
     """
-    step = make_step_fn(spec, dispatch)
-    vstep = jax.vmap(step, in_axes=(0, 0))
+    vstep = jax.vmap(make_step_fn(spec), in_axes=(0, 0))
 
     def fold(carry: StateTree, events: Mapping[str, jnp.ndarray]) -> StateTree:
         def scan_body(c, ev_t):
             return vstep(c, ev_t), None
 
-        out, _ = jax.lax.scan(scan_body, carry, events, unroll=unroll)
+        out, _ = jax.lax.scan(scan_body, carry, events)
         return out
 
     return fold
@@ -180,24 +149,18 @@ class ResidentCorpus:
 #: small tile-width cap still satisfies engines configured with a larger one
 _WIRE_GUARD_MIN = 8192
 
-def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
-                    unroll: int, dispatch: str, tile_backend: str):
+def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int,
+                    tile_backend: str):
     """The tile-interior fold of the resident tiles (single-device and
     mesh-sharded): ``(carry {f: [bs]}, words u32 [width, bs],
     sides {n: [width, bs]}, lens [bs], ord_base [bs], t_base) -> carry``.
 
-    Three lowerings per ``tile_backend``: the sequential XLA time scan, the
-    Pallas VMEM kernel, or — when the spec ships a law-checked
-    ``AssociativeFold`` — a liftless-scan tree reduction (no per-step loop
-    machinery at all)."""
-    batch_step = jax.vmap(make_step_fn(spec, dispatch), in_axes=(0, 0))
-    pallas_scan = None
+    Two lowerings per ``tile_backend``: the sequential XLA time scan, or —
+    when the spec ships a law-checked ``AssociativeFold`` — a liftless-scan
+    tree reduction (no per-step loop machinery at all)."""
+    batch_step = jax.vmap(make_step_fn(spec), in_axes=(0, 0))
     afold = None
-    if tile_backend == "pallas":
-        from surge_tpu.replay.pallas_fold import make_tile_scan
-
-        pallas_scan = make_tile_scan(spec, wire, width, bs, unroll)
-    elif tile_backend == "assoc":
+    if tile_backend == "assoc":
         from surge_tpu.replay.seqpar import ensure_validated
 
         afold = spec.associative
@@ -205,7 +168,7 @@ def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
             raise ValueError(
                 "surge.replay.tile-backend = assoc requires the ReplaySpec to "
                 "carry an AssociativeFold (spec.associative) — this model "
-                "only supports the sequential xla/pallas tile scan")
+                "only supports the sequential xla tile scan")
         if width & (width - 1):
             raise ValueError(
                 f"assoc tile backend needs a power-of-two time width, got {width}")
@@ -214,11 +177,6 @@ def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
         ensure_validated(afold, spec)
 
     def fold_body(carry, words, sides, lens, ord_base, t_base):
-        if pallas_scan is not None:
-            # the time scan as a VMEM-resident kernel (relative time)
-            return pallas_scan(carry, words, sides, lens - t_base,
-                               ord_base + t_base)
-
         if afold is not None:
             # no scan at all: lift every slot of the [width, bs] tile at once,
             # pairwise tree-reduce the summaries over TIME (combine is
@@ -247,8 +205,7 @@ def _make_fold_body(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
             events = wire.decode_words(w_row, side_row, t < lens, ord_base, t)
             return batch_step(c, events), None
 
-        out, _ = jax.lax.scan(body, carry, (words, sides, ts),
-                              unroll=unroll)
+        out, _ = jax.lax.scan(body, carry, (words, sides, ts))
         return out
 
     return fold_body
@@ -445,7 +402,7 @@ def _make_lane_fetch(wire: WireFormat, width: int, gather: str):
 
 
 def _make_tile(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
-               unroll: int, dispatch: str, tile_backend: str, gather: str):
+               tile_backend: str, gather: str):
     """The tile of the resident programs (single-device AND
     mesh-sharded), as ``(view, tile)``: ``view(flat_wire u8 [N, nbytes],
     side_flat) -> buffers`` once a program, and ``tile(state_slab {f:
@@ -458,8 +415,7 @@ def _make_tile(spec: ReplaySpec, wire: WireFormat, width: int, bs: int,
     (:func:`_make_lane_fetch`), the shared fold body
     (:func:`_make_fold_body`), and a contiguous write-back into the state
     slab. ``i0``/``t_base`` are traced scalars."""
-    fold_body = _make_fold_body(spec, wire, width, bs, unroll, dispatch,
-                                tile_backend)
+    fold_body = _make_fold_body(spec, wire, width, tile_backend)
     view, fetch = _make_lane_fetch(wire, width, gather)
 
     def tile(slab_state, buffers, starts_all, lens_all, ord_all, i0, t_base):
@@ -587,9 +543,7 @@ def _put_pieces(pieces: list, bucket: int):
     of ``bucket`` rows, element for element ``np.pad(arr, bucket)``. A piece
     that is the whole bucket is put as it is. Otherwise each is placed at its
     row offset by :func:`mk_wire` right after its put, into a bucket of
-    device zeros, which also supplies every row past the last piece (under
-    ``resident-len-bucket = exact`` the one piece is the array, of any
-    length, and the bucket the rows its fold reads)."""
+    device zeros, which also supplies every row past the last piece."""
     piece_rows = pieces[0].shape[0]
     if piece_rows == bucket:
         return jax.device_put(pieces[0])
@@ -907,8 +861,7 @@ class ReplayEngine:
 
     def __init__(self, spec: ReplaySpec, config: Config | None = None,
                  mesh: Optional[jax.sharding.Mesh] = None,
-                 mesh_axis: Optional[str] = None, unroll: int = 1,
-                 profiler=None) -> None:
+                 mesh_axis: Optional[str] = None, profiler=None) -> None:
         ensure_compile_cache()
         self.spec = spec
         self.config = config or default_config()
@@ -929,14 +882,12 @@ class ReplayEngine:
             max(self.config.get_int("surge.replay.batch-size"), lane), lane)
         self.buckets = self.config.get_int_list("surge.replay.length-buckets", "64,256,1024,4096")
 
-        self._unroll = unroll
-        self._dispatch = self.config.get_str("surge.replay.dispatch", "switch")
         self._tile_backend = self.config.get_str("surge.replay.tile-backend",
                                                  "auto")
-        if self._tile_backend not in ("auto", "xla", "pallas", "assoc"):
+        if self._tile_backend not in ("auto", "xla", "assoc"):
             raise ValueError(
                 f"unknown surge.replay.tile-backend "
-                f"{self._tile_backend!r} (auto|xla|pallas|assoc)")
+                f"{self._tile_backend!r} (auto|xla|assoc)")
         # "auto" resolves lazily (the choice is backend-dependent and reading
         # the backend here would initialize it in engine-constructing
         # processes that never dispatch)
@@ -996,8 +947,7 @@ class ReplayEngine:
         if hit is not None:
             return (key, *hit)
         wire = WireFormat(self.spec.registry, derived_cols)
-        batch_fold = make_batch_fold(self.spec, unroll=self._unroll,
-                                     dispatch=self._dispatch)
+        batch_fold = make_batch_fold(self.spec)
 
         def fold(carry: StateTree, packed, side, ord_base) -> StateTree:
             return batch_fold(carry, wire.decode(packed, side, ord_base))
@@ -1481,20 +1431,17 @@ class ReplayEngine:
         """Device-side half of :meth:`prepare_resident`: ship a packed wire
         corpus (fresh or mmapped from disk) and return the replay handle.
 
-        The device buffers' lengths are bucketed to powers of two by default
-        (``surge.replay.resident-len-bucket = pow2``), so consecutive uploads
-        of different-sized corpora — segment chunks in a restore — reuse one
-        compiled program per bucket instead of recompiling per exact length.
+        The device buffers' lengths are bucketed to powers of two, so
+        consecutive uploads of different-sized corpora — segment chunks in a
+        restore — reuse one compiled program per bucket instead of
+        recompiling per exact length.
         The bucket is the device buffer's, and one bucket serves the whole
         wire: the packed buffer's (``N + guard`` rows), which a side column
         of ``N`` rows shares, so every buffer a fold reads has one shape and
         zeros after the last event. The wire's own buffers go up as they are,
         in fixed-shape row pieces where the bucket is longer than one, and
         the host copies at most one piece an array (:func:`_bucket_pieces`,
-        :func:`_put_pieces`). ``exact`` puts each buffer whole at its own
-        length, a side column then placed into device zeros of the packed
-        buffer's rows, for single-corpus workloads that warm explicitly
-        (bench).
+        :func:`_put_pieces`).
 
         A wire that carries the word's sources (:class:`WordSources`) and
         no packed buffer yet sends each source column up as a side column
@@ -1506,8 +1453,7 @@ class ReplayEngine:
         flags it, the flags are read once every buffer is ready, and the
         ``ValueError`` (the host's message) leaves no corpus behind. A wire
         whose packed buffer exists (loaded, built for a ``save``, hand-made)
-        goes up as it lies, and ``exact`` reads ``packed``, built on the host
-        if need be.
+        goes up as it lies.
 
         The wire's side columns, type ids and packed columns may be the
         caller's arrays (:meth:`pack_resident`): every buffer has landed in
@@ -1531,29 +1477,24 @@ class ReplayEngine:
         b = w.lengths.shape[0]
         with stage("h2d", follows=w.trace_ctx, wire_bytes=_wire_nbytes(w),
                    side_bytes=_side_nbytes(w.side)) as h2d:
-            pow2 = self.config.get_str(
-                "surge.replay.resident-len-bucket", "pow2") == "pow2"
             with stage("h2d.bucket") as bucket:
                 bs = min(self.batch_size,
                          _round_up(max(b, 1), self._lane_multiple()))
-                b_pad = _round_up(max(b, 1), bs)
-                if pow2:
-                    chunks = 1
-                    while chunks * bs < b_pad:
-                        chunks *= 2
-                    b_pad = chunks * bs
+                chunks = 1
+                while chunks * bs < b:
+                    chunks *= 2
+                b_pad = chunks * bs
                 starts_p = _pad_rows(w.starts, b_pad)
                 lens_p = _pad_rows(w.lengths, b_pad)
                 copied_bytes = starts_p.nbytes + lens_p.nbytes
                 # the word: its sources where the device is to build it,
-                # else the packed buffer (exact: built on the host if need be)
-                on_device = pow2 and not w.host_packed
+                # else the packed buffer
+                on_device = not w.host_packed
                 word = w.words.arrays() if on_device else (w.packed,)
                 # one bucket a wire, the packed buffer's: a side column of
                 # fewer rows gets the same device shape
                 rows = w.packed_shape[0]
-                host = [_bucket_pieces(arr, _PIECE_ROWS, rows) if pow2
-                        else ([arr], 0)
+                host = [_bucket_pieces(arr, _PIECE_ROWS, rows)
                         for arr in (*word, *w.side.values())]
                 copied_bytes += sum(copied for _, copied in host)
                 pieces = sum(len(ps) for ps, _ in host)
@@ -1562,13 +1503,8 @@ class ReplayEngine:
                                    for p in ps) if on_device else 0
                 bucket.set_attribute("copied_bytes", copied_bytes)
             with stage("h2d.put", put_bytes=put_bytes, pieces=pieces):
-                # exact: the packed buffer's own rows, whole rows of
-                # _LANE_ROW events where the fetch reads rows
-                whole = (_bucket_len(rows) if pow2 else rows
-                         if self.lane_gather == "slices"
-                         else _round_up(rows, _LANE_ROW))
                 flat_wire, sides, flags = _put_wire(
-                    host, whole, wire,
+                    host, _bucket_len(rows), wire,
                     self._word_program(wire) if on_device else None)
                 flat_side = dict(zip(w.side, sides))
                 starts_dev = jax.device_put(starts_p)
@@ -2260,30 +2196,6 @@ class ReplayEngine:
         return _tile_width(lengths, bs_big, bs_small, self._tile_widths(),
                            self.lane_gather)
 
-    def warm_resident(self, resident: "ResidentCorpus") -> None:
-        """Compile every program a :meth:`replay_resident` of this corpus will
-        dispatch, against the real corpus buffers, with zero-trip work lists,
-        so a timed pass runs with zero in-window compiles."""
-        b = resident.lengths.shape[0]
-        if b == 0:
-            return
-        plan = self._plan_for(resident)
-        key = frozenset(resident.derived_key.items())
-        for bs, i0s in ((plan.bs_big, plan.big_i0),
-                        (plan.bs_small, plan.small_i0)):
-            if len(i0s) == 0:
-                continue
-            k_cap = self._plan_cap(len(i0s))
-            slab, ord_d = self._fresh_slab(resident.b_pad)
-            fold = self._resident_program(key, plan.width, bs, k_cap)
-            wl = jnp.zeros((k_cap,), dtype=jnp.int32)
-            out = fold(slab, resident.flat_wire, resident.flat_side,
-                       resident.starts_dev, resident.lens_dev, ord_d,
-                       wl, wl, np.int32(0))
-            jax.block_until_ready(out)
-            self._signatures.add(self._resident_signature(
-                resident, key, plan.width, bs, k_cap))
-
     @staticmethod
     def _resident_signature(resident: "ResidentCorpus", key: frozenset,
                             width: int, bs: int, k_cap: int) -> tuple:
@@ -2314,9 +2226,8 @@ class ReplayEngine:
         import jax
 
         wire = WireFormat(self.spec.registry, dict(key))
-        view, tile = _make_tile(self.spec, wire, width, bs, self._unroll,
-                                self._dispatch, self.tile_backend,
-                                self.lane_gather)
+        view, tile = _make_tile(self.spec, wire, width, bs,
+                                self.tile_backend, self.lane_gather)
 
         def fold(slab_state, flat_wire, side_flat, starts_all, lens_all,
                  ord_all, i0s, t_bases, k_n):

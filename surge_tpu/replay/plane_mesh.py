@@ -180,7 +180,7 @@ class MeshPlane:
 
         plane = self.plane
         wire = plane._wire
-        fold = make_batch_fold(plane.spec, dispatch=plane._dispatch)
+        fold = make_batch_fold(plane.spec)
         fnames = [f.name for f in self._fields]
 
         def local(slab_d, ords_d, adm_loc, adm_vals, adm_ord, lane_loc,

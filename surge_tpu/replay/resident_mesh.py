@@ -339,9 +339,8 @@ def _sharded_program(engine, key: frozenset, width: int, bs: int, k_cap: int):
     from jax.sharding import PartitionSpec as P
 
     wire = WireFormat(engine.spec.registry, dict(key))
-    view, tile = _make_tile(engine.spec, wire, width, bs, engine._unroll,
-                            engine._dispatch, engine.tile_backend,
-                            engine.lane_gather)
+    view, tile = _make_tile(engine.spec, wire, width, bs,
+                            engine.tile_backend, engine.lane_gather)
 
     # the one-chip program's name: XLA calls both ``jit_fold``, and the
     # benchmark's trace reduction maps that name to the cold fold's layer

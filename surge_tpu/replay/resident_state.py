@@ -176,7 +176,6 @@ class ResidentStatePlane(Controllable):
             "surge.replay.resident.refresh-max-poll-records", 4096)
         self._poll_timeout = max(self.config.get_seconds(
             "surge.replay.resident.refresh-interval-ms", 50), 0.001)
-        self._dispatch = self.config.get_str("surge.replay.dispatch", "switch")
         # refresh window width: the time-chunk rounded to a power of two —
         # rounds longer than one window fold through several chained windows
         self._window = _pow2(
@@ -353,7 +352,7 @@ class ResidentStatePlane(Controllable):
         import jax.numpy as jnp
 
         wire = self._wire
-        fold = make_batch_fold(self.spec, dispatch=self._dispatch)
+        fold = make_batch_fold(self.spec)
         names = [f.name for f in self._fields]
         # the read wire follows the DEVICE dtypes, not the schema's: with
         # jax_enable_x64 off (the default) a 64-bit schema column is

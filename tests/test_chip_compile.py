@@ -68,8 +68,7 @@ def compile_report() -> dict:
         side = {f.name: shape((N,), f.dtype) for f in wire.side_fields}
         report[name] = {}
         for gather in ("rows", "slices"):
-            view, tile = _make_tile(spec, wire, WIDTH, BS, 1, "switch",
-                                    "assoc", gather)
+            view, tile = _make_tile(spec, wire, WIDTH, BS, "assoc", gather)
 
             def fold(slab_state, flat_wire, side_flat, starts, lens, ords,
                      i0s, t_bases, k_n):

@@ -5,8 +5,7 @@ device is element for element ``np.pad`` of what the whole-column host pack
 (``tests/test_pack_blocked.py:plain_pack``) builds, the states are the
 host-packed wire's, and a column outside its declared width raises the host's
 message before any corpus exists. Input that cannot go up as it lies (int64,
-strided), a saved wire, ``exact`` buckets and the streamed fold keep the
-host's pass."""
+strided), a saved wire and the streamed fold keep the host's pass."""
 
 import time
 
@@ -297,25 +296,19 @@ def test_a_saved_source_wire_is_the_host_packed_file(small_pieces, tmp_path,
     assert [a["word_source_bytes"] for a in h2d_since(since)] == [0, 0]
 
 
-@pytest.mark.parametrize("how", ["exact", "streamed"])
 @pytest.mark.parametrize("schema", ["counter-1B", "counter-1B-side"])
-def test_exact_buckets_and_the_streamed_fold_read_the_hosts_buffer(
-        small_pieces, schema, how):
-    keys = ({"surge.replay.resident-len-bucket": "exact"}
-            if how == "exact" else {})
-    engine = make_engine(schema, **keys)
+def test_the_streamed_fold_reads_the_hosts_buffer(small_pieces, schema):
+    engine = make_engine(schema)
     events = make_events(schema, "several-pieces", seed=25)
     want = engine.replay_resident(engine.upload_resident(
         plain_pack(engine, events)))
     wire = engine.pack_resident(events)
     assert not wire.host_packed
     since = time.monotonic()
-    got = (engine.replay_resident(engine.upload_resident(wire))
-           if how == "exact" else
-           engine.replay_resident_streamed(wire, segments=3))
+    got = engine.replay_resident_streamed(wire, segments=3)
     assert wire.host_packed
     assert all(a["word_source_bytes"] == 0 for a in h2d_since(since))
-    assert len(h2d_since(since)) == (1 if how == "exact" else 3)
+    assert len(h2d_since(since)) == 3
     assert_same_states(got, want)
 
 

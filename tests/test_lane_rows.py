@@ -22,7 +22,7 @@ from surge_tpu.log.columnar import (build_segment_from_topic,
                                     extend_segment_from_topic)
 from surge_tpu.models import counter, shopping_cart
 from surge_tpu.replay import engine as engine_module
-from surge_tpu.replay.engine import (_LANE_ROW, ReplayEngine,
+from surge_tpu.replay.engine import (_LANE_ROW, ReplayEngine, _bucket_len,
                                      _make_lane_fetch, _rows_per_lane)
 from surge_tpu.replay.resident_state import ResidentStatePlane
 from surge_tpu.serialization import SerializedMessage
@@ -358,37 +358,13 @@ def test_the_planes_cold_folds_on_rows_match_the_scalar_fold(rows_on_cpu,
     assert fold.attributes["rows_fetched"] > 0
 
 
-def test_exact_bucket_rounds_the_device_buffers_up(rows_on_cpu):
-    """``resident-len-bucket = exact`` with a length ``A`` does not divide:
-    the host puts the wire as it is and the device pads it to whole rows."""
-    logs = cart_logs(n_agg=90, seed=2)
-    engine = make_engine(shopping_cart, "assoc", **{
-        "surge.replay.resident-len-bucket": "exact"})
-    wire = engine.pack_resident(
-        encode_events_columnar(shopping_cart.make_registry(), logs))
-    n = wire.packed.shape[0]
-    assert n % A, "pick a corpus the row does not divide"
-    resident = engine.upload_resident(wire)
-    assert resident.wire_bytes == wire.packed.nbytes + sum(
-        v.nbytes for v in wire.side.values())  # nothing more crossed the link
-    want_rows = -(-n // A) * A
-    assert resident.flat_wire.shape == (want_rows, wire.packed.shape[1])
-    assert all(v.shape == (want_rows,) for v in resident.flat_side.values())
-    assert not np.asarray(resident.flat_wire[n:]).any()
-    got = engine.replay_resident(resident)
-    want = engine.replay_ragged(logs)
-    for field, col in want.states.items():
-        np.testing.assert_array_equal(got.states[field], col, field)
-
-
 def test_a_cpu_host_keeps_the_slices():
     """No accelerator here: the backend's own choice is the old slice, and the
-    buffers stay as the host put them."""
-    engine = make_engine(counter, "xla", **{
-        "surge.replay.resident-len-bucket": "exact"})
+    buffers are the host's rows in their power-of-two bucket."""
+    engine = make_engine(counter, "xla")
     assert engine.lane_gather == "slices"
     wire = engine.pack_resident(
         encode_events_columnar(counter.make_registry(), counter_logs(7, 9)))
     resident = engine.upload_resident(wire)
-    assert resident.flat_wire.shape[0] == wire.packed.shape[0]
+    assert resident.flat_wire.shape[0] == _bucket_len(wire.packed_shape[0])
     assert (engine.replay_resident(resident).states["version"] == 9).all()

@@ -56,15 +56,14 @@ def _refresh_sigs(plane):
     return {s for s in plane._signatures if s[0] == "refresh"}
 
 
-# -- golden byte-identity: bucketed refresh over an xla- and a pallas-seeded plane ----
+# -- golden byte-identity: bucketed refresh over an xla- and an assoc-seeded plane ----
 
 
 @pytest.mark.parametrize("overrides", [
     {"surge.replay.resident.refresh-dispatch": "bucketed"},
     {"surge.replay.resident.refresh-dispatch": "bucketed",
-     "surge.replay.tile-backend": "pallas",
-     "surge.replay.dispatch": "select"},
-], ids=["bucketed", "bucketed-pallas-seed"])
+     "surge.replay.tile-backend": "assoc"},
+], ids=["bucketed", "bucketed-assoc-seed"])
 def test_bucketed_refresh_golden_byte_identity(overrides):
     """Incremental bucketed refresh rounds — across evictions, re-admissions
     AND a partition revoke/re-grant — byte-identical to the full cold-start

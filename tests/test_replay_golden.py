@@ -294,10 +294,10 @@ def test_mesh_sharded_resident_small_tiles_fold_once(mesh8):
         np.unique(np.asarray(res.states["count"]))
 
 
-def test_mesh_sharded_resident_pallas_golden(mesh8):
-    """The Pallas tile-scan kernel under shard_map (``tile-backend = pallas``
-    inside the sharded fold's per-device tile loop): byte-identical states to
-    the scalar fold, including a resumed fold with ordinal bases."""
+def test_mesh_sharded_resident_assoc_golden(mesh8):
+    """The assoc tree fold under shard_map (``tile-backend = assoc`` inside
+    the sharded fold's per-device tile loop): byte-identical states to the
+    scalar fold, including a resumed fold with ordinal bases."""
     from surge_tpu.codec.tensor import encode_events_columnar
 
     model = counter.CounterModel()
@@ -306,9 +306,9 @@ def test_mesh_sharded_resident_pallas_golden(mesh8):
 
     cfg = Config(overrides={"surge.replay.batch-size": 128,
                             "surge.replay.time-chunk": 16,
-                            "surge.replay.tile-backend": "pallas",
-                            "surge.replay.dispatch": "select"})
+                            "surge.replay.tile-backend": "assoc"})
     eng = ReplayEngine(model.replay_spec(), config=cfg, mesh=mesh8)
+    assert eng.tile_backend == "assoc"
     spec = model.replay_spec()
     colev = encode_events_columnar(spec.registry, logs)
     res = eng.replay_resident_sharded(eng.prepare_resident_sharded(colev))
@@ -316,7 +316,7 @@ def test_mesh_sharded_resident_pallas_golden(mesh8):
         assert int(res.states["count"][i]) == (exp.count if exp else 0), i
         assert int(res.states["version"][i]) == (exp.version if exp else 0), i
 
-    # resume: the kernel's ord_rel leg must continue derived ordinals
+    # resume: the tile's ordinal bases must continue derived ordinals
     cut = [len(l) // 2 for l in logs]
     first = encode_events_columnar(spec.registry,
                                    [l[:c] for l, c in zip(logs, cut)])

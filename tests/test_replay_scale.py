@@ -232,21 +232,23 @@ def test_length_sorted_chunking_cuts_padding_and_stays_exact():
 def test_resident_corpus_replay_matches_streaming_and_scalar():
     """Resident-corpus replay (one flat upload + on-device gather) must
     produce byte-identical states to the streaming window path and the scalar
-    fold, in the caller's original aggregate order, while shipping exactly
+    fold, in the caller's original aggregate order, while packing exactly
     wire_bytes_per_event() per event."""
     from surge_tpu.replay.corpus import synth_counter_corpus
 
     corpus = synth_counter_corpus(3000, 120_000, seed=17)  # unsorted order
     cfg = Config(overrides={"surge.replay.batch-size": 256,
-                            "surge.replay.time-chunk": 32,
-                            "surge.replay.resident-len-bucket": "exact"})
+                            "surge.replay.time-chunk": 32})
     eng = ReplayEngine(counter.make_replay_spec(), config=cfg)
-    resident = eng.prepare_resident(corpus.events)
-    # 1 byte/event on the link + the guard tail (slice safety); exact bucket
-    # policy so the shipped bytes equal the information bytes
-    from surge_tpu.replay.engine import _WIRE_GUARD_MIN
+    wire = eng.pack_resident(corpus.events)
+    # 1 byte/event in the packed word + the guard tail (slice safety); the
+    # device buffer is those rows bucketed to a power of two
+    from surge_tpu.replay.engine import _WIRE_GUARD_MIN, _bucket_len
     guard = max(eng.resident_tile_width(), _WIRE_GUARD_MIN)
-    assert resident.wire_bytes == corpus.num_events + guard
+    assert wire.packed_shape == (corpus.num_events + guard, 1)
+    resident = eng.upload_resident(wire)
+    assert resident.flat_wire.shape == (
+        _bucket_len(corpus.num_events + guard), 1)
     res = eng.replay_resident(resident)
     np.testing.assert_array_equal(res.states["count"], corpus.expected_count)
     np.testing.assert_array_equal(res.states["version"], corpus.expected_version)
@@ -269,8 +271,7 @@ def test_resident_plan_small_tile_divides_big():
     corpus = synth_counter_corpus(1500, 60_000, seed=23)
     for batch in (1007, 72):
         cfg = Config(overrides={"surge.replay.batch-size": batch,
-                                "surge.replay.time-chunk": 32,
-                                "surge.replay.resident-len-bucket": "exact"})
+                                "surge.replay.time-chunk": 32})
         eng = ReplayEngine(counter.make_replay_spec(), config=cfg)
         resident = eng.prepare_resident(corpus.events)
         plan = eng._resident_plan(resident)
@@ -358,120 +359,22 @@ def test_streamed_resident_replay_matches_plain():
         np.testing.assert_array_equal(one.states[name], plain.states[name])
 
 
-def test_pallas_tile_backend_matches_xla():
-    """surge.replay.tile-backend=pallas must fold byte-identically to the XLA
-    scan (interpret mode on CPU runs the same kernel program), across models
-    with packed-only (counter) and float-side (bank_account) wires and bool
-    state (shopping_cart)."""
-    import random
-
-    from surge_tpu.codec.tensor import encode_events_columnar
-    from surge_tpu.models import bank_account as ba
-    from surge_tpu.replay.corpus import synth_counter_corpus
-
-    corpus = synth_counter_corpus(900, 45_000, seed=8)
-    outs = {}
-    for backend in ("xla", "pallas"):
-        eng = ReplayEngine(counter.make_replay_spec(), config=Config(overrides={
-            "surge.replay.batch-size": 256, "surge.replay.time-chunk": 32,
-            "surge.replay.tile-backend": backend}))
-        outs[backend] = eng.replay_resident(eng.prepare_resident(corpus.events))
-    for name in outs["xla"].states:
-        np.testing.assert_array_equal(outs["xla"].states[name],
-                                      outs["pallas"].states[name])
-    np.testing.assert_array_equal(outs["pallas"].states["count"],
-                                  corpus.expected_count)
-
-    rng = random.Random(2)
-    vocab = ba.Vocab()
-    enc_logs = []
-    for i in range(130):
-        log = [ba.BankAccountCreated(str(i), f"o{i}", "s", 100.0)]
-        bal = 100.0
-        for _ in range(rng.randrange(0, 9)):
-            bal += rng.randrange(1, 20) * 0.25
-            log.append(ba.BankAccountUpdated(str(i), bal))
-        enc_logs.append([ba.encode_event(vocab, e) for e in log])
-    bspec = ba.BankAccountModel().replay_spec()
-    bcolev = encode_events_columnar(bspec.registry, enc_logs)
-    bouts = {}
-    for backend in ("xla", "pallas"):
-        eng = ReplayEngine(bspec, config=Config(overrides={
-            "surge.replay.batch-size": 64, "surge.replay.time-chunk": 8,
-            "surge.replay.tile-backend": backend}))
-        bouts[backend] = eng.replay_resident(eng.prepare_resident(bcolev))
-    for name in bouts["xla"].states:
-        np.testing.assert_array_equal(bouts["xla"].states[name],
-                                      bouts["pallas"].states[name])
-
-    # bool state (shopping_cart) rides the kernel as int32; engine-default
-    # geometry, a lane count that pads up to the 128-lane tiling
-    from surge_tpu.models import shopping_cart
-    from surge_tpu.testing import random_cart_log
-
-    cspec = shopping_cart.make_replay_spec()
-    ccolev = encode_events_columnar(
-        cspec.registry, [random_cart_log(rng, f"c{i}") for i in range(150)])
-    couts = {}
-    for backend in ("xla", "pallas"):
-        eng = ReplayEngine(cspec, config=Config(overrides={
-            "surge.replay.tile-backend": backend}))
-        couts[backend] = eng.replay_resident(eng.prepare_resident(ccolev))
-    assert couts["pallas"].states["checked_out"].dtype == np.bool_
-    assert couts["pallas"].states["checked_out"].any()
-    for name in couts["xla"].states:
-        np.testing.assert_array_equal(couts["xla"].states[name],
-                                      couts["pallas"].states[name])
-
-
-def test_pallas_lane_tiling_and_backend_gate(monkeypatch):
-    """What Mosaic refused on the chip (PR 21), held on the CPU: lane blocks are
-    multiples of 128 that fit VMEM double-buffered, tiles pad to whole blocks,
-    and the kernel interprets on cpu only, compiles on tpu, raises elsewhere."""
-    import jax
-
-    from surge_tpu.replay import pallas_fold
-
-    word = 512 * 4  # one u32 word slab, width 512
-    assert pallas_fold._lane_tiling(8192, word) == (8192, 1024)
-    assert pallas_fold._lane_tiling(1024, word) == (1024, 1024)
-    assert pallas_fold._lane_tiling(3000, word) == (3072, 1024)
-    assert pallas_fold._lane_tiling(64, word) == (128, 128)
-    # bank_account: four 4-byte side columns beside the word -> narrower blocks
-    bs_p, lb = pallas_fold._lane_tiling(3000, 5 * word)
-    assert (bs_p, lb) == (3072, 512)
-    assert 2 * lb * 5 * word <= pallas_fold._VMEM_BUDGET
-
-    assert pallas_fold._interpret() is True  # the suite runs on cpu
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert pallas_fold._interpret() is False
-    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
-    with pytest.raises(NotImplementedError, match="gpu"):
-        pallas_fold._interpret()
-
-
-def test_select_dispatch_matches_switch_dispatch():
-    """The branchless select lowering must be state-identical to lax.switch
-    across the resident and streaming paths (it exists purely as a VPU-friendly
-    lowering choice, surge.replay.dispatch)."""
+def test_assoc_tile_matches_switch_scan():
+    """The assoc tree tile (``tile-backend = assoc``) must be state-identical
+    to the streaming window path's ``lax.switch`` scan and to the closed
+    form."""
     from surge_tpu.replay.corpus import synth_counter_corpus
 
     corpus = synth_counter_corpus(1200, 60_000, seed=23)
-    results = {}
-    for dispatch in ("switch", "select"):
-        eng = ReplayEngine(counter.make_replay_spec(), config=Config(overrides={
-            "surge.replay.batch-size": 256, "surge.replay.time-chunk": 32,
-            "surge.replay.dispatch": dispatch}))
-        r1 = eng.replay_resident(eng.prepare_resident(corpus.events))
-        r2 = eng.replay_columnar(corpus.events)
-        for name in r1.states:
-            np.testing.assert_array_equal(r1.states[name], r2.states[name])
-        results[dispatch] = r1
-    for name in results["switch"].states:
-        np.testing.assert_array_equal(results["switch"].states[name],
-                                      results["select"].states[name])
-    np.testing.assert_array_equal(results["select"].states["count"],
-                                  corpus.expected_count)
+    eng = ReplayEngine(counter.make_replay_spec(), config=Config(overrides={
+        "surge.replay.batch-size": 256, "surge.replay.time-chunk": 32,
+        "surge.replay.tile-backend": "assoc"}))
+    assert eng.tile_backend == "assoc"
+    r1 = eng.replay_resident(eng.prepare_resident(corpus.events))
+    r2 = eng.replay_columnar(corpus.events)
+    for name in r1.states:
+        np.testing.assert_array_equal(r1.states[name], r2.states[name])
+    np.testing.assert_array_equal(r1.states["count"], corpus.expected_count)
 
 
 def test_resident_len_bucketing_reuses_programs_across_sizes():
@@ -664,8 +567,7 @@ def test_grouped_pack_is_indirect_and_exact_everywhere(mesh8):
 
     corpus = synth_counter_corpus(900, 40_000, seed=77)
     cfg = Config(overrides={"surge.replay.batch-size": 128,
-                            "surge.replay.time-chunk": 32,
-                            "surge.replay.resident-len-bucket": "exact"})
+                            "surge.replay.time-chunk": 32})
     eng = ReplayEngine(counter.make_replay_spec(), config=cfg)
     wire = eng.pack_resident(corpus.events)
     # the fast path really triggered: lanes are length-sorted but the buffer
@@ -721,8 +623,7 @@ def test_streamed_indirect_wire_with_empty_aggregates():
         cols={"increment_by": inc, "decrement_by": dec},
         derived_cols={"sequence_number": "ordinal"})
     eng = ReplayEngine(counter.make_replay_spec(), config=Config(overrides={
-        "surge.replay.batch-size": 16, "surge.replay.time-chunk": 16,
-        "surge.replay.resident-len-bucket": "exact"}))
+        "surge.replay.batch-size": 16, "surge.replay.time-chunk": 16}))
     wire = eng.pack_resident(colev)
     assert int((wire.lengths == 0).sum()) == 3
     plain = eng.replay_resident(eng.upload_resident(wire))

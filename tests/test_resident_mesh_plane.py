@@ -6,8 +6,8 @@ The load-bearing proof is golden byte-identity: the sharded slab with
 device-local gather lanes, driven through incremental refresh rounds,
 evict/re-admit cycles AND a partition revoke/re-grant rebalance, must serve
 every aggregate byte-identical to a single-device full cold-start replay over
-the same log. The Pallas tile-scan kernel under ``shard_map``
-(``tile-backend = pallas``) is held to the same bar."""
+the same log. The assoc tree fold under ``shard_map``
+(``tile-backend = assoc``) is held to the same bar."""
 
 import asyncio
 
@@ -175,10 +175,10 @@ def test_mesh_narrow_overflow_refetches_wide(mesh8):
     asyncio.run(scenario())
 
 
-def test_mesh_plane_pallas_tile_backend_byte_identity(mesh8):
-    """The Pallas tile-scan kernel under shard_map, end to end through the
-    PLANE: mesh seed (fold_resident_sharded with tile-backend=pallas) +
-    incremental rounds, byte-identical to the single-device golden replay."""
+def test_mesh_plane_assoc_tile_backend_byte_identity(mesh8):
+    """The assoc tree fold under shard_map, end to end through the PLANE:
+    mesh seed (fold_resident_sharded with tile-backend=assoc) + incremental
+    rounds, byte-identical to the single-device golden replay."""
     async def scenario():
         log = make_log()
         exp = Expected()
@@ -188,9 +188,9 @@ def test_mesh_plane_pallas_tile_backend_byte_identity(mesh8):
             evs.extend(exp.events(agg, 2 + i % 6, decrement_every=3))
         append_events(log, evs)
         plane = _mesh_plane(log, mesh8, capacity=24, overrides={
-            "surge.replay.tile-backend": "pallas",
-            "surge.replay.dispatch": "select",
+            "surge.replay.tile-backend": "assoc",
         })
+        assert plane.engine.tile_backend == "assoc"
         await plane.start()
         try:
             evs = []

@@ -214,29 +214,7 @@ def test_a_second_length_shares_the_bucket_and_its_programs(small_pieces):
     assert engine.num_compiles() == folds
 
 
-# -- (c) resident-len-bucket = exact -----------------------------------------
-
-@pytest.mark.parametrize("gather", ["slices", "rows"])
-def test_exact_pads_a_side_column_to_the_packed_rows(monkeypatch, gather):
-    monkeypatch.setattr(engine_module, "_lane_gather", lambda: gather)
-    engine = make_engine(**{"surge.replay.resident-len-bucket": "exact"})
-    events = cart_events(30_001, seed=7)
-    wire = engine.pack_resident(events)
-    since = time.monotonic()
-    resident = engine.upload_resident(wire)
-    (h2d,) = (s.attributes for s in h2d_spans(since))
-    rows = 30_001 + wire.guard
-    if gather == "rows":
-        rows = -(-rows // 128) * 128
-    assert_one_bucket(resident, wire, rows)
-    # each buffer whole at its own length: nothing more crossed the link
-    assert h2d["put_bytes"] == h2d["wire_bytes"] == (
-        wire.packed.nbytes + 3 * 4 * 30_001)
-    assert h2d["pieces"] == 4
-    assert_states(engine.replay_resident(resident), scalar_states(events))
-
-
-# -- (d) saved wires, old and new; the one rule of check_wire ----------------
+# -- (c) saved wires, old and new; the one rule of check_wire ----------------
 
 def old_style(wire):
     """The wire as a build before this rule packed and saved it: every side
@@ -295,7 +273,7 @@ def test_check_wire_refuses_a_packed_buffer_short_of_its_guard():
         engine.check_wire(wire)
 
 
-# -- (e) the streamed and the sharded fold -----------------------------------
+# -- (d) the streamed and the sharded fold -----------------------------------
 
 @pytest.mark.parametrize("segments", [2, 3])
 def test_the_streamed_fold_slices_short_side_columns(small_pieces, segments):
@@ -326,7 +304,7 @@ def test_the_sharded_fold_reads_the_callers_columns(mesh8, source):
     assert_states(res, scalar_states(events))
 
 
-# -- (f) the columns are the caller's again once the upload has returned -----
+# -- (e) the columns are the caller's again once the upload has returned -----
 
 def aligned(col, to=64):
     """``col`` at an address the CPU backend's ``device_put`` takes without a
@@ -340,7 +318,7 @@ def aligned(col, to=64):
 
 
 @pytest.mark.parametrize("mode", ["one_piece", "pieces", "whole_pieces",
-                                  "exact", "streamed"])
+                                  "streamed"])
 def test_writing_the_columns_after_the_upload_changes_nothing(monkeypatch,
                                                               mode):
     """The wire's side columns are the caller's arrays; the device buffers
@@ -348,9 +326,8 @@ def test_writing_the_columns_after_the_upload_changes_nothing(monkeypatch,
     returned leaves the buffers and the fold's states as they were."""
     monkeypatch.setattr(engine_module, "_PIECE_ROWS", PIECE)
     n = {"one_piece": 40_000, "pieces": 2 * PIECE + 5,
-         "whole_pieces": 2 * PIECE, "exact": 70_000, "streamed": 70_000}[mode]
-    engine = make_engine(**({"surge.replay.resident-len-bucket": "exact"}
-                            if mode == "exact" else {}))
+         "whole_pieces": 2 * PIECE, "streamed": 70_000}[mode]
+    engine = make_engine()
     events = cart_events(n, seed=12)
     events.cols = {k: aligned(v) for k, v in events.cols.items()}
     assert all(v.ctypes.data % 64 == 0 for v in events.cols.values())

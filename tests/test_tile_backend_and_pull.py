@@ -1,6 +1,7 @@
 """The resident fold's tile backend and its single-round-trip state pull.
 
-1. ``surge.replay.tile-backend = auto`` resolves by the backend and the spec;
+1. ``surge.replay.tile-backend = auto`` resolves by the backend and the spec,
+   and a value other than ``auto | xla | assoc`` raises;
 2. ``replay_resident`` pulls states in ONE device→host fetch — a u16 matrix
    with device-computed fit flags when every column is integer/bool, falling
    back to a wide u32 refetch when a value overflows 16 bits (the pull grows
@@ -10,6 +11,7 @@
 from __future__ import annotations
 
 import numpy as np
+import pytest
 
 from surge_tpu.codec.tensor import encode_events_columnar
 from surge_tpu.config import Config
@@ -75,3 +77,13 @@ def test_float_state_pulls_wide():
     for j, want in enumerate(finals):
         assert res.states["created"][j]
         np.testing.assert_allclose(res.states["balance"][j], want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("tile", ["pallas", "select", "XLA", ""])
+def test_an_unknown_tile_backend_raises(tile):
+    """The tile has two lowerings, the xla scan and the assoc tree: any other
+    ``tile-backend`` (the Pallas kernel's old name too) raises at
+    construction, naming the values accepted."""
+    with pytest.raises(ValueError, match=r"\(auto\|xla\|assoc\)"):
+        ReplayEngine(counter.make_replay_spec(), config=Config({
+            "surge.replay.tile-backend": tile}))
